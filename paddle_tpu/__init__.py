@@ -13,7 +13,6 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-from .core import jax_compat as _jax_compat  # noqa: F401  (installs shims)
 from .core import dtypes as _dtypes_mod
 from .core.dtypes import (  # noqa: F401
     bfloat16, bool_, complex128, complex64, float16, float32, float64,

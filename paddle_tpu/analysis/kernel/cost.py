@@ -40,13 +40,15 @@ from typing import Dict, Iterable, Optional, Sequence, Tuple
 
 __all__ = [
     "VMEM_BYTES_PER_CORE", "SAFETY_FRACTION", "DEFAULT_GENERATION",
-    "MAX_HEAD_DIM", "MODEL_TOLERANCE", "DMA_STAGING_SLOTS",
+    "MAX_HEAD_DIM", "LANE_WIDTH", "head_dim_lane_reason",
+    "MODEL_TOLERANCE", "DMA_STAGING_SLOTS",
     "budget_bytes", "fits",
     "generation_from_device_kind", "itemsize", "Buffer", "vmem_bytes",
     "decode_block_vmem", "decode_block_weight_bytes",
     "decode_block_unsupported_reason",
     "prefill_block_vmem", "prefill_block_unsupported_reason",
-    "linear_ce_vmem", "linear_ce_fits",
+    "linear_ce_vmem", "linear_ce_fits", "linear_ce_bwd_vmem",
+    "linear_ce_bwd_blocks", "linear_ce_unsupported_reason",
 ]
 
 # Physical per-core VMEM by TPU generation (the Pallas guide's ~16 MB
@@ -74,6 +76,13 @@ DEFAULT_GENERATION = "v4"
 # kernel (one (head, D) row must fit a VMEM register tile fan-out).
 MAX_HEAD_DIM = 256
 
+# Lanes of a TPU vector register.  The block megakernels split
+# ``[rows, heads * head_dim]`` lanes into heads, a shape cast Mosaic
+# only lowers when ``head_dim`` is a whole number of registers: on a
+# v5e, head_dim 16 and 64 are refused ("infer-vector-layout:
+# unsupported shape cast"), 128 and 256 compile and run (PR 22).
+LANE_WIDTH = 128
+
 # Documented tolerance for static-estimate vs kernel-declared bytes
 # (tests/test_kernel_cost.py pins decode_block and linear_ce to it).
 MODEL_TOLERANCE = 0.02
@@ -98,6 +107,19 @@ def itemsize(dtype) -> int:
         if name in s:
             return n
     raise ValueError(f"unknown dtype {dtype!r} for itemsize")
+
+
+def head_dim_lane_reason(head_dim: int) -> Optional[str]:
+    """Why the Mosaic lowering refuses the block megakernels at this
+    ``head_dim``, or None.  A limit of the COMPILED kernel only — the
+    interpreter has none, so the dispatch applies it when it compiles
+    (``ops/pallas/decode_block.unsupported_reason``)."""
+    if head_dim % LANE_WIDTH == 0:
+        return None
+    return (f"head_dim {head_dim} is not a multiple of the "
+            f"{LANE_WIDTH}-lane vector width: the kernel splits "
+            "[rows, heads*head_dim] lanes into heads, a shape cast "
+            "Mosaic only lowers lane-aligned — per-op tier serves it")
 
 
 def generation_from_device_kind(kind: str) -> str:
@@ -403,3 +425,70 @@ def linear_ce_fits(block_rows: int, chunk: int, hidden: int,
                                hidden=hidden, x_itemsize=x_itemsize,
                                w_itemsize=w_itemsize)["total"],
                 generation)
+
+
+# Smallest backward tile the lowering accepts: 8 sublanes of rows, one
+# 128-lane vocab chunk.
+_LINEAR_CE_MIN_BLOCKS = (8, 128)
+
+
+def linear_ce_bwd_vmem(*, block_rows: int, chunk: int, hidden: int,
+                       x_itemsize: int = 4, w_itemsize: int = 4) -> int:
+    """Scoped VMEM the larger of the two backward kernels
+    (``ops/pallas/linear_ce._bwd``: dx, then dw) asks Mosaic for.
+    Unlike the forward model above this one COUNTS the pipeline's two
+    buffers per streamed block, because here they decide: every in/out
+    block twice plus one fp32 accumulator the size of the output block.
+    It reproduces the compiler's own figure — (256, 512) at H 4096 bf16
+    is 20 MiB for dx, which a v5e's 16 MiB scoped limit refuses."""
+    x_blk = block_rows * hidden * x_itemsize
+    w_blk = chunk * hidden * w_itemsize
+    dx = 2 * x_blk + 2 * w_blk + block_rows * hidden * 4 + 2 * x_blk
+    dw = 2 * x_blk + 2 * w_blk + chunk * hidden * 4 + 2 * w_blk
+    return max(dx, dw)
+
+
+def linear_ce_bwd_blocks(block_rows: int, chunk: int, hidden: int,
+                         x_itemsize: int = 4, w_itemsize: int = 4,
+                         generation: Optional[str] = None
+                         ) -> Tuple[int, int]:
+    """The forward's (block_rows, chunk) halved — larger side first —
+    until the backward kernels fit the budget or reach the smallest
+    tile.  The backward recomputes from ``lse``, so its tiling is free
+    to differ from the forward's."""
+    br, c = block_rows, chunk
+    min_br, min_c = _LINEAR_CE_MIN_BLOCKS
+
+    def over():
+        return not fits(linear_ce_bwd_vmem(
+            block_rows=br, chunk=c, hidden=hidden, x_itemsize=x_itemsize,
+            w_itemsize=w_itemsize), generation)
+
+    while over() and (br > min_br or c > min_c):
+        if c > min_c and (c * w_itemsize >= br * x_itemsize
+                          or br <= min_br):
+            c //= 2
+        else:
+            br //= 2
+    return br, c
+
+
+def linear_ce_unsupported_reason(hidden: int, x_itemsize: int = 4,
+                                 w_itemsize: int = 4,
+                                 generation: Optional[str] = None
+                                 ) -> Optional[str]:
+    """None when the fused CE head's kernels can fit at this hidden
+    size at all, else the reason (the dispatch's typed-fallback signal):
+    the smallest backward tile still holds whole ``[rows, H]`` and
+    ``[chunk, H]`` blocks, so a wide enough ``H`` overflows VMEM
+    whatever the tiling."""
+    br, c = _LINEAR_CE_MIN_BLOCKS
+    need = linear_ce_bwd_vmem(block_rows=br, chunk=c, hidden=hidden,
+                              x_itemsize=x_itemsize,
+                              w_itemsize=w_itemsize)
+    if fits(need, generation):
+        return None
+    return (f"linear_ce backward needs {need} B of VMEM at its smallest "
+            f"tile {(br, c)} for hidden={hidden} (itemsizes "
+            f"{x_itemsize}/{w_itemsize}); budget "
+            f"{budget_bytes(generation)} B")

@@ -4,8 +4,7 @@ Serialize traced+lowered+compiled XLA executables once, warm-start
 every other process from the artifact directory:
 
 * :mod:`~paddle_tpu.aot.artifact` — the versioned, CRC'd store with an
-  environment/config manifest and the jax-0.4.37 donated-deserialize
-  gate;
+  environment/config manifest;
 * :mod:`~paddle_tpu.aot.buckets` — declared serve shape buckets, so
   variable prefill load lands on precompiled programs;
 * :mod:`~paddle_tpu.aot.serve` — export/load for the continuous-
@@ -20,12 +19,10 @@ The recompile-budget ratchet over this subsystem lives in
 """
 
 from .artifact import (LATEST_POINTER, AotArtifactCorruptError,
-                       AotDonationError, AotError,
-                       AotManifestMismatchError, ArtifactStore,
+                       AotError, AotManifestMismatchError, ArtifactStore,
                        args_signature, config_hash,
-                       donation_deserialize_safe, environment_fingerprint,
-                       export_compiled, new_generation,
-                       resolve_artifact_dir)
+                       environment_fingerprint, export_compiled,
+                       new_generation, resolve_artifact_dir)
 from .buckets import DEFAULT_CHUNK_BUCKETS, ShapeBucketRegistry
 from .serve import engine_config, export_engine, load_engine_artifacts
 from .train import (AotTrainStep, export_jit_apply, export_train_step,
@@ -33,8 +30,8 @@ from .train import (AotTrainStep, export_jit_apply, export_train_step,
 
 __all__ = [
     "AotError", "AotArtifactCorruptError", "AotManifestMismatchError",
-    "AotDonationError", "ArtifactStore", "args_signature", "config_hash",
-    "donation_deserialize_safe", "environment_fingerprint",
+    "ArtifactStore", "args_signature", "config_hash",
+    "environment_fingerprint",
     "export_compiled", "new_generation", "resolve_artifact_dir",
     "LATEST_POINTER",
     "DEFAULT_CHUNK_BUCKETS", "ShapeBucketRegistry",

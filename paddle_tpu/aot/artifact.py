@@ -18,16 +18,11 @@ all of it and raises a typed :class:`AotError` subclass on any
 mismatch — callers fall back to a fresh compile (with a telemetry
 event) rather than run a wrong or corrupt program.
 
-Donation gate: jax 0.4.37's XLA:CPU client mis-executes programs with
-donated buffers when they are DESERIALIZED rather than freshly compiled
-(flaky param corruption / SIGSEGV — found and documented in ISSUE 2
-against the persistent compilation cache, which round-trips executables
-through the same serialize path).  :func:`donation_deserialize_safe`
-encodes the known-bad (platform, jax version) set; ``load`` refuses a
-donated artifact on an unsafe platform instead of risking silent
-corruption.  Exporters on such platforms should compile undonated
-(numerics are identical; the cost is double-buffering the donated
-operands).
+Devices: an executable is compiled for a device assignment (one device
+for a serving engine, a mesh's devices for an SPMD train step).  ``put``
+records the ids in assignment order and ``get`` loads the program onto
+exactly those devices of this process — never onto "every local
+device", which is what the loader assumes when it is not told.
 """
 
 from __future__ import annotations
@@ -42,12 +37,11 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
-from ..core import jax_compat  # noqa: F401  (binds jax.export et al.)
-
 __all__ = [
     "AotError", "AotArtifactCorruptError", "AotManifestMismatchError",
-    "AotDonationError", "ArtifactStore", "environment_fingerprint",
-    "donation_deserialize_safe", "config_hash", "args_signature",
+    "ArtifactStore", "environment_fingerprint",
+    "config_hash", "args_signature", "executable_device_ids",
+    "devices_for_ids",
     "fresh_backend_compile", "MANIFEST_MAGIC", "LATEST_POINTER",
     "new_generation", "resolve_artifact_dir",
 ]
@@ -57,10 +51,6 @@ _MANIFEST = "manifest.json"
 #: rotation-root pointer file naming the live generation subdirectory
 LATEST_POINTER = "latest"
 _GEN_PREFIX = "gen-"
-
-#: (platform, jax.__version__) pairs where deserialized DONATED
-#: executables are known to mis-execute (ISSUE 2 / CHANGES PR 2).
-KNOWN_BAD_DONATED_DESERIALIZE = {("cpu", "0.4.37")}
 
 
 class AotError(RuntimeError):
@@ -78,11 +68,6 @@ class AotManifestMismatchError(AotError):
     missing executable).  Not corruption — just not OURS."""
 
 
-class AotDonationError(AotError):
-    """A donated executable was refused on a platform where deserialized
-    donated programs are known to mis-execute (jax-0.4.37 XLA:CPU)."""
-
-
 def environment_fingerprint() -> Dict[str, str]:
     """Everything an XLA executable is specialized to besides its
     inputs: jax/jaxlib versions and the backend platform."""
@@ -92,15 +77,6 @@ def environment_fingerprint() -> Dict[str, str]:
         "jaxlib": jaxlib.__version__,
         "platform": jax.default_backend(),
     }
-
-
-def donation_deserialize_safe(platform: Optional[str] = None,
-                              jax_version: Optional[str] = None) -> bool:
-    """True when a DESERIALIZED executable with donated buffers is safe
-    to run here (see module docstring / KNOWN_BAD_DONATED_DESERIALIZE)."""
-    platform = platform or jax.default_backend()
-    jax_version = jax_version or jax.__version__
-    return (platform, jax_version) not in KNOWN_BAD_DONATED_DESERIALIZE
 
 
 @contextlib.contextmanager
@@ -115,23 +91,38 @@ def fresh_backend_compile():
     artifact always comes from a fresh backend compile; the in-memory
     jit caches are untouched.
 
-    Clearing the config flag alone is NOT enough on jax 0.4.37:
-    ``compilation_cache.is_cache_used`` memoizes its decision in module
-    globals at the first compile of the process, so a process that ever
-    compiled with the cache enabled keeps using it regardless of the
-    flag.  ``reset_cache()`` drops only that in-memory memo (the disk
-    cache is untouched); we reset on entry so the disabled flag is
-    re-read, and on exit so later compiles re-enable the cache."""
-    import jax as _jax
-    from jax._src import compilation_cache as _cc
-    prev = _jax.config.jax_compilation_cache_dir
+    The cache is switched off by its enable flag, not by clearing its
+    directory: the directory may have come from
+    ``JAX_COMPILATION_CACHE_DIR`` and is left as it was found.
+    ``compilation_cache.is_cache_used`` memoizes its decision at the
+    first compile of the process, so ``reset_cache()`` (which drops only
+    that in-memory memo; the disk cache is untouched) runs on entry so
+    the flag is re-read, and on exit so later compiles use the cache
+    again."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
     try:
-        _jax.config.update("jax_compilation_cache_dir", None)
-        _cc.reset_cache()
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
         yield
     finally:
-        _jax.config.update("jax_compilation_cache_dir", prev)
-        _cc.reset_cache()
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+def executable_device_ids(compiled) -> List[int]:
+    """Ids of the devices ``compiled`` runs on, in assignment order."""
+    return [d.id for d in compiled.runtime_executable().local_devices()]
+
+
+def devices_for_ids(ids) -> Optional[List[jax.Device]]:
+    """This process's devices with those ids, in that order — what
+    ``deserialize_and_load`` must be told as ``execution_devices`` — or
+    None when ``ids`` is empty or names a device this process lacks."""
+    by_id = {d.id: d for d in jax.devices()}
+    if not ids or any(i not in by_id for i in ids):
+        return None
+    return [by_id[i] for i in ids]
 
 
 def config_hash(config: Dict[str, Any]) -> str:
@@ -299,6 +290,7 @@ class ArtifactStore:
             "crc32": zlib.crc32(blob),
             "size": len(blob),
             "donate_argnums": list(donate_argnums),
+            "device_ids": executable_device_ids(compiled),
             "in_sig": [td, leaves],
         }
         self._flush()
@@ -422,23 +414,18 @@ class ArtifactStore:
         """Does ``name``'s recorded input signature match ``args``?"""
         return _sig_matches(self.entry(name)["in_sig"], args)
 
-    def get(self, name: str, *, allow_donated: Optional[bool] = None
-            ) -> Callable:
-        """CRC-verify, donation-gate, and deserialize ``name``; returns
-        the loaded executable as a callable.  Raises AotError subclasses
-        on any reason the artifact cannot be used here."""
+    def get(self, name: str) -> Callable:
+        """CRC-verify and deserialize ``name`` onto the devices it was
+        exported for; returns the loaded executable as a callable.
+        Raises AotError subclasses on any reason the artifact cannot be
+        used here."""
         entry = self.entry(name)
-        if entry["donate_argnums"]:
-            safe = (allow_donated if allow_donated is not None
-                    else donation_deserialize_safe())
-            if not safe:
-                self._event("donation_refused", name=name)
-                raise AotDonationError(
-                    f"{self.directory}/{entry['file']}: donated executable "
-                    f"refused — deserialized donated programs mis-execute "
-                    f"on jax {jax.__version__} {jax.default_backend()} "
-                    "(ISSUE 2 cache bug); re-export undonated or fresh-"
-                    "compile")
+        devices = devices_for_ids(entry.get("device_ids"))
+        if devices is None:
+            raise AotManifestMismatchError(
+                f"{self.directory}: executable {name!r} was exported for "
+                f"device ids {entry.get('device_ids')}, this process has "
+                f"{[d.id for d in jax.devices()]} — re-export here")
         path = os.path.join(self.directory, entry["file"])
         try:
             with open(path, "rb") as f:
@@ -454,7 +441,8 @@ class ArtifactStore:
         from jax.experimental import serialize_executable as se
         try:
             payload, in_tree, out_tree = pickle.loads(blob)
-            loaded = se.deserialize_and_load(payload, in_tree, out_tree)
+            loaded = se.deserialize_and_load(payload, in_tree, out_tree,
+                                             execution_devices=devices)
         except AotError:
             raise
         except Exception as e:
@@ -476,7 +464,7 @@ def export_compiled(directory: str, name: str, jitted, example_args: Tuple,
     """One-call export of a single jitted function: trace → lower →
     compile ``jitted`` at ``example_args`` and store it under ``name``.
     ``donate_argnums`` must mirror what ``jitted`` was built with — it
-    is recorded for the load-side donation gate, not applied here."""
+    is recorded in the manifest, not applied here."""
     store = ArtifactStore(directory, registry=registry)
     store.begin(config=config, buckets=buckets)
     with fresh_backend_compile():

@@ -18,11 +18,7 @@ model config, batch/pool geometry, and the parameter tree signature,
 so a mismatched engine falls back to fresh compiles instead of running
 a wrong program.
 
-Donation note: the fresh engine donates the KV pools into its compiled
-steps.  Exports only record donation where deserialized donated
-executables are safe (see artifact.donation_deserialize_safe) — on the
-known-broken jax-0.4.37 CPU path the exported steps are compiled
-UNDONATED (identical numerics, double-buffered pools).
+Like the fresh engine, the exported steps donate the KV pools.
 
 Sampler coverage (ISSUE 7): the engine samples every sub-batch at the
 FIXED decode width ``max_batch`` (rows padded; vmap keeps real rows
@@ -49,8 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .artifact import (ArtifactStore, AotManifestMismatchError,
-                       args_signature, donation_deserialize_safe,
-                       fresh_backend_compile)
+                       args_signature, fresh_backend_compile)
 from .buckets import DEFAULT_CHUNK_BUCKETS, ShapeBucketRegistry
 
 __all__ = ["export_engine", "load_engine_artifacts", "engine_config",
@@ -180,7 +175,7 @@ def export_engine(engine, directory: str, *,
         ShapeBucketRegistry(DEFAULT_CHUNK_BUCKETS)
     if breg.max_batch is None:
         breg = ShapeBucketRegistry(breg.chunk_sizes, max_batch=engine.B)
-    donate = (1, 2) if donation_deserialize_safe() else ()
+    donate = (1, 2)
     if rotate:
         from .artifact import new_generation
         store = new_generation(directory, registry=registry)

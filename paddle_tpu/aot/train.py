@@ -15,12 +15,6 @@ Two entry points, mirroring the two ways the tree builds train steps:
 
 * :func:`export_jit_apply` — the raw ``Optimizer.build_jit_apply``
   fused-apply program, for callers that run their own step loop.
-
-Donation: by default the export donates exactly when a deserialized
-donated program is safe on this platform
-(:func:`~paddle_tpu.aot.artifact.donation_deserialize_safe`); the
-jax-0.4.37 XLA:CPU path exports undonated so its artifacts remain
-loadable (identical numerics, double-buffered state).
 """
 
 from __future__ import annotations
@@ -30,7 +24,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 
 from .artifact import (ArtifactStore, _sig_matches, args_signature,
-                       donation_deserialize_safe, fresh_backend_compile)
+                       fresh_backend_compile)
 
 __all__ = ["export_train_step", "load_train_step", "AotTrainStep",
            "export_jit_apply", "engine_topology_key", "export_engine_step",
@@ -83,7 +77,7 @@ def train_config(model, args: Tuple) -> Dict[str, Any]:
 
 
 def export_train_step(model, inputs, labels, directory: str, *,
-                      donate: Optional[bool] = None,
+                      donate: bool = True,
                       rotate: bool = False,
                       keep_last: Optional[int] = None,
                       registry=None) -> ArtifactStore:
@@ -97,8 +91,6 @@ def export_train_step(model, inputs, labels, directory: str, *,
     if model._optimizer is None:
         raise ValueError("export_train_step needs a prepared Model "
                          "(call prepare(optimizer=..., loss=...) first)")
-    if donate is None:
-        donate = donation_deserialize_safe()
     donate_argnums = (0, 1, 2) if donate else ()
     jit_step = model._build_jit_step(donate=donate)
     args_init = _example_args(model, inputs, labels)
@@ -213,7 +205,7 @@ def _engine_example_args(engine, inputs, labels) -> Tuple:
 
 
 def export_engine_step(engine, inputs, labels, directory: str, *,
-                       donate: Optional[bool] = None,
+                       donate: bool = True,
                        registry=None):
     """Compile + serialize ``engine``'s SPMD train step under its
     topology's entry name.  An existing store is EXTENDED (other
@@ -222,8 +214,6 @@ def export_engine_step(engine, inputs, labels, directory: str, *,
     compiled)`` — the freshly compiled executable is handed back so the
     caller can install it directly and the export costs no second
     compile."""
-    if donate is None:
-        donate = donation_deserialize_safe()
     if engine._state is None:
         engine.shard_state()
     jitted = engine.build_train_step(donate=donate)
@@ -283,13 +273,11 @@ def load_engine_step(engine, directory: str, *, registry=None
 
 def export_jit_apply(opt, params, grads, state, directory: str, *,
                      lr=1e-3, step: int = 1,
-                     donate: Optional[bool] = None,
+                     donate: bool = True,
                      registry=None) -> ArtifactStore:
     """Serialize ``Optimizer.build_jit_apply``'s fused-apply program at
     the given (params, grads, state) signature — the raw-step-loop
     analog of :func:`export_train_step`."""
-    if donate is None:
-        donate = donation_deserialize_safe()
     fused = opt.build_jit_apply(donate=donate)
     args = (params, grads, state, lr, step)
     store = ArtifactStore(directory, registry=registry)

@@ -164,13 +164,20 @@ class CheckpointManager:
             excess -= 1
 
     def _sweep_stragglers(self) -> None:
-        """Remove ``.tmp-*`` leftovers from crashed saves (best effort)."""
+        """Remove ``.tmp-*`` leftovers from crashed saves (best effort)
+        — only temp files of names THIS manager writes (its checkpoints
+        and the pointer; ``atomic_write_bytes`` ends a temp name with
+        its target's).  Another writer may be mid-publish in the same
+        directory: ``Model.fit(async_save=True)`` saves ``epoch_N.*``
+        from the main thread while this runs on the writer thread, and
+        unlinking its temp file fails its ``os.replace``."""
         try:
             names = os.listdir(self.directory)
         except OSError:
             return
+        ours = (CKPT_SUFFIX, "-" + LATEST_POINTER)
         for n in names:
-            if n.startswith(fio._TMP_PREFIX):
+            if n.startswith(fio._TMP_PREFIX) and n.endswith(ours):
                 try:
                     os.unlink(os.path.join(self.directory, n))
                 except OSError:

@@ -198,8 +198,6 @@ class Model:
             return (new_params, kept_buffers, new_opt_state, loss_v,
                     outs_v, notfinite)
 
-        # donate=False is the AOT-export path on platforms where a
-        # deserialized DONATED program is unsafe (aot/artifact.py)
         return jax.jit(step, donate_argnums=(0, 1, 2) if donate else ())
 
     def _make_jit_step(self):
@@ -544,13 +542,13 @@ class Model:
         # cache what MFU needs so the per-step path does no discovery
         self._tele_n_params = sum(
             int(p.size) for p in self.network.parameters())
-        try:
-            import jax
-            from ..observability import peak_flops_per_chip
-            self._tele_peak_flops = peak_flops_per_chip(
-                jax.local_devices()[0])
-        except RuntimeError:        # backend init failure: MFU off
-            self._tele_peak_flops = 0.0
+        # off-TPU there is no peak to divide by: the step record keeps
+        # its mfu key with the value null
+        import jax
+        from ..core.device import on_tpu
+        from ..observability import peak_flops_per_chip
+        self._tele_peak_flops = peak_flops_per_chip(
+            jax.local_devices()[0]) if on_tpu() else None
         return session
 
     @staticmethod
@@ -583,8 +581,9 @@ class Model:
         reg = session.registry
         examples, items = self._batch_items(inputs)
         tokens_per_s = items / step_secs if step_secs > 0 else 0.0
-        mfu = (tokens_per_s * 6.0 * self._tele_n_params
-               / self._tele_peak_flops) if self._tele_peak_flops else 0.0
+        mfu = round(tokens_per_s * 6.0 * self._tele_n_params
+                    / self._tele_peak_flops, 8) \
+            if self._tele_peak_flops else None
         guard = self._step_guard
         reg.counter("train.steps_total").inc()
         reg.histogram("train.step_secs", unit="s").record(step_secs)
@@ -599,7 +598,7 @@ class Model:
             examples_per_s=round(examples / step_secs, 3)
             if step_secs > 0 else 0.0,
             tokens_per_s=round(tokens_per_s, 3),
-            mfu=round(mfu, 8),
+            mfu=mfu,
             skipped=self._last_step_skipped,
             consecutive_skips=(guard.consecutive if guard else 0),
             skipped_total=(guard.total_skipped if guard else 0))

@@ -1556,6 +1556,42 @@ class ContinuousBatchingEngine:
             if self.decode_tokens else None)
         return s
 
+    def kernel_tiers(self) -> Dict[str, Dict[str, Optional[str]]]:
+        """Which tier the decode step and each declared prefill bucket
+        run their layers on — ``{"tier": "pallas" | "xla", "reason":
+        why the megakernel stood down, or None}`` from the SAME
+        functions the dispatch reads (``ops.decode_block.*_tier``), so a
+        printed tier is the one that ran."""
+        from ..ops.decode_block import (decode_block_spec,
+                                        decode_block_tier,
+                                        prefill_block_tier)
+
+        def per_layer(tree, lead):
+            return jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape[lead:], a.dtype),
+                tree)
+
+        spec = decode_block_spec(self.cfg, self.BS, **self._quant_kw())
+        lp = per_layer(dict(self.params["blocks"]), 2)
+        pool = per_layer(self.pool_k, 1)
+        ffn = _make_rms_ffn(self.cfg)[1] \
+            if getattr(self.cfg, "moe_num_experts", 0) else None
+        def row(fused: bool, knob: str, tier):
+            tier, reason = tier() if fused else (
+                "xla", f"the engine was built with {knob}=False")
+            return {"tier": tier, "reason": reason}
+
+        tiers = {"decode_block": row(
+            self.fused_decode_block, "fused_decode_block",
+            lambda: decode_block_tier(spec, lp, pool, ffn))}
+        sizes = self._buckets.chunk_sizes if self._buckets is not None \
+            else ()
+        for c in sizes:
+            tiers[f"prefill_block[{c}]"] = row(
+                self.fused_prefill, "fused_prefill",
+                lambda c=c: prefill_block_tier(spec, lp, pool, c, ffn))
+        return tiers
+
     def aot_stats(self) -> Dict[str, object]:
         """Warm-start observability for bench rows/telemetry: whether
         artifacts loaded (and why not), plus declared-bucket hit/miss

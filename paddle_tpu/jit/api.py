@@ -313,11 +313,13 @@ def save(layer, path, input_spec=None, aot=False, **config):
                 "STABLEHLO export")
         from jax.experimental import serialize_executable as se
         from ..aot.artifact import (environment_fingerprint,
+                                    executable_device_ids,
                                     fresh_backend_compile)
         with fresh_backend_compile():
             compiled = jax.jit(pure).lower(params_sds, *sds).compile()
         payload = pickle.dumps(se.serialize(compiled))
         blob["aot"] = {"env": environment_fingerprint(),
+                       "device_ids": executable_device_ids(compiled),
                        "crc32": zlib.crc32(payload),
                        "payload": payload}
     with open(path + ".pdmodel", "wb") as f:
@@ -328,9 +330,9 @@ def load(path, **config):
     """``paddle.jit.load`` analog: deserialize the STABLEHLO program +
     params saved by :func:`save`; returns a :class:`TranslatedLayer`.
     An embedded ``aot=True`` executable is used when its environment
-    fingerprint matches and its CRC verifies — otherwise the portable
-    STABLEHLO program is used (version skew is a fallback, corruption
-    of the aot payload raises)."""
+    fingerprint matches, its devices exist here and its CRC verifies —
+    otherwise the portable STABLEHLO program is used (version skew is a
+    fallback, corruption of the aot payload raises)."""
     import pickle
     import zlib
 
@@ -352,16 +354,20 @@ def load(path, **config):
     aot_blob = blob.get("aot")
     if aot_blob is not None:
         from ..aot.artifact import (AotArtifactCorruptError,
+                                    devices_for_ids,
                                     environment_fingerprint)
         if zlib.crc32(aot_blob["payload"]) != aot_blob["crc32"]:
             raise AotArtifactCorruptError(
                 f"{path}.pdmodel: embedded AOT executable fails its CRC "
                 "— archive is corrupt (the STABLEHLO program shares the "
                 "same file; re-export)")
-        if aot_blob.get("env") == environment_fingerprint():
+        devices = devices_for_ids(aot_blob.get("device_ids"))
+        if aot_blob.get("env") == environment_fingerprint() \
+                and devices is not None:
             from jax.experimental import serialize_executable as se
             aot_call = se.deserialize_and_load(
-                *pickle.loads(aot_blob["payload"]))
+                *pickle.loads(aot_blob["payload"]),
+                execution_devices=devices)
     return TranslatedLayer(exported, params, aot_call=aot_call)
 
 

@@ -591,12 +591,60 @@ def block_apply(params: Dict[str, jax.Array], x: jax.Array,
 
 def stack_block_params(cfg: LlamaConfig, key, num_stages: int
                       ) -> Dict[str, jax.Array]:
+    """``[num_stages, per, ...]`` leaves, layer ``i`` seeded by the
+    ``i``-th split of ``key``.  vmapped over the keys, so every leaf is
+    born stacked: a per-layer list stacked afterwards holds the model
+    twice at its peak and leaves the device heap in layer-sized holes,
+    which at 7B width on a 16 GB chip is the difference between a
+    decode step's temporaries fitting and not."""
     per = cfg.num_layers // num_stages
-    keys = jax.random.split(key, cfg.num_layers)
-    blocks = [init_block_params(cfg, k) for k in keys]
-    return {name: jnp.stack([b[name] for b in blocks]).reshape(
-        (num_stages, per) + blocks[0][name].shape)
-        for name in blocks[0]}
+    keys = jax.random.split(key, cfg.num_layers).reshape(num_stages, per)
+    return jax.vmap(jax.vmap(lambda k: init_block_params(cfg, k)))(keys)
+
+
+def llama_param_specs(cfg: LlamaConfig, topo, num_model_chunks: int = 1):
+    """``(param_specs, restack)`` of the stacked train/serve param tree
+    on ``topo`` (``restack`` regroups blocks for virtual-pipeline
+    chunks; see ``manual.vpp_block_layout``)."""
+    from ..parallel import manual as man
+    blk_specs, restack = man.vpp_block_layout(
+        block_param_specs(cfg, pipeline=True),
+        topo.get_pipe_parallel_world_size(), num_model_chunks,
+        cfg.num_layers)
+    return {"wte": P(MP_AXIS, None), "head": P(None, MP_AXIS),
+            "lnf_w": P(), "blocks": blk_specs}, restack
+
+
+def init_llama_params(cfg: LlamaConfig, topo, seed: int = 0,
+                      num_model_chunks: int = 1):
+    """Seeded params placed on ``topo`` — and nothing else.  The train
+    step's ``init_fn`` adds the fp32 Adam moments on top of this; a
+    server (``serving/http.py``, ``chip_smoke.py``, the bench serve
+    rows) calls this directly, so it never allocates optimizer state it
+    would throw away (two fp32 moment trees are 4x the bf16 weights)."""
+    param_specs, restack = llama_param_specs(cfg, topo, num_model_chunks)
+    S = topo.get_pipe_parallel_world_size()
+
+    def sh(spec):
+        return NamedSharding(topo.mesh, spec)
+
+    k1, k2, k3 = jax.random.split(jax.random.key(seed), 3)
+    dt = jnp.dtype(cfg.dtype)
+    if num_model_chunks == 1:
+        blocks = stack_block_params(cfg, k3, S)
+    else:
+        blocks = restack(stack_block_params(cfg, k3, S * num_model_chunks))
+    return {
+        "wte": jax.device_put(
+            jax.random.normal(k1, (cfg.vocab_size, cfg.hidden_size), dt)
+            * cfg.initializer_range, sh(param_specs["wte"])),
+        "head": jax.device_put(
+            jax.random.normal(k2, (cfg.hidden_size, cfg.vocab_size), dt)
+            * cfg.initializer_range, sh(param_specs["head"])),
+        "lnf_w": jax.device_put(jnp.ones(cfg.hidden_size, dt), sh(P())),
+        "blocks": {n: jax.device_put(v, sh(param_specs["blocks"][n]))
+                   for n, v in blocks.items()},
+    }
 
 
 def build_llama_train_step(cfg: LlamaConfig, topo=None,
@@ -625,7 +673,6 @@ def build_llama_train_step(cfg: LlamaConfig, topo=None,
     Returns (step_fn, init_fn)."""
     from ..parallel import manual as man
     topo = topo or get_topology()
-    mesh = topo.mesh
     S = topo.get_pipe_parallel_world_size()
     mp = topo.get_model_parallel_world_size()
     sep = topo.get_sep_parallel_world_size()
@@ -688,7 +735,9 @@ def build_llama_train_step(cfg: LlamaConfig, topo=None,
             cp_attn = make_auto_attn(
                 cfg.num_layers, S, num_microbatches, schedule, remat,
                 remat_policy, functools.partial(tuned_flash, causal=True),
-                functools.partial(_gqa_attention, causal=True))
+                functools.partial(_gqa_attention, causal=True),
+                state_bytes=lambda: man.train_state_bytes(
+                    jax.eval_shape(init_params_fn, 0), param_specs, topo))
         elif isinstance(use_flash, str):
             import math as _math
             from ..ops.pallas.flash_backends import run_backend
@@ -704,34 +753,10 @@ def build_llama_train_step(cfg: LlamaConfig, topo=None,
             cp_attn = None
 
     vpp = num_model_chunks if schedule == "interleave" else 1
-    blk_specs, _vpp_restack = man.vpp_block_layout(
-        block_param_specs(cfg, pipeline=True), S, vpp, cfg.num_layers)
-    param_specs = {"wte": P(MP_AXIS, None), "head": P(None, MP_AXIS),
-                   "lnf_w": P(), "blocks": blk_specs}
-
-    def sh(spec):
-        return NamedSharding(mesh, spec)
+    param_specs, _ = llama_param_specs(cfg, topo, vpp)
 
     def init_params_fn(seed: int = 0):
-        key = jax.random.key(seed)
-        k1, k2, k3 = jax.random.split(key, 3)
-        dt = jnp.dtype(cfg.dtype)
-        return {
-            "wte": jax.device_put(
-                jax.random.normal(k1, (cfg.vocab_size, cfg.hidden_size), dt)
-                * cfg.initializer_range, sh(param_specs["wte"])),
-            "head": jax.device_put(
-                jax.random.normal(k2, (cfg.hidden_size, cfg.vocab_size), dt)
-                * cfg.initializer_range, sh(param_specs["head"])),
-            "lnf_w": jax.device_put(jnp.ones(cfg.hidden_size, dt), sh(P())),
-            "blocks": {n: jax.device_put(v, sh(blk_specs[n]))
-                       for n, v in _stacked_blocks(k3).items()},
-        }
-
-    def _stacked_blocks(k3):
-        if vpp == 1:
-            return stack_block_params(cfg, k3, S)
-        return _vpp_restack(stack_block_params(cfg, k3, S * vpp))
+        return init_llama_params(cfg, topo, seed, vpp)
 
     sp = sequence_parallel and mp > 1
     if sp:

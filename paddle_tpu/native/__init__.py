@@ -8,6 +8,7 @@ no compiler is available.
 
 Public surface:
     available()                -> bool
+    unavailable_reason()       -> why not (None while available)
     shuffle_indices(n, seed)   -> np.ndarray[int64]  (Fisher-Yates, C++)
     collate_stack(samples)     -> np.ndarray         (threaded batch memcpy)
     TokenRing(capacity)        -> blocking MPMC ring (GIL-free waits)
@@ -24,8 +25,8 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
-__all__ = ["available", "shuffle_indices", "collate_stack", "TokenRing",
-           "load_library"]
+__all__ = ["available", "unavailable_reason", "shuffle_indices",
+           "collate_stack", "TokenRing", "load_library"]
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "src", "dataloader_core.cpp")
@@ -33,13 +34,18 @@ _LIB_PATH = os.path.join(_DIR, "libpt_dataloader.so")
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_error: Optional[str] = None      # why the C++ core is not loaded
 _lock = threading.Lock()
 
 
 def _build() -> Optional[str]:
     """Compile the C++ core if needed.  Multi-process safe: each process
     compiles to a private temp file and atomically renames it into place,
-    so concurrent launcher ranks never dlopen a half-written .so."""
+    so concurrent launcher ranks never dlopen a half-written .so.
+    A failed build (no g++, a compile error) is said once on stderr and
+    kept for :func:`unavailable_reason`; callers then take the python
+    fallbacks."""
+    global _error
     try:
         have_lib = os.path.exists(_LIB_PATH)
         have_src = os.path.exists(_SRC)
@@ -47,6 +53,7 @@ def _build() -> Optional[str]:
                          >= os.path.getmtime(_SRC)):
             return _LIB_PATH
         if not have_src:
+            _error = f"{_SRC} is missing and no built library exists"
             return None
         tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
         cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
@@ -54,12 +61,18 @@ def _build() -> Optional[str]:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _LIB_PATH)  # atomic on POSIX
         return _LIB_PATH
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = (getattr(e, "stderr", None) or b"").decode(
+            errors="replace").strip()
+        _error = f"building {_SRC} failed: {type(e).__name__}: {e}" \
+            + (f"\n{detail}" if detail else "")
+        print(f"paddle_tpu.native: {_error}; using the python fallbacks",
+              file=sys.stderr)
         return None
 
 
 def load_library() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _error
     with _lock:
         if _tried:
             return _lib
@@ -70,7 +83,10 @@ def load_library() -> Optional[ctypes.CDLL]:
         try:
             lib = ctypes.CDLL(path)
             _bind(lib)
-        except (OSError, AttributeError):
+        except (OSError, AttributeError) as e:
+            _error = f"loading {path} failed: {type(e).__name__}: {e}"
+            print(f"paddle_tpu.native: {_error}; using the python "
+                  "fallbacks", file=sys.stderr)
             return None
         _lib = lib
         return _lib
@@ -97,6 +113,12 @@ def _bind(lib: ctypes.CDLL) -> None:
 
 def available() -> bool:
     return load_library() is not None
+
+
+def unavailable_reason() -> Optional[str]:
+    """Why :func:`available` is False (a missing compiler, a failed
+    build or load), or None while the C++ core is loaded."""
+    return None if available() else _error
 
 
 def shuffle_indices(n: int, seed: int) -> np.ndarray:

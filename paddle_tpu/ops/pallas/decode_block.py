@@ -153,6 +153,10 @@ def unsupported_reason(spec, lp, pool_k) -> Optional[str]:
                 + (" in the quantized export layout"
                    if getattr(spec, "weight_dtype", None) else
                    " (MoE FFNs run the reference tier)"))
+    if not use_interpret():
+        lanes = cost.head_dim_lane_reason(spec.head_dim)
+        if lanes is not None:
+            return lanes
     wbytes = sum(lp[n].size * lp[n].dtype.itemsize for n in keys)
     return cost.decode_block_unsupported_reason(
         hidden=spec.hidden, num_heads=spec.num_heads,
@@ -366,7 +370,7 @@ def _kernel(*refs, meta: _Meta):
     # ---- epilogue: fold the CURRENT token, then proj/norm/FFN --------
     @pl.when(jt == meta.nt - 1)
     def _epi():
-        attn = jnp.zeros((Hq, D), jnp.float32)
+        heads = []
         for kv in range(Hkv):
             sl = slice(kv * G, (kv + 1) * G)
             qh = q_scr[sl]
@@ -379,7 +383,8 @@ def _kernel(*refs, meta: _Meta):
             l_f = alpha * l_scr[sl] + p_new
             acc_f = acc_scr[sl] * alpha \
                 + p_new * vn_scr[kv][None, :]
-            attn = attn.at[sl].set(acc_f / jnp.maximum(l_f, 1e-30))
+            heads.append(acc_f / jnp.maximum(l_f, 1e-30))
+        attn = jnp.concatenate(heads, axis=0)               # [Hq, D]
         x = x_ref[:].astype(jnp.float32)                    # [1, H]
         proj = _mmw(attn.reshape(1, Hq * D), w,
                     "proj_w" if meta.fused_qkv else "o_w", meta)
@@ -488,9 +493,9 @@ def _call(x, lp, pool_k, pool_v, block_table, lengths, cos, sin, *,
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),       # block table
         pl.BlockSpec(memory_space=pltpu.SMEM),       # lengths
-        pl.BlockSpec((1, H), lambda b, j: (b, 0)),   # x row
-        pl.BlockSpec((1, D), lambda b, j: (b, 0)),   # cos row
-        pl.BlockSpec((1, D), lambda b, j: (b, 0)),   # sin row
+        pl.BlockSpec((None, 1, H), lambda b, j: (b, 0, 0)),   # x row
+        pl.BlockSpec((None, 1, D), lambda b, j: (b, 0, 0)),   # cos row
+        pl.BlockSpec((None, 1, D), lambda b, j: (b, 0, 0)),   # sin row
         *[wspec(lp[n]) for n in keys],
         pl.BlockSpec(memory_space=pltpu.ANY),        # pool_k (codes)
         pl.BlockSpec(memory_space=pltpu.ANY),        # pool_v (codes)
@@ -500,12 +505,12 @@ def _call(x, lp, pool_k, pool_v, block_table, lengths, cos, sin, *,
     # re-quantizes them, so pool contents match the reference tier's)
     kv_dt = jnp.float32 if kvq else pool_k.dtype
     out_specs = [
-        pl.BlockSpec((1, H), lambda b, j: (b, 0)),
+        pl.BlockSpec((None, 1, H), lambda b, j: (b, 0, 0)),
         pl.BlockSpec((1, Hkv, D), lambda b, j: (b, 0, 0)),
         pl.BlockSpec((1, Hkv, D), lambda b, j: (b, 0, 0)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((B, H), x.dtype),
+        jax.ShapeDtypeStruct((B, 1, H), x.dtype),
         jax.ShapeDtypeStruct((B, Hkv, D), kv_dt),
         jax.ShapeDtypeStruct((B, Hkv, D), kv_dt),
     ]
@@ -532,7 +537,7 @@ def _call(x, lp, pool_k, pool_v, block_table, lengths, cos, sin, *,
              if kvq else (pool_k, pool_v))
     cos2 = jnp.zeros((B, D), x.dtype) if cos is None else cos
     sin2 = jnp.zeros((B, D), x.dtype) if sin is None else sin
-    return pl.pallas_call(
+    x_out, k_new, v_new = pl.pallas_call(
         functools.partial(_kernel, meta=meta),
         grid=(B, nt),
         in_specs=in_specs,
@@ -542,8 +547,9 @@ def _call(x, lp, pool_k, pool_v, block_table, lengths, cos, sin, *,
                         pltpu.SemaphoreType.DMA((2, pages, n_pool))],
         interpret=use_interpret(),
     )(jnp.asarray(block_table, jnp.int32),
-      jnp.asarray(lengths, jnp.int32), x, cos2, sin2,
-      *[lp[n] for n in keys], *pools)
+      jnp.asarray(lengths, jnp.int32), x[:, None], cos2[:, None],
+      sin2[:, None], *[lp[n] for n in keys], *pools)
+    return x_out[:, 0], k_new, v_new
 
 
 def decode_block_pallas(x, lp, pool_k, pool_v, block_table, lengths, cos,

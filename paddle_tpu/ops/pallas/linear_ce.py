@@ -12,7 +12,9 @@ the grid (rows innermost) so each weight chunk's gradient block stays
 resident in VMEM while all row blocks stream through.
 
 Block sizes (block_rows, chunk) are selected through
-``ops/pallas/autotune`` (timed once per shape signature, cached).
+``ops/pallas/autotune`` (timed once per shape signature, cached); the
+backward shrinks them until its larger working set fits VMEM
+(``analysis/kernel/cost.linear_ce_bwd_blocks``).
 Weight layout is [V, H] (embedding layout); ``ops/fused_cross_entropy``
 transposes Linear-layout heads before dispatching here.
 """
@@ -233,10 +235,14 @@ def _dw_kernel(x_ref, w_ref, lab_ref, lse_ref, g_ref, dw_ref, acc_scr,
 
 
 def _bwd(x2, w, labels2, lse, g2, meta: _Meta):
+    from ...analysis.kernel import cost
     T, H = x2.shape
     V = w.shape[0]
-    br = min(meta.block_rows, _pow2_ceil(T))
-    C = min(meta.chunk, _pow2_ceil(V))
+    # the backward holds an fp32 accumulator and an output block beside
+    # the forward's blocks: shrink the tile until both kernels fit
+    br, C = cost.linear_ce_bwd_blocks(
+        min(meta.block_rows, _pow2_ceil(T)), min(meta.chunk, _pow2_ceil(V)),
+        H, x2.dtype.itemsize, w.dtype.itemsize)
     xp = _pad_rows(x2, br)
     lab = _pad_rows(labels2.reshape(-1, 1).astype(jnp.int32), br)
     lsep = _pad_rows(lse.reshape(-1, 1), br)

@@ -114,6 +114,10 @@ def unsupported_reason(spec, lp, pool_k, chunk: int) -> Optional[str]:
                 + (" in the quantized export layout"
                    if getattr(spec, "weight_dtype", None) else
                    " (MoE FFNs run the reference tier)"))
+    if not use_interpret():
+        lanes = cost.head_dim_lane_reason(spec.head_dim)
+        if lanes is not None:
+            return lanes
     wbytes = sum(lp[n].size * lp[n].dtype.itemsize for n in keys)
     return cost.prefill_block_unsupported_reason(
         hidden=spec.hidden, num_heads=spec.num_heads,

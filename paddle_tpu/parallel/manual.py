@@ -20,7 +20,7 @@ Reference semantics being matched (cited per function):
   (fleet/meta_optimizers/dygraph_optimizer/dygraph_sharding_optimizer.py:44,
   sharding/group_sharded_stage2.py:46): grads reduce-scattered to the owner
   shard, optimizer moments stored 1/shard per device, updated params
-  all-gathered — expressed per-leaf on a flattened (padded) vector.
+  all-gathered — expressed per-leaf on the leaf's (padded) ROWS.
 """
 
 from __future__ import annotations
@@ -44,12 +44,13 @@ __all__ = ["mp_copy", "fwd_psum", "vocab_parallel_embedding",
            "zero_adam_leaf_update", "local_shape", "moment_shape",
            "MOMENT_SPEC", "tree_map_with_spec"]
 
-# Flat optimizer-moment layout: [pp, mp, shard * chunk] — one fp32 chunk per
-# (pp, mp, sharding) mesh coordinate, replicated over dp/sep.
+# Optimizer-moment layout: [pp, mp, shard * rows, cols] — one fp32 chunk of
+# the leaf's rows per (pp, mp, sharding) mesh coordinate, replicated over
+# dp/sep (see moment_shape).
 MOMENT_SPEC = P(PP_AXIS, MP_AXIS, SHARDING_AXIS)
 # Expert-parallel leaves (param spec carries the dp axis — MoE expert
 # banks): every (dp, sharding) coordinate owns distinct state, so the
-# flat dim is sharded over both and NOT replicated over dp.
+# rows dim is sharded over both and NOT replicated over dp.
 MOMENT_SPEC_EP = P(PP_AXIS, MP_AXIS, (DP_AXIS, SHARDING_AXIS))
 
 
@@ -172,42 +173,46 @@ def vocab_parallel_linear_nll(x, w_local, labels, *, w_layout: str = "vh",
         axis_name=axis_name, backend="xla")
 
 
-def zero_adam_leaf_update(p, g, m_flat, v_flat, tf, *, lr, b1=0.9, b2=0.95,
+def zero_adam_leaf_update(p, g, m, v, tf, *, lr, b1=0.9, b2=0.95,
                           eps=1e-8, weight_decay=0.0,
                           axis_name: str = SHARDING_AXIS):
     """ZeRO-sharded Adam step for one (local) parameter leaf.
 
     ``p``/``g``: the device-local shard of the param and its grad (grads
     must already be reduced over data axes; the sharding-axis reduction
-    happens HERE via psum_scatter).  ``m_flat``/``v_flat``: fp32 moment
-    chunks of size ceil(p.size/shard) — each device owns 1/shard of the
-    optimizer state (stage-1/2 memory behavior,
-    reference group_sharded_stage2.py:46).  Returns (p_new, m_new, v_new).
+    happens HERE via psum_scatter).  ``m``/``v``: this device's fp32
+    moment chunk ``[rows, cols]`` — the leaf viewed as ``[R, cols]``
+    (:func:`moment_shape`) with its rows split over the sharding axis,
+    so each device owns 1/shard of the optimizer state (stage-1/2
+    memory behavior, reference group_sharded_stage2.py:46).  Returns
+    (p_new, m_new, v_new).
     """
     shard = lax.axis_size(axis_name)
-    shape, n = p.shape, p.size
-    chunk = m_flat.size
-    pad = shard * chunk - n
-    g32 = jnp.pad(g.astype(jnp.float32).reshape(-1), (0, pad))
-    g32 = g32.reshape(shard, chunk)
+    shape = p.shape
+    rows, cols = m.shape
+    R = p.size // cols
+    pad = shard * rows - R
+
+    def chunks(a):                       # leaf -> [shard, rows, cols] fp32
+        a = a.astype(jnp.float32).reshape(R, cols)
+        return jnp.pad(a, ((0, pad), (0, 0))).reshape(shard, rows, cols)
+
     # reduce-scatter: sum over the sharding axis, keep only our chunk
-    g_loc = lax.psum_scatter(g32, axis_name, scatter_dimension=0,
+    g_loc = lax.psum_scatter(chunks(g), axis_name, scatter_dimension=0,
                              tiled=False)
     idx = lax.axis_index(axis_name)
-    p32 = jnp.pad(p.astype(jnp.float32).reshape(-1), (0, pad))
-    p_loc = lax.dynamic_index_in_dim(p32.reshape(shard, chunk), idx, 0,
-                                     keepdims=False)
-    m2 = b1 * m_flat + (1 - b1) * g_loc
-    v2 = b2 * v_flat + (1 - b2) * g_loc * g_loc
+    p_loc = lax.dynamic_index_in_dim(chunks(p), idx, 0, keepdims=False)
+    m2 = b1 * m + (1 - b1) * g_loc
+    v2 = b2 * v + (1 - b2) * g_loc * g_loc
     mh = m2 / (1 - b1 ** tf)
     vh = v2 / (1 - b2 ** tf)
     upd = mh / (jnp.sqrt(vh) + eps)
     if weight_decay:
         upd = upd + weight_decay * p_loc
     p_loc = p_loc - lr * upd
-    p_new = lax.all_gather(p_loc, axis_name, tiled=False).reshape(-1)
-    p_new = p_new[:n].reshape(shape).astype(p.dtype)
-    return p_new, m2, v2
+    p_new = lax.all_gather(p_loc, axis_name, tiled=False)
+    p_new = p_new.reshape(shard * rows, cols)[:R]
+    return p_new.reshape(shape).astype(p.dtype), m2, v2
 
 
 def vpp_block_layout(blk_specs, S: int, vpp: int, num_layers: int):
@@ -633,7 +638,8 @@ def build_hybrid_train_step(*, topo: HybridTopology, param_specs,
                     p, g, m_leaf, v_leaf, tf, lr=learning_rate, b1=b1,
                     b2=b2, eps=adam_eps, weight_decay=weight_decay)
             p2, m2, v2 = zero_adam_leaf_update(
-                p, g, m_leaf.reshape(-1), v_leaf.reshape(-1), tf,
+                p, g, m_leaf.reshape(m_leaf.shape[-2:]),
+                v_leaf.reshape(v_leaf.shape[-2:]), tf,
                 lr=learning_rate, b1=b1, b2=b2, eps=adam_eps,
                 weight_decay=weight_decay)
             return p2, m2.reshape(m_leaf.shape), v2.reshape(v_leaf.shape)
@@ -734,18 +740,47 @@ def local_shape(shape: Tuple[int, ...], spec: P,
     return tuple(out)
 
 
-def moment_shape(param_shape: Tuple[int, ...], spec: P,
-                 topo: HybridTopology) -> Tuple[int, int, int]:
-    """Global shape of the flat ZeRO moment buffer for one param leaf:
-    [pp, mp, shard*chunk] with chunk = ceil(local_numel/shard).  Expert
-    (dp-sharded) leaves get a dp factor on the flat dim to match
-    MOMENT_SPEC_EP — each data rank's experts carry their own moments."""
-    n = int(np.prod(local_shape(param_shape, spec, topo))) or 1
+def train_state_bytes(params, param_specs, topo: HybridTopology) -> int:
+    """Per-device HBM a ZeRO stage-2 Adam step pins: the local param
+    shards, their gradients (same dtype) and the two fp32 moments, which
+    are split over the sharding axis.  ``params`` may be abstract
+    (``jax.eval_shape`` of the init).  Stage 3 pins less (params are
+    sharded at rest too), so for it this is an upper bound."""
     shard = topo.axis_size(SHARDING_AXIS)
-    chunk = -(-n // shard)
+    total = 0
+
+    def add(leaf, spec):
+        nonlocal total
+        n = int(np.prod(local_shape(leaf.shape, spec, topo)))
+        total += 2 * n * leaf.dtype.itemsize + 8 * -(-n // shard)
+
+    tree_map_with_spec(add, params, param_specs)
+    return total
+
+
+def moment_shape(param_shape: Tuple[int, ...], spec: P,
+                 topo: HybridTopology) -> Tuple[int, int, int, int]:
+    """Global shape of the ZeRO moment buffer for one param leaf:
+    ``[pp, mp, shard*rows, cols]``.  The device-local leaf is viewed as
+    ``[R, cols]`` — ``cols`` its last dim, ``R`` the product of the
+    others — and its ROWS are chunked over the sharding axis,
+    ``rows = ceil(R/shard)``.  Expert (dp-sharded) leaves get a dp
+    factor on the rows dim to match MOMENT_SPEC_EP — each data rank's
+    experts carry their own moments.
+
+    Rows, not a flat 1-D chunk: merging leading dims keeps the minor
+    dim and its tiling whole, so the update reads params and grads
+    where they lie.  A flat chunk made the TPU re-tile every param and
+    grad each step into 1-D fp32 copies: compiled for a v5e, ONE
+    [4, 4096, 11008] leaf took 281 s, 145 MiB of code and 2 GiB of
+    temporaries against 1.6 s, 0.2 MiB and none for this layout."""
+    ls = local_shape(param_shape, spec, topo)
+    cols = ls[-1] if ls else 1
+    R = int(np.prod(ls[:-1])) or 1
+    shard = topo.axis_size(SHARDING_AXIS)
     dpf = topo.axis_size(DP_AXIS) if spec_has_axis(spec, DP_AXIS) else 1
     return (topo.axis_size(PP_AXIS), topo.axis_size(MP_AXIS),
-            dpf * shard * chunk)
+            dpf * shard * -(-R // shard), cols)
 
 
 def tree_map_with_spec(fn, tree, specs):

@@ -96,7 +96,7 @@ from .frontend import (RequestAborted, RequestHandle, RequestRejected,
 from .metrics import ServeMetrics
 
 __all__ = ["HttpServingServer", "HttpTransport", "WireHandle",
-           "iter_sse", "main"]
+           "iter_sse", "parse_args", "build_frontend", "main"]
 
 
 # ---------------------------------------------------------------------
@@ -1131,7 +1131,10 @@ class HttpTransport:
 # ---------------------------------------------------------------------
 # CLI: python -m paddle_tpu.serving.http --model llama_tiny --port 8821
 # ---------------------------------------------------------------------
-def _build_frontend(args) -> ServingFrontend:
+def build_frontend(args) -> ServingFrontend:
+    """The CLI's engine + frontend from :func:`parse_args` output
+    (``chip_smoke.py`` builds its server through the same two calls).
+    Weights are seeded params only — no train state is ever built."""
     import jax
 
     from .. import parallel as dist
@@ -1144,11 +1147,12 @@ def _build_frontend(args) -> ServingFrontend:
     if cfg_fn is None:
         raise SystemExit(f"unknown model {args.model!r} (the zoo has "
                          "llama_tiny / llama_7b / ...)")
-    cfg = cfg_fn()
+    cfg_kw = {k: v for k, v in (("dtype", args.dtype),
+                                ("num_layers", args.num_layers))
+              if v is not None}
+    cfg = cfg_fn(**cfg_kw)
     topo = dist.init_topology(devices=jax.devices()[:1])
-    _, init_fn = llama_zoo.build_llama_train_step(cfg, topo,
-                                                  num_microbatches=1)
-    params = init_fn(args.seed)["params"]
+    params = llama_zoo.init_llama_params(cfg, topo, args.seed)
     set_topology(HybridTopology())
     eng_kw: Dict[str, Any] = dict(
         max_batch=args.max_batch, block_size=args.block_size,
@@ -1173,7 +1177,7 @@ def _build_frontend(args) -> ServingFrontend:
         stream_capacity=args.stream_capacity)
 
 
-def main(argv: Optional[List[str]] = None) -> int:
+def parse_args(argv: Optional[List[str]] = None):
     import argparse
 
     ap = argparse.ArgumentParser(
@@ -1182,6 +1186,10 @@ def main(argv: Optional[List[str]] = None) -> int:
                     "continuous-batching engine")
     ap.add_argument("--model", default="llama_tiny",
                     help="model-zoo config name (default: llama_tiny)")
+    ap.add_argument("--dtype", default=None,
+                    help="override the config's dtype (e.g. bfloat16)")
+    ap.add_argument("--num-layers", type=int, default=None,
+                    help="override the config's depth")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8821)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -1198,13 +1206,19 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--stream-capacity", type=int, default=512)
     ap.add_argument("--drain-timeout-s", type=float, default=30.0)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv: Optional[List[str]] = None) -> int:
+    args = parse_args(argv)
+
+    from ..core.device import enable_compile_cache
     from ..observability import REGISTRY
     from ..observability.tracing import TRACER
+    enable_compile_cache()
     REGISTRY.enable()
     TRACER.enable()
-    fe = _build_frontend(args)
+    fe = build_frontend(args)
     server = HttpServingServer(fe, host=args.host, port=args.port,
                                drain_timeout_s=args.drain_timeout_s)
     server.install_signal_handlers()

@@ -8,39 +8,23 @@ import os
 # Sequential thunk order: XLA:CPU's concurrency-optimized scheduler can run
 # independent collectives in different orders on different virtual devices
 # and deadlock the in-process rendezvous (see __graft_entry__.py).
+# The collective stuck/terminate watchdogs widen the rendezvous fuse for
+# hosts where the virtual devices' threads progress unevenly.
 _FLAGS = ("--xla_force_host_platform_device_count=8 "
-          "--xla_cpu_enable_concurrency_optimized_scheduler=false")
-# Collective stuck/terminate watchdogs are only known to newer XLA builds;
-# an UNKNOWN flag in XLA_FLAGS is a FATAL abort at first backend init
-# (parse_flags_from_env.cc CHECK), taking the whole pytest process down —
-# so probe them in a throwaway subprocess before adopting them.
-_OPT_FLAGS = ("--xla_cpu_collective_call_warn_stuck_timeout_seconds=120 "
-              "--xla_cpu_collective_call_terminate_timeout_seconds=480")
-
-
-def _flags_supported(flags: str) -> bool:
-    import subprocess
-    import sys
-    try:
-        return subprocess.run(
-            [sys.executable, "-c", "import jax; jax.local_devices()"],
-            env=dict(os.environ, XLA_FLAGS=flags, JAX_PLATFORMS="cpu"),
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-            timeout=120).returncode == 0
-    except Exception:
-        return False
-
+          "--xla_cpu_enable_concurrency_optimized_scheduler=false "
+          "--xla_cpu_collective_call_warn_stuck_timeout_seconds=120 "
+          "--xla_cpu_collective_call_terminate_timeout_seconds=480")
 
 if "--xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
-    if _flags_supported(_FLAGS + " " + _OPT_FLAGS):
-        _FLAGS = _FLAGS + " " + _OPT_FLAGS
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
                                + _FLAGS).strip()
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
+# the hardware lane (tests/test_pallas_hw.py) names its platform through
+# JAX_PLATFORMS; everything else runs on the CPU
+jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS") or "cpu")
 
 # Persistent XLA compilation cache: the slow lane is dominated by
 # whole-model compiles on one CPU core; caching executables across test
@@ -51,6 +35,8 @@ jax.config.update("jax_platforms", "cpu")
 # SIGSEGV/SIGILL at run time (observed: resnet conv compile crashed the
 # slow lane after the round migrated hosts).  Namespace the cache by a
 # machine fingerprint so each host keeps its own executables.
+# JAX_COMPILATION_CACHE_DIR, when set, places the cache instead and no
+# directory is set here.
 import hashlib as _hashlib
 import platform as _platform
 
@@ -75,8 +61,9 @@ _cache_base = os.environ.get("PT_TEST_COMPILE_CACHE",
 # the eviction scan needs — a stale dir breaks every new cache write
 _cache_dir = f"{_cache_base}_{_machine_tag()}_v2"
 try:
-    os.makedirs(_cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(_cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", _cache_dir)
     # 0.0: with per-module clear_caches() below, sub-second jits must
     # persist too or every module pays their recompiles from scratch
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
@@ -96,29 +83,34 @@ def disable_persistent_compile_cache():
     """Opt the calling module out of the persistent XLA compilation
     cache; returns a restore callable.
 
-    This jax/XLA:CPU build (0.4.37) mis-executes DONATED programs
-    DESERIALIZED from the persistent compilation cache (the ISSUE 2 bug
-    — see aot/artifact.py:fresh_backend_compile and the PR 8
-    test_parallel.py deflake).  Modules whose tests compile bit-for-bit
-    identical donating programs hit the broken deserialize path on warm
-    reruns and drift nondeterministically; a module-scoped autouse
-    fixture built on this helper makes every compile fresh (bit-exact).
+    Modules whose tests pin bit-for-bit results of donating programs
+    use a module-scoped autouse fixture built on this helper, so every
+    compile is fresh: XLA:CPU programs with donated buffers that were
+    DESERIALIZED from the persistent cache drifted nondeterministically
+    on warm reruns (ISSUE 2, the PR 8 test_parallel.py deflake — seen
+    under jax 0.4.37 and not re-proven safe since, so the opt-out
+    stays).
 
-    The flag alone is not enough mid-suite: ``is_cache_used`` memoizes
-    its decision at the first compile of the process, so the memo must
-    be reset on entry — and on exit, so later modules re-enable."""
-    from jax._src import compilation_cache as _cc
+    The switch is ``aot.artifact.fresh_backend_compile`` (the cache's
+    enable flag plus the is-cache-used memo reset, so a directory that
+    ``JAX_COMPILATION_CACHE_DIR`` set stays as it was found)."""
+    from paddle_tpu.aot.artifact import fresh_backend_compile
 
-    prev = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    _cc.reset_cache()         # drop the is-cache-used memo
+    guard = fresh_backend_compile()
+    guard.__enter__()
     jax.clear_caches()        # drop executables already deserialized
+    return lambda: guard.__exit__(None, None, None)
 
-    def restore():
-        jax.config.update("jax_compilation_cache_dir", prev)
-        _cc.reset_cache()
 
-    return restore
+@pytest.fixture
+def tpu():
+    """For tests that need the chip: ask THIS process's backend (one
+    process holds a chip, so no child may probe it) and skip without
+    one.  This file pins the CPU unless ``JAX_PLATFORMS`` says
+    otherwise: on the chip run ``JAX_PLATFORMS=tpu pytest
+    tests/test_pallas_hw.py -m tpu``."""
+    if jax.devices()[0].platform != "tpu":
+        pytest.skip("this process's JAX backend is not a TPU")
 
 
 @pytest.fixture(autouse=True, scope="module")

@@ -7,9 +7,9 @@ The load-bearing contracts:
   (train step params after optimizer steps; engine greedy tokens);
 * a warm start performs ZERO backend compiles (CompileMonitor-pinned);
 * every way an artifact can be unusable — version skew, geometry drift,
-  CRC corruption (tests/faults.py bitrot injector), the jax-0.4.37
-  donated-deserialize bug — either raises a TYPED AotError or falls
-  back to a fresh compile with the reason recorded, never runs a wrong
+  CRC corruption (tests/faults.py bitrot injector), devices this
+  process does not have — either raises a TYPED AotError or falls back
+  to a fresh compile with the reason recorded, never runs a wrong
   program.
 """
 
@@ -22,9 +22,9 @@ import pytest
 import paddle_tpu as pt
 import paddle_tpu.nn as nn
 from paddle_tpu import parallel as dist
-from paddle_tpu.aot import (AotArtifactCorruptError, AotDonationError,
+from paddle_tpu.aot import (AotArtifactCorruptError,
                             AotManifestMismatchError, ArtifactStore,
-                            ShapeBucketRegistry, donation_deserialize_safe,
+                            ShapeBucketRegistry, export_compiled,
                             export_engine, export_jit_apply,
                             export_train_step)
 from paddle_tpu.core import rng as core_rng
@@ -429,23 +429,25 @@ def test_train_step_corrupt_artifact_falls_back(tmp_path):
     assert m._aot_error is not None and "CRC" in m._aot_error
 
 
-@pytest.mark.skipif(donation_deserialize_safe(),
-                    reason="donated deserialized executables are safe "
-                           "on this platform")
-def test_donation_gate_refuses_donated_artifact(tmp_path):
-    """On the known-broken jax-0.4.37 XLA:CPU path, a DONATED exported
-    step must be refused at load (AotDonationError) and the Model must
-    fall back to fresh compile rather than risk silent param
-    corruption."""
-    x, y = _batch()
-    store = export_train_step(_make_model(), [x], [y], str(tmp_path),
-                              donate=True)
-    with pytest.raises(AotDonationError, match="donated"):
-        store.get("train_step_init")
-    m = _make_model(aot_dir=str(tmp_path))
-    losses, _ = m.train_batch([x], [y])
-    assert np.isfinite(losses[0])
-    assert "donated" in m._aot_error
+def test_one_device_artifact_loads_among_many_devices(tmp_path):
+    """A program exported for ONE device loads onto that device and
+    runs while the process holds several (conftest's 8 virtual CPUs).
+    Told nothing, jax 0.9's loader assumes every local device executes
+    and the call fails with "Expected args ... to have 8 shards"."""
+    import jax.numpy as jnp
+    assert len(jax.devices()) > 1
+    dev = jax.devices()[3]
+    x = jax.device_put(jnp.arange(8.0), dev)
+    store = export_compiled(str(tmp_path), "double", jax.jit(lambda a: a * 2),
+                            (x,), config={"kind": "one_device"})
+    assert store.entry("double")["device_ids"] == [dev.id]
+    out = ArtifactStore(str(tmp_path)).get("double")(x)
+    assert out.devices() == {dev}
+    np.testing.assert_array_equal(np.asarray(out), np.arange(8.0) * 2)
+    # an artifact for a device this process lacks is a typed mismatch
+    store.entry("double")["device_ids"] = [10 ** 6]
+    with pytest.raises(AotManifestMismatchError, match="device ids"):
+        store.get("double")
 
 
 def test_export_jit_apply_roundtrip(tmp_path):

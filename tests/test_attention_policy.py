@@ -1,5 +1,5 @@
 """Attention backend policy (ops/attention_policy) — decision table
-pinned to the round-4 v5e measurements in BASELINE.md."""
+pinned to the round-4 v5e sweep (record removed)."""
 
 import pytest
 
@@ -22,7 +22,8 @@ class TestDenseResidualBytes:
 
 
 class TestPreferFlash:
-    """Each row reproduces a measured v5e outcome (BASELINE.md round 4)."""
+    """Each row reproduces a measured v5e outcome (round-4 v5e sweep,
+    record removed)."""
 
     def test_gpt125m_b8_dense(self):
         # b8 s1024: dense ran AND was 18% faster -> policy must pick dense
@@ -48,6 +49,16 @@ class TestPreferFlash:
         # inf HBM (CPU host) -> always dense
         assert not prefer_flash((64, 4096, 32, 128), (64, 4096, 32, 128),
                                 48, remat=False, hbm_bytes=float("inf"))
+
+    def test_train_state_shrinks_the_budget(self):
+        # llama_7b width, 4 layers, b4 s2048 remat: 12.9 GB of params,
+        # grads and fp32 moments leave 4 GB of a v5e — dense, which the
+        # state-blind budget picked, was refused by the TPU compiler
+        # (17.87 of 15.75 GiB, PR 22); flash compiles
+        shape = (4, 2048, 32, 128)
+        assert not prefer_flash(shape, shape, 4, True, HBM)
+        assert prefer_flash(shape, shape, 4, True, HBM,
+                            state_bytes=12.86e9)
 
     def test_pp_divides_layers(self):
         # fewer resident layers (pp sharding) tips the same shape to dense
@@ -92,7 +103,7 @@ class TestModelWiring:
     def test_gpt_auto_builds_on_cpu(self):
         # use_flash=None on a CPU host must fall back to the dense path
         # (no Pallas import) and still train — covered by building a tiny
-        # step; the TPU branch is exercised by bench_sweep flash=None rows
+        # step; the TPU branch is exercised on the chip (chip_smoke.py)
         import numpy as np
         import jax
         from paddle_tpu.models.gpt import GPTConfig, build_gpt_train_step

@@ -150,6 +150,19 @@ class TestCheckpointManager:
         assert not [n for n in os.listdir(str(tmp_path))
                     if n.startswith(".tmp-")]
 
+    def test_sweep_spares_another_writers_temp_file(self, tmp_path):
+        """``Model.save`` publishes ``epoch_N.*`` in the same directory
+        from another thread: the manager's sweep must not unlink that
+        writer's in-flight temp file (its ``os.replace`` then failed —
+        the async_resume flake), only its own stragglers."""
+        m = CheckpointManager(str(tmp_path), keep_last=3)
+        theirs = tmp_path / ".tmp-abc123-epoch_3.pdopt"
+        ours = tmp_path / ".tmp-def456-ckpt-00000007.pdckpt"
+        for f in (theirs, ours):
+            f.write_bytes(b"partial")
+        m.save(_state(1), 1)
+        assert theirs.exists() and not ours.exists()
+
     def test_crash_before_rename_latest_stays_good(self, tmp_path,
                                                    monkeypatch):
         m = CheckpointManager(str(tmp_path), keep_last=3)

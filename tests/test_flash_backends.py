@@ -13,7 +13,6 @@ import numpy as np
 import pytest
 
 from paddle_tpu.ops.pallas import flash_backends as fb
-from test_pallas_hw import needs_tpu   # shared no-TPU skip gate
 
 
 def _dense_ref(q, k, v, scale, causal):
@@ -88,7 +87,7 @@ def test_jax_flash_backend_interpret():
 
 
 @pytest.mark.tpu
-@needs_tpu
+@pytest.mark.usefixtures("tpu")
 @pytest.mark.parametrize("backend", ["ours", "jax_flash", "splash"])
 @pytest.mark.parametrize("hq,hkv", [(8, 8), (8, 2)])
 def test_backends_match_dense_on_tpu(backend, hq, hkv):
@@ -103,7 +102,7 @@ def test_backends_match_dense_on_tpu(backend, hq, hkv):
 
 
 @pytest.mark.tpu
-@needs_tpu
+@pytest.mark.usefixtures("tpu")
 @pytest.mark.parametrize("backend", ["ours", "jax_flash", "splash"])
 def test_backend_grads_finite_on_tpu(backend):
     q, k, v = _qkv(1, 512, 512, 4, 4, 64)

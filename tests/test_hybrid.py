@@ -242,17 +242,17 @@ def test_mp2_step_uses_pallas_flash():
 
 def test_mp2_sharding4_moments_are_sharded():
     """ZeRO stage-1/2: optimizer moments are stored 1/shard per device
-    (flat chunk layout over the sharding axis)."""
+    (the leaf's rows chunked over the sharding axis)."""
     topo = dist.init_topology(mp=2, sharding=4)
     cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
                     num_heads=4, max_position_embeddings=64)
     step_fn, init_fn = build_gpt_train_step(cfg, topo, num_microbatches=1)
     state = init_fn(0)
     m_wte = state["opt"]["m"]["wte"]
-    # wte local shard = (128/2)*32 = 2048 elems; chunk = 2048/4 = 512
-    assert m_wte.shape == (1, 2, 4 * 512)
+    # wte local shard = [128/2, 32]; its 64 rows chunk 4 ways -> 16 rows
+    assert m_wte.shape == (1, 2, 4 * 16, 32)
     shard_bytes = [s.data.nbytes for s in m_wte.addressable_shards]
-    assert max(shard_bytes) == 512 * 4  # fp32 chunk per device
+    assert max(shard_bytes) == 16 * 32 * 4  # fp32 chunk per device
 
 
 # ---------------------------------------------------------------------------

@@ -409,6 +409,27 @@ def test_linear_ce_candidate_filter_uses_cost():
     assert not cost.linear_ce_fits(512, 2048, 8192)
 
 
+def test_linear_ce_backward_tile_fits_its_own_working_set():
+    """The backward holds an fp32 accumulator and an output block beside
+    the forward's blocks, all double-buffered: at the forward's
+    (256, 512) tile and H 4096 bf16 the closed form gives the 20 MiB the
+    v5e compiler reported when it refused the kernel (PR 22), so the
+    backward halves its tile until it fits the budget."""
+    need = cost.linear_ce_bwd_vmem(block_rows=256, chunk=512, hidden=4096,
+                                   x_itemsize=2, w_itemsize=2)
+    assert need == 28 * 2 ** 20            # dw; dx alone is 20 MiB
+    br, c = cost.linear_ce_bwd_blocks(256, 512, 4096, 2, 2)
+    assert (br, c) == (128, 128)
+    assert cost.fits(cost.linear_ce_bwd_vmem(
+        block_rows=br, chunk=c, hidden=4096, x_itemsize=2, w_itemsize=2))
+    # a tile that already fits is left alone
+    assert cost.linear_ce_bwd_blocks(128, 128, 256, 4, 4) == (128, 128)
+    # past the width whose smallest tile fits, the reason names the cause
+    assert cost.linear_ce_unsupported_reason(4096, 2, 2) is None
+    reason = cost.linear_ce_unsupported_reason(16384, 4, 4)
+    assert "VMEM" in reason and "hidden=16384" in reason
+
+
 # ---------------------------------------------------------------------------
 # acceptance grep: no second hardcoded VMEM constant
 # ---------------------------------------------------------------------------

@@ -14,6 +14,25 @@ def test_library_builds():
     assert native.available(), "C++ core failed to build (g++ is baked in)"
 
 
+def test_missing_compiler_is_said_not_silent(monkeypatch, tmp_path,
+                                             capsys):
+    """A checkout holds the C++ source, not the .so: with no g++ the
+    build failure is printed and kept, and the python fallbacks serve."""
+    import subprocess
+
+    def no_gxx(*a, **k):
+        raise FileNotFoundError(2, "No such file or directory: 'g++'")
+
+    monkeypatch.setattr(native, "_LIB_PATH", str(tmp_path / "lib.so"))
+    for name, val in (("_lib", None), ("_tried", False), ("_error", None)):
+        monkeypatch.setattr(native, name, val)
+    monkeypatch.setattr(subprocess, "run", no_gxx)
+    assert not native.available()
+    assert "g++" in native.unavailable_reason()
+    assert "paddle_tpu.native: building" in capsys.readouterr().err
+    assert sorted(native.shuffle_indices(10, 0).tolist()) == list(range(10))
+
+
 def test_shuffle_indices_permutation():
     idx = native.shuffle_indices(1000, seed=42)
     assert sorted(idx.tolist()) == list(range(1000))

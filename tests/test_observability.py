@@ -518,9 +518,12 @@ class TestHw:
             device_kind = "TPU v4"
             platform = "tpu"
         assert peak_flops_per_chip(Dev()) == 275e12
+        Dev.device_kind = "TPU v5 lite"      # what a v5e chip reports
+        assert peak_flops_per_chip(Dev()) == 197e12
         Dev.device_kind = "cpu"
         Dev.platform = "cpu"
-        assert peak_flops_per_chip(Dev()) == 1e12
+        with pytest.raises(KeyError, match="device_kind 'cpu'"):
+            peak_flops_per_chip(Dev())
 
     def test_estimate_mfu(self):
         # 1e4 tokens/s * 6 * 1e9 params = 6e13 FLOP/s on a 197e12 chip

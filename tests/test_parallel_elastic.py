@@ -81,8 +81,7 @@ def _make_trainer(*, dp=1, sharding=1, batch=12, stage=2, **kw):
 def test_kill_dp_reshape_carryover_bit_identical():
     """dp4 killed at step 3 reshapes to dp3 with ZeRO state gathered
     from the survivors; every post-reshape loss is bitwise equal to an
-    uninterrupted dp3 run (which, state being carried exactly, extends
-    to the whole trajectory here)."""
+    uninterrupted dp3 run."""
     ref = _make_trainer(dp=3)
     ref_losses = ref.run(6)
 
@@ -99,8 +98,10 @@ def test_kill_dp_reshape_carryover_bit_identical():
     # the pin: post-reshape trajectory ≡ uninterrupted run at the new
     # topology (bitwise — no tolerance)
     assert losses[3:] == ref_losses[3:]
-    # carryover was exact, so the pre-kill dp4 prefix matches too
-    assert losses == ref_losses
+    # the pre-kill prefix ran on dp4, the reference on dp3: the same
+    # numbers summed in another order, so equal to the last ulp or two,
+    # not bitwise (it differed by 1 ulp under jax 0.9 on this CPU)
+    np.testing.assert_allclose(losses[:3], ref_losses[:3], rtol=1e-6)
 
 
 def test_kill_dp8_divisor_fallback():

@@ -900,6 +900,41 @@ def test_sigterm_triggers_graceful_shutdown(model, aot_dir):
         signal.signal(signal.SIGINT, old_int)
 
 
+def test_cli_builds_the_model_its_flags_name():
+    """`--dtype` / `--num-layers` reach the zoo config, and the weights
+    are the seeded params a train state starts from — built WITHOUT the
+    train state (two fp32 Adam moment trees are 4x the bf16 weights:
+    27 GB at llama_7b width and 16 layers, on a 16 GB chip)."""
+    from paddle_tpu.serving.http import build_frontend, parse_args
+    fe = build_frontend(parse_args(
+        ["--model", "llama_tiny", "--dtype", "bfloat16", "--num-layers",
+         "1", "--num-blocks", "16", "--prefill-buckets", "8", "32",
+         "--seed", "3"]))
+    try:
+        eng = fe.engine
+        assert (eng.cfg.dtype, eng.cfg.num_layers) == ("bfloat16", 1)
+        assert set(eng.params) == {"wte", "head", "lnf_w", "blocks"}
+        topo = dist.init_topology(devices=jax.devices()[:1])
+        _, init_fn = build_llama_train_step(eng.cfg, topo,
+                                            num_microbatches=1)
+        ref = init_fn(3)["params"]
+        set_topology(HybridTopology())
+        for got, want in zip(jax.tree.leaves(eng.params),
+                             jax.tree.leaves(ref)):
+            assert got.dtype == want.dtype == np.dtype("bfloat16")
+            np.testing.assert_array_equal(
+                np.asarray(got.astype("float32")),
+                np.asarray(want.astype("float32")))
+        # the engine names the tier each program runs on, and why
+        tiers = eng.kernel_tiers()
+        assert set(tiers) == {"decode_block", "prefill_block[8]",
+                              "prefill_block[32]"}
+        assert all(t["tier"] == "xla" and "not on a TPU" in t["reason"]
+                   for t in tiers.values())
+    finally:
+        fe.close()
+
+
 # ---------------------------------------------------------------------
 # loadgen over the wire
 # ---------------------------------------------------------------------

@@ -57,13 +57,12 @@ def _tiny_llama():
     import jax
     import numpy as np
     from paddle_tpu import parallel as dist
-    from paddle_tpu.models.llama import build_llama_train_step, llama_tiny
+    from paddle_tpu.models.llama import init_llama_params, llama_tiny
     from paddle_tpu.parallel.topology import HybridTopology, set_topology
 
     cfg = llama_tiny()
     topo = dist.init_topology(devices=jax.devices()[:1])
-    _, init_fn = build_llama_train_step(cfg, topo, num_microbatches=1)
-    params = init_fn(0)["params"]
+    params = init_llama_params(cfg, topo, 0)
     set_topology(HybridTopology())
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
