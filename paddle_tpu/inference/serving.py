@@ -147,6 +147,19 @@ def build_sampler():
     return jax.vmap(one)
 
 
+def sched_ratios(s: Dict[str, object]) -> Dict[str, object]:
+    """``scheduler_stats`` counters (one engine's, or a fleet's summed)
+    plus the two ratios they exist for; None before the first prefill
+    / decode step."""
+    disp = s.get("prefill_tokens_dispatched", 0)
+    steps = s.get("decode_slot_steps", 0)
+    s["bucket_fill"] = (s.get("prefill_tokens_computed", 0) / disp
+                        if disp else None)
+    s["stalled_share"] = (s.get("stalled_slot_iterations", 0) / steps
+                          if steps else None)
+    return s
+
+
 class ContinuousBatchingEngine:
     """Llama-family continuous-batching engine (greedy by default,
     per-request sampling via temperature/top_k/top_p on add_request).
@@ -356,6 +369,18 @@ class ContinuousBatchingEngine:
         self.decode_steps = 0
         self.decode_slot_steps = 0
         self.decode_tokens = 0
+        # scheduler accounting at the timeline's boundaries (ISSUE 27),
+        # always on, read by scheduler_stats(): prefill_tokens_dispatched
+        # counts PADDED chunk sizes; stalled_slot_iterations sums, over
+        # the iterations that ran a prefill, the streams that were live
+        # and waited through it
+        self.admissions = 0
+        self.prefill_chunks = 0
+        self.prefill_tokens_dispatched = 0
+        self.stalled_slot_iterations = 0
+        # the engine timeline while the tracer is on, else None: set
+        # once per step(), read by the phases underneath it
+        self._tl = None
         _spec_programs = {}
         if spec_config is not None:
             spec_config.validate_against(cfg)
@@ -550,13 +575,19 @@ class ContinuousBatchingEngine:
         bt_row = jnp.asarray(self.block_table[slot])
         pos, off = start, 0
         logits = None
+        tl = self._tl
         for size, valid in self._buckets.plan_chunks(len(suffix)):
+            sp = tl and tl.enter("prefill_chunk", size=size, valid=valid)
             toks = np.zeros((size,), np.int32)
             toks[:valid] = suffix[off:off + valid]
             fill = self._bucket_fill(size)
             self.pool_k, self.pool_v, logits = fill(
                 self.params, self.pool_k, self.pool_v, bt_row,
                 jnp.int32(pos), jnp.asarray(toks), jnp.int32(valid))
+            self.prefill_chunks += 1
+            self.prefill_tokens_dispatched += size
+            if tl:
+                tl.leave(sp)
             pos += valid
             off += valid
         return logits
@@ -1167,6 +1198,7 @@ class ContinuousBatchingEngine:
         # and offload restores shrink it; padding never counts)
         self.stats["prefill_tokens_computed"] += T0 - L * self.BS
         table = self.slot_pages[slot]
+        tl = self._tl
         if self._buckets is not None:
             # declared-bucket prefill (cold prompts AND cache-hit
             # suffixes): fixed chunk programs, no per-length jit
@@ -1180,13 +1212,21 @@ class ContinuousBatchingEngine:
             # for every quant admission keeps greedy output
             # bit-identical across cold/hit/replay paths
             suffix = req.prompt[L * self.BS:]
+            sp = tl and tl.enter("prefill_chunk", size=len(suffix),
+                                valid=len(suffix))
             fill = self._chunk_fill(len(suffix))
             self.pool_k, self.pool_v, logits = fill(
                 self.params, self.pool_k, self.pool_v,
                 jnp.asarray(self.block_table[slot]),
                 jnp.int32(L * self.BS), jnp.asarray(suffix))
+            self.prefill_chunks += 1
+            self.prefill_tokens_dispatched += len(suffix)
+            if tl:
+                tl.leave(sp)
             return logits
-        # dense prefill, jitted once per distinct prompt length
+        # dense prefill, jitted once per distinct prompt length: one
+        # unpadded chunk on the timeline
+        sp = tl and tl.enter("prefill_chunk", size=T0, valid=T0)
         jprefill = self._prefill_cache.get(T0)
         if jprefill is None:
             prefill, _ = build_llama_decoder(self.cfg, T0,
@@ -1211,6 +1251,10 @@ class ContinuousBatchingEngine:
             paged_view(kc).astype(self.pool_k.dtype))
         self.pool_v = self.pool_v.at[:, pages].set(
             paged_view(vc).astype(self.pool_v.dtype))
+        self.prefill_chunks += 1
+        self.prefill_tokens_dispatched += T0
+        if tl:
+            tl.leave(sp)
         return logits
 
     def _admit(self) -> None:
@@ -1223,6 +1267,10 @@ class ContinuousBatchingEngine:
         recomputing.  Under saturation, strictly-lower-priority running
         requests are evicted for higher-priority waiters
         (``_preempt_for_priority``)."""
+        tl = self._tl
+        running = self.active_requests
+        adm0, chunks0 = self.admissions, self.prefill_chunks
+        sp_admit = tl and tl.enter("admit", running=running)
         if self.enable_preemption:
             self._preempt_for_priority()
         for slot in range(self.B):
@@ -1236,12 +1284,14 @@ class ContinuousBatchingEngine:
             if snap is not None:
                 if not self._restore_preempted(slot, req, idx, snap):
                     break              # head-of-line waits for pages
+                self.admissions += 1
                 continue
             if req.out:
                 # preempted, but the bounded spill tier evicted the
                 # snapshot: demoted to replay-from-prefix
                 if not self._replay_into_slot(slot, req, idx):
                     break              # head-of-line waits for pages
+                self.admissions += 1
                 continue
             T0 = len(req.prompt)
             total = T0 + req.max_new_tokens
@@ -1275,6 +1325,13 @@ class ContinuousBatchingEngine:
                              restored_blocks=restored,
                              tokens_skipped=(L + restored) * self.BS)
                 t_pf = tr.now()
+            # the same interval on the timeline, joined by rid (the
+            # trace's outermost id where a router or supervisor renumbers)
+            sp_pf = tl and tl.enter(
+                "prefill", tokens=T0,
+                rid=req.req_id if tr is None or tr.rid is None else tr.rid,
+                cached_tokens=(L + restored) * self.BS)
+            chunks_pf = self.prefill_chunks
             table = shared + priv
             self.block_table[slot, :] = -1
             self.block_table[slot, :need] = table
@@ -1282,8 +1339,11 @@ class ContinuousBatchingEngine:
             try:
                 logits = self._prefill_into_slot(slot, req, L + restored)
                 self._register_prefix(req.prompt, table)
+                sp = tl and tl.enter("first_token_fetch")
                 first = self._pick_token(req, np.asarray(logits)[0],
                                          position=T0)
+                if tl:
+                    tl.leave(sp)
             except BaseException:
                 # exactly-once page release (ISSUE 11 hardening): the
                 # slot never went live, so neither cancel() nor a later
@@ -1294,11 +1354,15 @@ class ContinuousBatchingEngine:
                 self.slot_pages[slot] = []
                 self.block_table[slot, :] = -1
                 self.queue.appendleft(req)
+                if tl:
+                    tl.leave(sp_pf, error=True)
                 if tr is not None:
                     tr.add("prefill", t_pf, tr.now(), tokens=T0,
                            error=True)
                     tr.mark("enqueued")   # still waiting (retry/replay)
                 raise
+            if tl:
+                tl.leave(sp_pf, chunks=self.prefill_chunks - chunks_pf)
             if tr is not None:
                 tr.add("prefill", t_pf, tr.now(), tokens=T0,
                        cached_tokens=(L + restored) * self.BS)
@@ -1306,6 +1370,12 @@ class ContinuousBatchingEngine:
             self.slots[slot] = req
             self.lengths[slot] = T0
             self.tokens[slot] = first
+            self.admissions += 1
+        if self.prefill_chunks > chunks0:
+            # every live stream waited through this admission's prefill
+            self.stalled_slot_iterations += running
+        if tl:
+            tl.leave(sp_admit, admitted=self.admissions - adm0)
 
     @staticmethod
     def _append_tok(req: GenRequest, tok: int) -> None:
@@ -1315,6 +1385,9 @@ class ContinuousBatchingEngine:
             req.eos_pos = len(req.out) - 1
 
     def _retire_done(self) -> None:
+        tl = self._tl
+        sp = tl and tl.enter("retire")
+        n0 = len(self.finished)
         for s in range(self.B):
             req = self.slots[s]
             if req is not None and (len(req.out) >= req.max_new_tokens
@@ -1323,6 +1396,8 @@ class ContinuousBatchingEngine:
                 if req.eos_pos is not None:
                     req.out = req.out[:req.eos_pos + 1]
                 self._retire(s)
+        if tl:
+            tl.leave(sp, retired=len(self.finished) - n0)
 
     def _free_slot(self, slot: int) -> None:
         self.alloc.release(self.slot_pages[slot])
@@ -1366,10 +1441,29 @@ class ContinuousBatchingEngine:
                 return True
         return False
 
+    #: the fleet router numbers its replicas' engines; a solo engine
+    #: has none (the timeline's ``engine_step`` carries it)
+    replica: Optional[int] = None
+
     def step(self) -> Dict[int, np.ndarray]:
         """One scheduler iteration: admit, decode every active slot,
         collect tokens, retire finished.  Returns newly finished
         {req_id: full ids} (empty dict when idle)."""
+        from ..observability.tracing import TRACER
+        # the ONE tracer read of an iteration: None when it is off, and
+        # then no site below stamps a time or opens an annotation
+        tl = self._tl = TRACER.timeline()
+        if tl is None:
+            return self._iterate()
+        sp = tl.enter("engine_step") if self.replica is None \
+            else tl.enter("engine_step", replica=self.replica)
+        try:
+            return self._iterate()
+        finally:
+            tl.leave(sp)        # and whatever a raise left open below
+
+    def _iterate(self) -> Dict[int, np.ndarray]:
+        tl = self._tl
         # retire first so freed slots/pages admit this very iteration;
         # then AGAIN after admission — the prefill's first token can
         # already satisfy the budget (max_new_tokens=1) or hit eos, and
@@ -1383,18 +1477,19 @@ class ContinuousBatchingEngine:
             out = self.finished
             self.finished = {}
             return out
-        from ..observability.tracing import TRACER
-        _tracing = TRACER.enabled
         if self._spec is not None and self._spec.config.enabled:
             # speculative decode: draft K, verify K+1 in one dispatch,
             # commit the accepted prefix (spec_decode/runner.py) —
             # greedy output is bit-identical to the baseline branch
             pre = sum(len(self.slots[s].out) for s in active)
             pre_by_slot = {s: len(self.slots[s].out) for s in active} \
-                if _tracing else None
-            m0 = time.monotonic() if _tracing else 0.0
+                if tl else None
+            m0 = time.monotonic() if tl else 0.0
+            sp = tl and tl.enter("spec_decode", batch=len(active))
             self._spec.run_decode(active)
-            if _tracing:
+            if tl:
+                tl.leave(sp, committed=sum(
+                    len(self.slots[s].out) for s in active) - pre)
                 m1 = time.monotonic()
                 for s in active:
                     r = self.slots[s]
@@ -1411,12 +1506,19 @@ class ContinuousBatchingEngine:
             out = self.finished
             self.finished = {}
             return out
-        m0 = time.monotonic() if _tracing else 0.0
+        m0 = time.monotonic() if tl else 0.0
+        sp = tl and tl.enter("decode_dispatch", batch=len(active))
         self.pool_k, self.pool_v, logits = self._step(
             self.params, self.pool_k, self.pool_v,
             jnp.asarray(self.block_table), jnp.asarray(self.lengths),
             jnp.asarray(self.tokens))
+        if tl:
+            tl.leave(sp)
+        sp = tl and tl.enter("logits_fetch")     # waits for the device
         self.last_logits = np.asarray(logits)
+        if tl:
+            tl.leave(sp, bytes=self.last_logits.nbytes)
+        sp = tl and tl.enter("pick")
         for s in active:
             self.lengths[s] += 1            # the fed token's KV is stored
         sampled = [s for s in active
@@ -1436,7 +1538,8 @@ class ContinuousBatchingEngine:
                 tok = int(self.last_logits[s].argmax())
             self._append_tok(req, int(tok))
             self.tokens[s] = int(tok)
-        if _tracing:
+        if tl:
+            tl.leave(sp, sampled=len(sampled))
             m1 = time.monotonic()
             for s in active:
                 tr = self._trace_of(self.slots[s])
@@ -1538,6 +1641,22 @@ class ContinuousBatchingEngine:
         lk = s["lookups"]
         s["hit_rate"] = (s["hits"] / lk) if lk else None
         return s
+
+    def scheduler_stats(self) -> Dict[str, object]:
+        """Admission/prefill accounting for the ``serve.sched.*`` gauges
+        (``ServeMetrics.publish_engine``): ``bucket_fill`` is useful
+        over dispatched (padded) prefill tokens, ``stalled_share`` the
+        share of per-slot decode steps that began behind a prefill —
+        the streams whose token gap paid for somebody's prompt."""
+        s: Dict[str, object] = {
+            "admissions": self.admissions,
+            "prefill_chunks": self.prefill_chunks,
+            "prefill_tokens_dispatched": self.prefill_tokens_dispatched,
+            "prefill_tokens_computed":
+                self.stats["prefill_tokens_computed"],
+            "stalled_slot_iterations": self.stalled_slot_iterations,
+            "decode_slot_steps": self.decode_slot_steps}
+        return sched_ratios(s)
 
     def spec_stats(self) -> Optional[Dict[str, object]]:
         """Speculation counters for bench rows / serve telemetry, or

@@ -43,8 +43,8 @@ from .compile_monitor import CompileMonitor
 from .hw import estimate_mfu, peak_flops_per_chip
 from .session import TelemetrySession, observe
 from .traced_lock import LockOrderRecorder, TracedLock
-from .tracing import (Span, SpanTracer, Trace, TRACER, attribution,
-                      export_chrome, write_spans_jsonl)
+from .tracing import (Span, SpanTracer, Timeline, Trace, TRACER,
+                      attribution, write_spans_jsonl)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
@@ -52,6 +52,6 @@ __all__ = [
     "CompileMonitor", "TelemetrySession", "observe",
     "estimate_mfu", "peak_flops_per_chip",
     "LockOrderRecorder", "TracedLock",
-    "Span", "SpanTracer", "Trace", "TRACER", "attribution",
-    "export_chrome", "write_spans_jsonl",
+    "Span", "SpanTracer", "Timeline", "Trace", "TRACER", "attribution",
+    "write_spans_jsonl",
 ]

@@ -46,10 +46,20 @@ SLO or ended REJECTED / TIMED_OUT / replayed — those records ride the
 :class:`~paddle_tpu.observability.FlightRecorder` ring, so every
 flight dump is a post-mortem with timelines.
 
-Exports: :func:`export_chrome` (chrome://tracing / Perfetto JSON, the
-profiler's format), :func:`write_spans_jsonl` (one span per line —
+The engine timeline (ISSUE 27): beside the request traces the tracer
+keeps ONE :class:`Timeline` of scheduler iterations — a ring of the
+newest ``N`` span trees (``iteration`` → ``engine_step`` → ``admit`` →
+``prefill`` → ...), written through :meth:`Timeline.enter` /
+:meth:`Timeline.leave`.  Each span is also opened, under the same name
+with the :data:`PROFILER_PREFIX`, as a ``jax.profiler`` annotation, so
+with a profiler session open the engine's phases lie on the profiler's
+own clock beside the device's operations (no-ops otherwise).  A new
+engine phase gets its span through that one pair, nowhere else.
+
+Exports: :func:`write_spans_jsonl` (one span per line —
 ``tools/trace_report.py`` renders it), :func:`attribution` (per-phase
-p50/p95 contributions to TTFT/TPOT — ``LoadReport.attribution``).
+p50/p95 contributions to TTFT/TPOT — ``LoadReport.attribution``).  The
+picture is the profiler's own trace: ``tools/trace_report.py --xplane``.
 """
 
 from __future__ import annotations
@@ -62,8 +72,20 @@ import time
 from contextlib import contextmanager
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
-__all__ = ["Span", "Trace", "SpanTracer", "TRACER", "attribution",
-           "export_chrome", "write_spans_jsonl"]
+from jax import profiler as _profiler
+
+__all__ = ["Span", "Trace", "Iteration", "Timeline", "SpanTracer",
+           "TRACER", "PROFILER_PREFIX", "attribution",
+           "write_spans_jsonl"]
+
+#: every timeline span is ALSO a ``jax.profiler`` annotation of this
+#: prefix + its name (``pt:logits_fetch``): the names a trace reader
+#: looks for on the host's ``python`` line
+PROFILER_PREFIX = "pt:"
+
+#: iterations the engine timeline keeps: a 30 s window at 10
+#: iterations/s thirteen times over
+TIMELINE_CAPACITY = 4096
 
 
 class Span:
@@ -232,6 +254,111 @@ class Trace:
                 f"state={self.state}, spans={len(self.spans)})")
 
 
+class Iteration:
+    """One scheduler iteration on the engine timeline: its number and
+    its spans in the order they opened (the root first).  Span times
+    are ``time.monotonic()`` seconds — a request trace's span lies at
+    ``trace.mono_t0 + span.t0`` on the same axis; span ids count from 1
+    within the iteration and the root's parent is 0."""
+
+    __slots__ = ("n", "spans")
+
+    def __init__(self, n: int):
+        self.n = n
+        self.spans: List[Span] = []
+
+    def to_dict(self) -> Dict[str, Any]:
+        return {"n": self.n, "spans": [s.to_dict() for s in self.spans]}
+
+
+class _Open(threading.local):
+    """Per-thread tree under construction: the open spans, innermost
+    last, each with its profiler annotation."""
+
+    def __init__(self):
+        self.stack: List[Tuple[Span, Any]] = []
+        self.cur: Optional[Iteration] = None
+
+
+class Timeline:
+    """The engine's own phases, one span tree a scheduler iteration, in
+    a ring of the newest :data:`TIMELINE_CAPACITY` iterations (the tail
+    is what a profiler session at the end of a window sees; ``dropped``
+    counts what the ring let go).
+
+    The ONE recording helper: ``sp = tl.enter(name, **attrs)`` ...
+    ``tl.leave(sp, **more)`` stamps the span on the monotonic clock
+    AND holds a ``jax.profiler`` annotation ``pt:<name>`` open over the
+    same interval.  A span opened while none is open starts a new
+    iteration (its annotation is a ``StepTraceAnnotation`` numbered
+    like the iteration); any other nests under the innermost open one.
+    ``leave`` also closes what an exception left open beneath ``sp``,
+    so a root left in a ``finally`` keeps every tree well formed.
+    Trees are built per thread and published whole under ``_lock``."""
+
+    def __init__(self):
+        self._ring: Deque[Iteration] = collections.deque(
+            maxlen=TIMELINE_CAPACITY)
+        self._lock = threading.Lock()
+        self._open = _Open()
+        self._seq = 0
+        self.dropped = 0
+
+    def enter(self, name: str, **attrs) -> Span:
+        st = self._open
+        stack = st.stack
+        if stack:
+            parent = stack[-1][0].span_id
+            ann = _profiler.TraceAnnotation(PROFILER_PREFIX + name)
+        else:
+            with self._lock:
+                self._seq += 1
+                n = self._seq
+            st.cur = Iteration(n)
+            parent = 0
+            attrs["n"] = n
+            ann = _profiler.StepTraceAnnotation(PROFILER_PREFIX + name,
+                                                step_num=n)
+        ann.__enter__()
+        t0 = time.monotonic()
+        spans = st.cur.spans
+        sp = Span(name, t0, t0, len(spans) + 1, parent, attrs or None)
+        spans.append(sp)
+        stack.append((sp, ann))
+        return sp
+
+    def leave(self, sp: Span, **attrs) -> None:
+        st = self._open
+        stack = st.stack
+        if attrs:
+            sp.attrs = {**sp.attrs, **attrs} if sp.attrs else attrs
+        if not any(o is sp for o, _ in stack):
+            return                  # an enclosing leave already took it
+        t1 = time.monotonic()
+        while True:
+            top, ann = stack.pop()
+            top.t1 = t1
+            ann.__exit__(None, None, None)
+            if top is sp:
+                break
+        if not stack:
+            it, st.cur = st.cur, None
+            with self._lock:
+                if len(self._ring) == self._ring.maxlen:
+                    self.dropped += 1
+                self._ring.append(it)
+
+    def iterations(self, last: Optional[int] = None) -> List[Iteration]:
+        """The finished iterations, oldest first (the newest ``last``)."""
+        with self._lock:
+            its = list(self._ring)
+        return its if last is None else its[-last:]
+
+    def to_dict(self, last: Optional[int] = None) -> Dict[str, Any]:
+        return {"dropped": self.dropped,
+                "iterations": [i.to_dict() for i in self.iterations(last)]}
+
+
 class _Ambient(threading.local):
     """Per-thread active-trace stack (the propagation channel)."""
 
@@ -263,6 +390,7 @@ class SpanTracer:
             maxlen=int(done_capacity))
         self._ambient = _Ambient()
         self._train: Optional[Trace] = None
+        self._timeline: Optional[Timeline] = None
 
     # -- lifecycle ------------------------------------------------------
     def enable(self) -> None:
@@ -284,6 +412,7 @@ class SpanTracer:
             self._by_rid.clear()
             self._done.clear()
             self._train = None
+            self._timeline = None
             self._seq = 0
         self.slo_ttft_s = None
         self.slo_tpot_s = None
@@ -427,6 +556,23 @@ class SpanTracer:
                 tr = self._train
         return tr
 
+    # -- engine timeline -------------------------------------------------
+    def timeline(self) -> Optional[Timeline]:
+        """The process engine timeline (lazily created; None when
+        disabled): every scheduler — a solo engine, a fleet's replicas
+        — records its iterations here.  An instrumented ``step`` calls
+        this ONCE and guards its sites on the result, so the disabled
+        path costs that one boolean."""
+        if not self.enabled:
+            return None
+        tl = self._timeline
+        if tl is None:
+            with self._lock:
+                if self._timeline is None:
+                    self._timeline = Timeline()
+                tl = self._timeline
+        return tl
+
 
 def attribution(traces: List[Trace],
                 pcts: Tuple[int, ...] = (50, 95)) -> Dict[str, Any]:
@@ -473,48 +619,6 @@ def attribution(traces: List[Trace],
 
     return {"n_traced": n, "ttft": _pct(ttft_by_phase),
             "tpot": _pct(tpot_by_phase)}
-
-
-def export_chrome(traces: List[Trace], path: str) -> str:
-    """Write chrome://tracing / Perfetto JSON (the profiler's format:
-    ``{"traceEvents": [...], "displayTimeUnit": "ms"}``, complete "X"
-    events, µs timestamps).  One tid per trace, wall-clock anchored,
-    so serve requests and the training twin land on one timeline."""
-    pid = os.getpid()
-    events: List[Dict[str, Any]] = [
-        {"name": "process_name", "ph": "M", "pid": pid,
-         "args": {"name": "paddle_tpu_trace"}}]
-    for tid, tr in enumerate(t for t in traces if t is not None):
-        events.append({"name": "thread_name", "ph": "M", "pid": pid,
-                       "tid": tid,
-                       "args": {"name": f"{tr.name} {tr.trace_id}"
-                                + (f" rid={tr.rid}"
-                                   if tr.rid is not None else "")}})
-        end = tr.duration_s
-        root_dur = (end if end is not None
-                    else (max((s.t1 for s in tr.snapshot()),
-                              default=0.0)))
-        events.append({
-            "name": f"{tr.name}:{tr.state or 'live'}", "ph": "X",
-            "cat": "trace", "ts": tr.wall_t0 * 1e6,
-            "dur": root_dur * 1e6, "pid": pid, "tid": tid,
-            "args": {"trace_id": tr.trace_id, "rid": tr.rid,
-                     "request_id": tr.request_id}})
-        for s in tr.snapshot():
-            ev: Dict[str, Any] = {
-                "name": s.name, "ph": "X", "cat": "span",
-                "ts": (tr.wall_t0 + s.t0) * 1e6,
-                "dur": max(s.t1 - s.t0, 0.0) * 1e6,
-                "pid": pid, "tid": tid}
-            if s.attrs:
-                ev["args"] = s.attrs
-            events.append(ev)
-    parent = os.path.dirname(os.path.abspath(path))
-    if parent:
-        os.makedirs(parent, exist_ok=True)
-    with open(path, "w", encoding="utf-8") as f:
-        json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
-    return path
 
 
 def write_spans_jsonl(traces: List[Trace], path: str) -> str:

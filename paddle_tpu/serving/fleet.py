@@ -73,7 +73,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ..inference.serving import GenRequest
+from ..inference.serving import GenRequest, sched_ratios
 from ..observability import REGISTRY
 from ..observability.tracing import TRACER
 from .frontend import AdmissionConfig
@@ -207,9 +207,18 @@ class EngineRouter:
     # replica lifecycle
     # ------------------------------------------------------------------
     def _add_replica(self, factory: Callable[[], object]) -> _Replica:
-        sup = SupervisedEngine(factory, policy=self.policy,
+        idx = len(self._replicas)
+
+        def numbered():
+            # every engine this replica ever runs (rebuilds too) says
+            # which replica it is on the one engine timeline
+            engine = factory()
+            engine.replica = idx
+            return engine
+
+        sup = SupervisedEngine(numbered, policy=self.policy,
                                registry=self._reg, **self._sup_kwargs)
-        rep = _Replica(idx=len(self._replicas), sup=sup)
+        rep = _Replica(idx=idx, sup=sup)
         self._replicas.append(rep)
         return rep
 
@@ -837,6 +846,16 @@ class EngineRouter:
     def aot_stats(self) -> Dict[str, object]:
         return {f"replica{r.idx}": r.sup.aot_stats()
                 for r in self._live()}
+
+    def scheduler_stats(self) -> Dict[str, object]:
+        """Summed per-replica admission/prefill counters, the ratios
+        recomputed over the sums (the ``serve.sched.*`` gauges)."""
+        total: Dict[str, object] = {}
+        for r in self._live():
+            for k, v in r.sup.scheduler_stats().items():
+                if isinstance(v, int):
+                    total[k] = total.get(k, 0) + v
+        return sched_ratios(total)
 
     def fleet_stats(self) -> Dict[str, object]:
         """The ``serve.fleet.*`` rollup: health census, aggregate load,

@@ -549,61 +549,93 @@ class ServingFrontend:
     # ------------------------------------------------------------------
     def step(self) -> bool:
         """One scheduler iteration: expire deadlines, advance the
-        engine, stream newly produced tokens, publish gauges.  Returns
-        True while live requests remain."""
-        deliveries: List[_Delivery] = []
-        with self._lock:
-            now = self._clock()
-            self._expire(now, deliveries)
-            try:
-                # The scheduler lock IS the engine serialization point:
-                # step() mutates engine batch state, and every other
-                # engine touch (submit's admission, drain) already goes
-                # through _lock.  Callers never block on _lock for the
-                # step duration — they use the handle condvars.
-                finished = self.engine.step()  # locklint: disable=LK002
-            except BaseException as e:
-                self._crash(e)
-                raise
-            now = self._clock()
-            for rid, rec in list(self._recs.items()):
-                out = rec.req.out
-                n = len(out)
-                d = _Delivery(rec, now=now)
-                if n > rec.delivered:
-                    d.toks = list(out[rec.delivered:n])
-                    if rec.delivered == 0:
-                        rec.handle.first_token_t = now
-                        self.metrics.on_first_token(
-                            rid, now - rec.handle.submit_t)
-                        tr = rec.handle.trace
-                        if tr is not None:
-                            # trace-relative TTFT: the window split
-                            # attribution() cuts the timeline at
-                            tr.meta["ttft_s"] = tr.now()
-                            tr.event("first_token")
-                        if len(d.toks) > 1:
-                            self.metrics.on_tokens(len(d.toks) - 1, 0.0)
-                    else:
-                        self.metrics.on_tokens(
-                            len(d.toks),
-                            (now - rec.last_token_t) / len(d.toks))
-                    rec.last_token_t = now
-                    rec.delivered = n
-                if rid in finished:
-                    rec.done = True
-                    del self._recs[rid]
-                    d.state = RequestState.FINISHED
-                    d.result = finished[rid]
-                    self.metrics.on_finish(
-                        rid, now - rec.handle.submit_t, n)
-                    self._finish_trace(rec.handle.trace, "FINISHED", n)
-                if d.toks or d.state is not None:
-                    deliveries.append(d)
-            self._publish()
-            pending = bool(self._recs)
-        self._apply(deliveries)
-        return pending
+        engine, publish gauges, stream newly produced tokens.  Returns
+        True while live requests remain.  With the tracer on it is one
+        ``iteration`` tree on the engine timeline (and on the
+        profiler's clock): docs/observability.md has the span table."""
+        # the ONE tracer read of an iteration: None when it is off
+        tl = TRACER.timeline()
+        it = tl and tl.enter("iteration")
+        live = queued = 0
+        try:
+            deliveries: List[_Delivery] = []
+            with self._lock:
+                now = self._clock()
+                if tl:
+                    live = len(self._recs)
+                    queued = sum(1 for r in self._recs.values()
+                                 if not r.req.out)
+                sp = tl and tl.enter("expire")
+                self._expire(now, deliveries)
+                if tl:
+                    tl.leave(sp, expired=len(deliveries))
+                try:
+                    # The scheduler lock IS the engine serialization
+                    # point: step() mutates engine batch state, and
+                    # every other engine touch (submit's admission,
+                    # drain) already goes through _lock.  Callers never
+                    # block on _lock for the step duration — they use
+                    # the handle condvars.
+                    finished = self.engine.step()  # locklint: disable=LK002
+                except BaseException as e:
+                    self._crash(e)
+                    raise
+                now = self._clock()
+                sp = tl and tl.enter("publish")
+                self._publish()
+                if tl:
+                    tl.leave(sp)
+                sp = tl and tl.enter("deliver")
+                self._collect(finished, now, deliveries)
+                pending = bool(self._recs)
+            self._apply(deliveries)
+            if tl:
+                tl.leave(sp, tokens=sum(len(d.toks) for d in deliveries),
+                         finished=len(finished))
+            return pending
+        finally:
+            if tl:
+                tl.leave(it, live=live, queued=queued)
+
+    def _collect(self, finished, now: float,
+                 deliveries: List[_Delivery]) -> None:
+        """Under the scheduler lock: what each live request produced
+        this iteration, as deferred deliveries (``_apply`` hands them
+        over once the lock is released)."""
+        for rid, rec in list(self._recs.items()):
+            out = rec.req.out
+            n = len(out)
+            d = _Delivery(rec, now=now)
+            if n > rec.delivered:
+                d.toks = list(out[rec.delivered:n])
+                if rec.delivered == 0:
+                    rec.handle.first_token_t = now
+                    self.metrics.on_first_token(
+                        rid, now - rec.handle.submit_t)
+                    tr = rec.handle.trace
+                    if tr is not None:
+                        # trace-relative TTFT: the window split
+                        # attribution() cuts the timeline at
+                        tr.meta["ttft_s"] = tr.now()
+                        tr.event("first_token")
+                    if len(d.toks) > 1:
+                        self.metrics.on_tokens(len(d.toks) - 1, 0.0)
+                else:
+                    self.metrics.on_tokens(
+                        len(d.toks),
+                        (now - rec.last_token_t) / len(d.toks))
+                rec.last_token_t = now
+                rec.delivered = n
+            if rid in finished:
+                rec.done = True
+                del self._recs[rid]
+                d.state = RequestState.FINISHED
+                d.result = finished[rid]
+                self.metrics.on_finish(
+                    rid, now - rec.handle.submit_t, n)
+                self._finish_trace(rec.handle.trace, "FINISHED", n)
+            if d.toks or d.state is not None:
+                deliveries.append(d)
 
     def run_until_drained(self, timeout_s: Optional[float] = None) -> None:
         """Pump (or wait on the driver) until no live requests remain."""

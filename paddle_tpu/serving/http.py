@@ -674,7 +674,9 @@ class _RequestHandler(http.server.BaseHTTPRequestHandler):
     def _trace_debug(self, key: str) -> None:
         """``GET /v1/trace/<key>``: one request's span tree (live or
         from the finished ring) as JSON — ``key`` is the server req_id,
-        the client request_id, or the trace_id (tried in that order)."""
+        the client request_id, or the trace_id (tried in that order);
+        ``/v1/trace/engine`` is the scheduler's own timeline, unless
+        a request carries that id."""
         from ..observability.tracing import TRACER
         if not TRACER.enabled:
             self._send_json(404, {
@@ -688,6 +690,11 @@ class _RequestHandler(http.server.BaseHTTPRequestHandler):
             tr = TRACER.lookup(request_id=key)
         if tr is None:
             tr = TRACER.lookup(trace_id=key)
+        if tr is None and key == "engine":
+            # no request is called that: the engine timeline's newest
+            # iterations (ISSUE 27)
+            self._send_json(200, TRACER.timeline().to_dict(last=256))
+            return
         if tr is None:
             self._send_json(404, {"error": f"no trace for {key!r}"})
             return

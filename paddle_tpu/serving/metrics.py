@@ -326,6 +326,17 @@ class ServeMetrics:
                 prefix.get("offloaded_bytes", 0))
             if prefix.get("hit_rate") is not None:
                 g("serve.prefix.hit_rate").set(prefix["hit_rate"])
+        sched = engine.scheduler_stats() \
+            if hasattr(engine, "scheduler_stats") else None
+        if sched is not None:
+            g = self._reg.gauge
+            g("serve.sched.admissions").set(sched.get("admissions", 0))
+            g("serve.sched.prefill_chunks").set(
+                sched.get("prefill_chunks", 0))
+            if sched["bucket_fill"] is not None:
+                g("serve.sched.bucket_fill").set(sched["bucket_fill"])
+            if sched["stalled_share"] is not None:
+                g("serve.sched.stalled_share").set(sched["stalled_share"])
         fleet = engine.fleet_stats() \
             if hasattr(engine, "fleet_stats") else None
         if fleet is not None:

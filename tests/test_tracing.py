@@ -17,7 +17,13 @@ Load-bearing contracts:
 * disabled-mode tracing allocates nothing on the hot path (the
   MetricsRegistry bar from test_observability.py);
 * the tracing module and every instrumented serve file carry ZERO
-  tracelint/locklint findings, and both ledgers stay EMPTY.
+  tracelint/locklint findings, and both ledgers stay EMPTY;
+* the engine timeline (ISSUE 27): every ``frontend.step()`` is one
+  ``iteration`` span tree with the documented names and parents, every
+  span is also one ``pt:`` profiler annotation over the same interval,
+  the boundary counters count to the unit with the tracer on or off,
+  and the per-request ``decode_step`` / ``ttft_s`` records the
+  benchmark harness reads are untouched.
 """
 
 import gc
@@ -35,8 +41,10 @@ from paddle_tpu.inference.serving import ContinuousBatchingEngine
 from paddle_tpu.models.llama import build_llama_train_step, llama_tiny
 from paddle_tpu.observability import (FlightRecorder, MemorySink,
                                       MetricsRegistry, REGISTRY)
-from paddle_tpu.observability.tracing import (TRACER, SpanTracer, Trace,
-                                              attribution, export_chrome,
+from paddle_tpu.observability import tracing
+from paddle_tpu.observability.tracing import (PROFILER_PREFIX, TRACER,
+                                              SpanTracer, Timeline, Trace,
+                                              attribution,
                                               write_spans_jsonl)
 from paddle_tpu.parallel.topology import HybridTopology, set_topology
 from paddle_tpu.serving import (AdmissionConfig, EngineRouter,
@@ -456,6 +464,11 @@ def test_http_trace_endpoint_and_headers(model):
             assert any(s["name"] == "prefill" for s in d["spans"])
         status, body, _ = _get(srv.port, "/v1/trace/nope")
         assert status == 404
+        # the scheduler's own timeline under the same endpoint
+        status, body, _ = _get(srv.port, "/v1/trace/engine")
+        assert status == 200
+        its = json.loads(body)["iterations"]
+        assert its and its[0]["spans"][0]["name"] == "iteration"
 
 
 def test_http_trace_endpoint_404_when_disabled(model):
@@ -498,26 +511,15 @@ def _traced_run(model, n=6):
     return TRACER.done_traces()
 
 
-def test_chrome_export_and_jsonl_roundtrip(model, tmp_path):
+def test_jsonl_roundtrip(model, tmp_path):
     done = _traced_run(model)
     jp = str(tmp_path / "traces.jsonl")
-    cp = str(tmp_path / "traces_chrome.json")
     write_spans_jsonl(done, jp)
-    export_chrome(done, cp)
     lines = [json.loads(ln) for ln in open(jp)]
     assert len(lines) == len(done)
     assert all("spans" in d and "trace_id" in d for d in lines)
-    chrome = json.load(open(cp))
-    evs = chrome["traceEvents"]
-    assert chrome["displayTimeUnit"] == "ms"
-    xs = [e for e in evs if e["ph"] == "X"]
-    assert len(xs) >= len(done)              # one root X per trace
-    assert all(e["ts"] >= 0 and e["dur"] >= 0 for e in xs)
-    names = {e["name"] for e in xs}
+    names = {s["name"] for d in lines for s in d["spans"]}
     assert "prefill" in names and "queue_wait" in names
-    # perfetto needs the thread metadata rows to label lanes
-    assert any(e.get("ph") == "M" and e.get("name") == "thread_name"
-               for e in evs)
 
 
 def test_trace_report_tool(model, tmp_path, capsys):
@@ -656,6 +658,412 @@ def test_trace_thread_safety():
     assert len(spans) == n_threads * per_thread
     ids = [s.span_id for s in spans]
     assert len(set(ids)) == len(ids)          # no duplicate ids
+
+
+# ---------------------------------------------------------------------
+# the engine timeline (ISSUE 27)
+# ---------------------------------------------------------------------
+#: span -> parent, as docs/observability.md tables them
+TIMELINE_PARENT = {
+    "iteration": None, "expire": "iteration", "engine_step": "iteration",
+    "retire": "engine_step", "admit": "engine_step", "prefill": "admit",
+    "prefill_chunk": "prefill", "first_token_fetch": "prefill",
+    "decode_dispatch": "engine_step", "logits_fetch": "engine_step",
+    "pick": "engine_step", "spec_decode": "engine_step",
+    "deliver": "iteration", "publish": "iteration"}
+
+
+def _assert_tree(it, root="iteration"):
+    """One iteration: the documented names and parents, children inside
+    their parent, siblings in order and not overlapping."""
+    by_id = {s.span_id: s for s in it.spans}
+    assert [s.span_id for s in it.spans] == \
+        list(range(1, len(it.spans) + 1))
+    roots = [s for s in it.spans if s.parent == 0]
+    assert [s.name for s in roots] == [root], it.to_dict()
+    assert roots[0].attrs["n"] == it.n
+    last_end = {}
+    for s in it.spans:
+        assert s.t0 <= s.t1
+        if s.parent == 0:
+            continue
+        up = by_id[s.parent]
+        assert up.name == TIMELINE_PARENT[s.name], (s.name, up.name)
+        assert up.t0 <= s.t0 and s.t1 <= up.t1, (s.name, up.name)
+        assert s.t0 >= last_end.get(s.parent, 0.0), s.name
+        last_end[s.parent] = s.t1
+
+
+def test_timeline_one_tree_per_frontend_step(model):
+    TRACER.enable()
+    fe = ServingFrontend(_engine(model))
+    hs = [fe.submit(_prompt(model, 20), 4)]
+    steps = 1
+    fe.step()
+    hs.append(fe.submit(_prompt(model, 11), 3, temperature=0.8, seed=5))
+    while fe.step():
+        steps += 1
+    steps += 1
+    its = TRACER.timeline().iterations()
+    assert len(its) == steps
+    assert [it.n for it in its] == list(range(1, steps + 1))
+    for it in its:
+        _assert_tree(it)
+    names = {s.name for it in its for s in it.spans}
+    assert names == set(TIMELINE_PARENT) - {"spec_decode"}
+    assert its[0].spans[0].attrs["live"] == 1
+    assert its[0].spans[0].attrs["queued"] == 1
+    # a prefill joins its request trace: same rid, same interval
+    prefills = [s for it in its for s in it.spans if s.name == "prefill"]
+    assert sorted(s.attrs["rid"] for s in prefills) == \
+        sorted(h.trace.rid for h in hs)
+    for h in hs:
+        tr = h.trace
+        mine = next(s for s in tr.snapshot() if s.name == "prefill")
+        on_tl = next(s for s in prefills if s.attrs["rid"] == tr.rid)
+        assert on_tl.attrs["tokens"] == mine.attrs["tokens"]
+        assert on_tl.attrs["chunks"] == -(-mine.attrs["tokens"] // 8)
+        assert tr.mono_t0 + mine.t0 <= on_tl.t0 <= on_tl.t1 \
+            <= tr.mono_t0 + mine.t1 + 1e-6
+        assert (mine.t1 - mine.t0) - (on_tl.t1 - on_tl.t0) < 0.05
+    # the sampled request went through the sampler inside `pick`
+    assert any(s.name == "pick" and s.attrs["sampled"] == 1
+               for it in its for s in it.spans)
+
+
+def test_timeline_engine_root_spec_and_fleet(model):
+    """An engine driven without a frontend records ``engine_step`` as
+    the root; the spec-decode branch records ``spec_decode`` in place
+    of dispatch/fetch/pick; a fleet's replicas share the one timeline
+    and differ by ``replica``."""
+    from paddle_tpu.spec_decode import SpecDecodeConfig
+    TRACER.enable()
+    eng = _engine(model, spec_config=SpecDecodeConfig(
+        draft_cfg=model[0], draft_params=model[1], k=3, window=12))
+    eng.add_request(_prompt(model, 6), 5)
+    eng.run_to_completion()
+    its = TRACER.timeline().iterations()
+    assert its
+    for it in its:
+        _assert_tree(it, root="engine_step")
+    spec = [s for it in its for s in it.spans if s.name == "spec_decode"]
+    assert spec and sum(s.attrs["committed"] for s in spec) == 4
+    assert not any(s.name in ("decode_dispatch", "logits_fetch", "pick")
+                   for it in its for s in it.spans)
+    TRACER.reset()
+    fe = ServingFrontend(_router(model))
+    for n in (5, 7):
+        fe.submit(_prompt(model, n), 3)
+    _drain(fe)
+    its = TRACER.timeline().iterations()
+    for it in its:
+        _assert_tree(it)
+    replicas = {s.attrs["replica"] for it in its for s in it.spans
+                if s.name == "engine_step"}
+    assert replicas == {0, 1}
+    # the router sums its replicas' scheduler counters
+    st = fe.engine.scheduler_stats()
+    assert st["admissions"] == 2 and st["prefill_chunks"] == 2
+    assert 0 < st["bucket_fill"] <= 1 and st["stalled_share"] == 0
+
+
+@pytest.mark.parametrize("tracer_on", [False, True])
+def test_timeline_counters_scripted_admission(model, tracer_on):
+    """A 700-token prompt admitted through buckets [128, 512] while one
+    stream is running: the chunk plan on the timeline, the boundary
+    counters to the unit — the counters with the tracer off as well."""
+    from paddle_tpu.models.llama import llama_tiny
+    if tracer_on:
+        TRACER.enable()
+    cfg = llama_tiny(max_position_embeddings=1024)
+    eng = ContinuousBatchingEngine(
+        cfg, model[1], max_batch=2, block_size=8, num_blocks=128,
+        prefill_buckets=(128, 512), enable_prefix_caching=False)
+    reg = MetricsRegistry(enabled=True)
+    fe = ServingFrontend(eng, registry=reg)
+    fe.submit(_prompt(model, 20), 12)
+    fe.step()                                   # one stream is running
+    assert (eng.stalled_slot_iterations, eng.prefill_chunks,
+            eng.prefill_tokens_dispatched) == (0, 1, 128)
+    fe.submit(_prompt(model, 700), 2)
+    fe.step()
+    plan = eng._buckets.plan_chunks(700)
+    assert plan == [(512, 512), (128, 128), (128, 60)]
+    steps = 2
+    while fe.step():
+        steps += 1
+    assert eng.admissions == 2
+    assert eng.prefill_chunks == 1 + len(plan)
+    assert eng.prefill_tokens_dispatched == 128 + sum(c for c, _ in plan)
+    assert eng.stats["prefill_tokens_computed"] == 720
+    assert eng.stalled_slot_iterations == 1
+    # the operator's surface: the serve.sched.* gauges read these
+    assert {k: reg.gauge(f"serve.sched.{k}").value for k in (
+        "admissions", "prefill_chunks", "bucket_fill", "stalled_share")} \
+        == {"admissions": 2, "prefill_chunks": 4,
+            "bucket_fill": 720 / 896,
+            "stalled_share": 1 / eng.decode_slot_steps}
+    if not tracer_on:
+        assert TRACER.timeline() is None
+        return
+    its = TRACER.timeline().iterations()
+    assert len(its) == steps + 1
+    # what the counter sums is on the timeline too: the one admit that
+    # ran chunks while a stream was live
+    assert [(it.n, s.attrs["running"]) for it in its for s in it.spans
+            if s.name == "admit" and s.attrs["running"]
+            and any(c.name == "prefill_chunk" for c in it.spans)] \
+        == [(2, 1)]
+    second = its[1].spans
+    admit = next(s for s in second if s.name == "admit")
+    assert admit.attrs == {"running": 1, "admitted": 1}
+    assert [(s.attrs["size"], s.attrs["valid"]) for s in second
+            if s.name == "prefill_chunk"] == plan
+    pf = next(s for s in second if s.name == "prefill")
+    assert pf.attrs["tokens"] == 700 and pf.attrs["chunks"] == 3 \
+        and pf.attrs["cached_tokens"] == 0
+
+
+def test_timeline_ring_keeps_newest_and_counts_dropped(monkeypatch):
+    monkeypatch.setattr(tracing, "TIMELINE_CAPACITY", 4)
+    tl = Timeline()
+    for i in range(10):
+        root = tl.enter("engine_step")
+        sp = tl.enter("admit", running=i)
+        tl.leave(sp, admitted=0)
+        tl.leave(root)
+    its = tl.iterations()
+    assert [it.n for it in its] == [7, 8, 9, 10]
+    assert tl.dropped == 6
+    assert its[-1].spans[1].attrs == {"running": 9, "admitted": 0}
+    assert [it.n for it in tl.iterations(last=2)] == [9, 10]
+    d = tl.to_dict(last=1)
+    assert d["dropped"] == 6 and d["iterations"][0]["n"] == 10
+    # a raise between open and close: the root's close takes the
+    # abandoned children with it, and the next tree starts clean
+    root = tl.enter("engine_step")
+    tl.enter("admit")
+    lost = tl.enter("prefill")
+    tl.leave(root)
+    tl.leave(lost)                               # late close: a no-op
+    it = tl.iterations()[-1]
+    assert [s.name for s in it.spans] == ["engine_step", "admit",
+                                          "prefill"]
+    assert all(s.t1 == it.spans[0].t1 for s in it.spans)
+    assert tl.enter("engine_step").parent == 0
+    # the tracer owns ONE timeline, lazily, and reset() drops it
+    t = SpanTracer(enabled=True)
+    assert t.timeline() is t.timeline()
+    first = t.timeline()
+    t.reset()
+    assert t.timeline() is not first
+    t.disable()
+    assert t.timeline() is None
+
+
+class _AnnotationRecorder:
+    """Stands in for jax.profiler's annotation classes: no profiler
+    session in tier-1, so record what WOULD have been opened."""
+
+    def __init__(self):
+        self.log = []              # ("enter"|"exit", kind, name, kwargs)
+
+    def patch(self, monkeypatch):
+        rec = self
+
+        def make(kind):
+            class Ann:
+                def __init__(self, name, **kw):
+                    self.name, self.kw = name, kw
+
+                def __enter__(self):
+                    rec.log.append(("enter", kind, self.name, self.kw))
+                    return self
+
+                def __exit__(self, *exc):
+                    rec.log.append(("exit", kind, self.name, self.kw))
+            return Ann
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                            make("trace"))
+        monkeypatch.setattr(jax.profiler, "StepTraceAnnotation",
+                            make("step"))
+        return self
+
+
+def test_timeline_spans_are_profiler_annotations(model, monkeypatch):
+    rec = _AnnotationRecorder().patch(monkeypatch)
+    TRACER.enable()
+    fe = ServingFrontend(_engine(model))
+    fe.submit(_prompt(model, 20), 3)
+    fe.submit(_prompt(model, 5), 2)
+    _drain(fe)
+    its = TRACER.timeline().iterations()
+    # one annotation a span, same name with the one prefix, same order
+    entered = [e for e in rec.log if e[0] == "enter"]
+    spans = [s for it in its for s in it.spans]
+    assert [e[2] for e in entered] == \
+        [PROFILER_PREFIX + s.name for s in spans]
+    assert PROFILER_PREFIX == "pt:"
+    # the iteration is a StepTraceAnnotation numbered like the tree
+    steps = [e for e in entered if e[1] == "step"]
+    assert [e[2] for e in steps] == ["pt:iteration"] * len(its)
+    assert [e[3]["step_num"] for e in steps] == [it.n for it in its]
+    assert all(e[1] == "trace" for e in entered
+               if e[2] != "pt:iteration")
+    # properly nested: every exit closes the innermost open annotation
+    stack = []
+    for what, _, name, _ in rec.log:
+        if what == "enter":
+            stack.append(name)
+        else:
+            assert stack.pop() == name
+    assert stack == []
+
+
+def test_timeline_disabled_costs_nothing(model, monkeypatch):
+    """Tracer off: no annotation is opened, and 200 engine steps that
+    admit, decode and retire keep no record — under half a block a
+    step stays allocated (what JAX's own dispatch leaves behind),
+    against dozens a step once the tracer is on."""
+    rec = _AnnotationRecorder().patch(monkeypatch)
+    eng = _engine(model, enable_prefix_caching=False)
+    prompt = _prompt(model, 6)
+
+    def forty_steps():
+        # per request: the admitting step, two more tokens, the retire
+        for _ in range(10):
+            eng.add_request(prompt, 4)
+            for _ in range(4):
+                eng.step()
+            assert not eng.queue and eng.active_requests == 0
+
+    def net_blocks():
+        gc.collect()
+        before = sys.getallocatedblocks()
+        for _ in range(5):
+            forty_steps()
+        gc.collect()
+        return sys.getallocatedblocks() - before
+
+    for _ in range(4):                          # compile, fill caches
+        forty_steps()
+    off = min(net_blocks() for _ in range(3))
+    assert off <= 100, f"disabled timeline kept {off} blocks"
+    assert rec.log == [] and TRACER.timeline() is None
+    TRACER.enable()
+    assert net_blocks() > 2000 and len(rec.log) > 2000
+
+
+def test_timeline_leaves_harness_readings_alone(model):
+    """What benchmark/lib/serve.py:_readings extracts: the per-request
+    ``decode_step`` spans (one engine-level fact copied per slot) name
+    exactly ``decode_steps`` distinct steps, each inside its
+    iteration's ``engine_step``; ``ttft_s`` is the ``first_token``
+    instant."""
+    TRACER.enable()
+    eng = _engine(model)
+    fe = ServingFrontend(eng)
+    hs = [fe.submit(_prompt(model, n), 5) for n in (6, 9, 4)]
+    _drain(fe)
+    steps = set()
+    for h in hs:
+        tr = h.trace
+        spans = tr.snapshot()
+        for s in spans:
+            if s.name == "decode_step":
+                steps.add((round(tr.mono_t0 + s.t0, 6),
+                           round(s.t1 - s.t0, 9)))
+                assert s.attrs["batch"] in (1, 2)
+        first = next(s for s in spans if s.name == "first_token")
+        assert tr.meta["ttft_s"] == pytest.approx(first.t0, abs=1e-3)
+    assert len(steps) == eng.decode_steps > 0
+    tl_steps = [s for it in TRACER.timeline().iterations()
+                for s in it.spans if s.name == "engine_step"]
+    for t0, dur in steps:
+        assert any(s.t0 <= t0 + 1e-6 and t0 + dur <= s.t1 + 1e-6
+                   for s in tl_steps)
+
+
+def test_trace_report_xplane_mode(capsys):
+    """``tools/trace_report.py --xplane`` on the benchmark's recorded
+    trace (no ``pt:`` spans there: it says so and still gives the
+    device's busy and idle time), and its gap arithmetic on spans it
+    would find in a traced serve run."""
+    from benchmark.lib import xplane as xp
+    small = os.path.join(REPO, "benchmark", "tests", "data",
+                         "small.xplane.pb")
+    assert trace_report.main(["--xplane", small]) == 0
+    out = capsys.readouterr().out
+    assert "no pt: spans" in out
+    assert "busy 0.000366 s" in out and "idle 0.034417 s" in out
+    host = [("bench:engine_step", 0.0, 10.0), ("pt:iteration", 0.5, 9.5),
+            ("pt:engine_step", 1.0, 8.0), ("pt:logits_fetch", 2.0, 5.0),
+            ("pt:pick", 5.0, 6.0), ("pt:deliver", 8.5, 9.0)]
+    named = trace_report.split_gaps([(4.5, 8.75), (20.0, 21.0)], host, xp)
+    assert named == pytest.approx({
+        "pt:logits_fetch": 0.5, "pt:pick": 1.0, "pt:engine_step": 2.0,
+        "pt:iteration": 0.5, "pt:deliver": 0.25, "unannotated": 1.0})
+    # the decode step's two handshakes bound the clocks' offset: the
+    # program started 0.2 after its dispatch began (the device is at
+    # most 0.2 ahead) and ended 0.499 before the fetch returned (at
+    # least -0.499 ahead); another program's events do not count
+    host.append(("pt:decode_dispatch", 1.3, 1.9))
+    mods = [("jit_step(123)", 1.5, 4.501), ("jit_fill(9)", 4.6, 4.7)]
+    upper, lower = trace_report.clock_check(mods, host)
+    assert upper == pytest.approx([0.2])
+    assert lower == pytest.approx([-0.499])
+    # a fetch that "returned" 1 ms before its program ended: the
+    # device's clock is at least that far ahead
+    _, lower = trace_report.clock_check([("jit_step(1)", 1.5, 5.001)],
+                                        host)
+    assert lower == pytest.approx([0.001])
+    # the gaps named again with the device moved along that interval:
+    # two operations leave the device idle from 4.5 to 9
+    ops = [(0.0, 4.5), (9.0, 10.0)]
+    window_s, idle_s, n_gaps, named = trace_report.idle_by_span(
+        ops, host, 0.0, xp)
+    assert (window_s, idle_s, n_gaps) == (10.0, 4.5, 1)
+    assert trace_report.leaf_share(named) == pytest.approx(100 * 2 / 4.5)
+    named = trace_report.idle_by_span(ops, host, 0.499, xp)[3]
+    assert named["pt:logits_fetch"] == pytest.approx(0.001)
+    assert named["pt:deliver"] == pytest.approx(0.5)
+
+
+def test_trace_report_engine_mode(model, tmp_path, capsys):
+    """``tools/trace_report.py --engine`` on the ``/v1/trace/engine``
+    payload of a scripted run: the iteration's budget by phase, the one
+    stalled iteration, the bucket fill and the prefill's chunk plan."""
+    from paddle_tpu.models.llama import llama_tiny
+    TRACER.enable()
+    eng = ContinuousBatchingEngine(
+        llama_tiny(max_position_embeddings=1024), model[1], max_batch=2,
+        block_size=8, num_blocks=128, prefill_buckets=(128, 512),
+        enable_prefix_caching=False)
+    fe = ServingFrontend(eng)
+    fe.submit(_prompt(model, 20), 12)
+    fe.step()
+    fe.submit(_prompt(model, 700), 2)
+    _drain(fe)
+    path = tmp_path / "engine.json"
+    path.write_text(json.dumps(TRACER.timeline().to_dict()))
+    assert trace_report.main(["--engine", str(path)]) == 0
+    out = capsys.readouterr().out
+    n = len(TRACER.timeline().iterations())
+    assert out.startswith(f"{n} iterations (numbers 1-{n}, root iteration")
+    assert f"were live: 1 of {n} =" in out
+    assert f"decode steps 1 of {eng.decode_slot_steps} =" in out
+    assert "useful / dispatched: 720 / 896 = 80.36%" in out
+    assert "dispatching a chunk of 128: 3 times" in out
+    rows = {ln.split()[0]: ln.split() for ln in out.splitlines()
+            if len(ln.split()) == 7 and ln.split()[1].isdigit()}
+    assert rows["prefill_chunk"][1:3] == ["4", "2"]      # spans, in_iters
+    assert rows["iteration"][1:3] == [str(n), str(n)]
+    assert any(ln.startswith("  512 + 128 + 128") and ln.split()[-3] == "1"
+               for ln in out.splitlines())
+    path.write_text(json.dumps({"dropped": 0, "iterations": []}))
+    assert trace_report.main(["--engine", str(path)]) == 0
+    assert "no iterations" in capsys.readouterr().out
 
 
 # ---------------------------------------------------------------------
