@@ -828,9 +828,11 @@ def block_multihead_attention(qkv, key_cache, value_cache, seq_lens_encoder,
         out = paged_decode_attention(q, kc, vc, bt, dec_lens + 1)
         return out, kc, vc
 
+    # inference-only, as in the reference (no grad kernel): the page
+    # walk's trip count is data, which reverse mode cannot unroll
     return run_op("block_multihead_attention", impl,
                   (qkv, key_cache, value_cache, seq_lens_decoder,
-                   block_tables), {})
+                   block_tables), {}, differentiable=False)
 
 
 def variable_length_memory_efficient_attention(query, key, value,

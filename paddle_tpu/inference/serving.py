@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.decode_block import make_norm_ffn as _make_rms_ffn  # noqa: F401
+from ..ops.paged_kv import decode_walk
 #   ^ the norm/FFN closure pair moved to ops/decode_block.py (ISSUE 9)
 #     so the decode step, the chunk fill, and the spec-decode draft all
 #     read one definition; the old name stays importable for callers.
@@ -149,14 +150,19 @@ def build_sampler():
 
 def sched_ratios(s: Dict[str, object]) -> Dict[str, object]:
     """``scheduler_stats`` counters (one engine's, or a fleet's summed)
-    plus the two ratios they exist for; None before the first prefill
-    / decode step."""
+    plus the ratios they exist for; None before the first prefill /
+    decode step."""
     disp = s.get("prefill_tokens_dispatched", 0)
     steps = s.get("decode_slot_steps", 0)
+    table = s.get("decode_pages_table", 0)
+    walked = s.get("decode_pages_walked", 0)
     s["bucket_fill"] = (s.get("prefill_tokens_computed", 0) / disp
                         if disp else None)
     s["stalled_share"] = (s.get("stalled_slot_iterations", 0) / steps
                           if steps else None)
+    s["kv_walk_share"] = walked / table if table else None
+    s["kv_walk_fill"] = (s.get("decode_pages_live", 0) / walked
+                         if walked else None)
     return s
 
 
@@ -244,9 +250,13 @@ class ContinuousBatchingEngine:
 
     The engine keeps its own page table rather than reusing
     ops/paged_kv.PagedKVCache: that class sizes its table [B, num_blocks]
-    (every slot could own the whole pool), while the decode gather cost
-    scales with TABLE WIDTH — the engine's [B, max_blocks_per_seq] table
-    keeps the per-step gather at the per-sequence cap, not the pool size.
+    (every slot could own the whole pool); the engine's
+    [B, max_blocks_per_seq] table is the served context an operator
+    allows.  The decode step's attention walks that table only as far
+    as the longest live sequence reaches (``paged_decode_attention``;
+    ``scheduler_stats()["kv_walk_share"]``), so a wide table costs a
+    short request nothing per token; the chunk fill still gathers the
+    row's whole width.
     """
 
     def __init__(self, cfg, params, *, max_batch: int = 4,
@@ -378,6 +388,12 @@ class ContinuousBatchingEngine:
         self.prefill_chunks = 0
         self.prefill_tokens_dispatched = 0
         self.stalled_slot_iterations = 0
+        # the decode program's page walk (ISSUE 28), per plain decode
+        # dispatch: table entries its attention gathered over all B
+        # rows (ops.paged_kv.decode_walk, the program's own arithmetic)
+        # and those of them that hold a live token of an active slot
+        self.decode_pages_walked = 0
+        self.decode_pages_live = 0
         # the engine timeline while the tracer is on, else None: set
         # once per step(), read by the phases underneath it
         self._tl = None
@@ -1506,6 +1522,13 @@ class ContinuousBatchingEngine:
             out = self.finished
             self.finished = {}
             return out
+        # the attention of this dispatch sees each row's stored tokens
+        # plus the one it appends
+        seen = self.lengths + 1
+        trips, chunk_pages = decode_walk(seen, self.MB, self.BS)
+        self.decode_pages_walked += trips * chunk_pages * self.B
+        self.decode_pages_live += int(
+            np.sum(-(-seen[active] // self.BS)))
         m0 = time.monotonic() if tl else 0.0
         sp = tl and tl.enter("decode_dispatch", batch=len(active))
         self.pool_k, self.pool_v, logits = self._step(
@@ -1647,8 +1670,19 @@ class ContinuousBatchingEngine:
         (``ServeMetrics.publish_engine``): ``bucket_fill`` is useful
         over dispatched (padded) prefill tokens, ``stalled_share`` the
         share of per-slot decode steps that began behind a prefill —
-        the streams whose token gap paid for somebody's prompt."""
+        the streams whose token gap paid for somebody's prompt;
+        ``kv_walk_share`` the part of the page table the decode
+        attention gathered (1.0 = its whole width every step),
+        ``kv_walk_fill`` the part of what it gathered that was live.
+        Both count plain decode dispatches: a speculative dispatch
+        (K+1 inner steps of the same program) is left out of the
+        walked pages and of the table they are divided by."""
+        plain = self.decode_steps - (
+            self._spec.stats["spec_steps"] if self._spec else 0)
         s: Dict[str, object] = {
+            "decode_pages_walked": self.decode_pages_walked,
+            "decode_pages_live": self.decode_pages_live,
+            "decode_pages_table": plain * self.B * self.MB,
             "admissions": self.admissions,
             "prefill_chunks": self.prefill_chunks,
             "prefill_tokens_dispatched": self.prefill_tokens_dispatched,

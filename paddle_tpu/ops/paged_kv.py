@@ -4,10 +4,12 @@ python/paddle/incubate/nn/functional/block_multihead_attention.py).
 
 TPU-first: the physical cache is one pooled array
 ``[num_blocks, block_size, H_kv, D]`` per k/v; sequences own logical pages
-through an int32 ``block_table [B, max_blocks]``.  The decode step gathers
-a sequence's pages with one XLA gather (rides HBM at full bandwidth; no
-pointer chasing like the CUDA kernel — the gather IS the page walk) and
-runs the same online-softmax math as the dense MMHA.  The host-side
+through an int32 ``block_table [B, max_blocks]``.  The decode step walks
+the table a chunk of columns at a time, as far as the longest live
+sequence reaches: each trip is one XLA gather of that chunk's pages
+(rides HBM at full bandwidth; no pointer chasing like the CUDA kernel —
+the gather IS the page walk) folded into the same online-softmax math
+as the dense MMHA.  The host-side
 :class:`BlockAllocator` mirrors the reference's block manager: free-list
 allocate/extend/release so unrelated sequences share the pool.
 """
@@ -25,9 +27,13 @@ __all__ = ["BlockAllocator", "PagedKVCache", "PagedKVGeometryError",
            "QuantizedKVPool", "paged_decode_attention", "paged_append",
            "validate_paged_decode_geometry", "quantize_kv",
            "dequantize_kv", "kv_page_bytes", "zeros_kv_pool",
-           "pool_geometry", "is_quantized_pool"]
+           "pool_geometry", "is_quantized_pool", "decode_walk"]
 
 NEG_INF = -1e30
+
+# Positions one trip of the decode page walk covers in a table wide
+# enough to need several (16 pages of 16 tokens).
+WALK_POSITIONS = 256
 
 # Scale floor for int8 KV quantization (all-zero rows — fresh pool
 # pages — must not divide by zero; codes stay 0 and dequantize to 0).
@@ -285,6 +291,26 @@ def paged_append(pool_k, pool_v, k_new, v_new, block_table, lengths,
     return pool_k, pool_v
 
 
+def decode_walk(lengths, max_blocks: int, block_size: int):
+    """``(trips, chunk_pages)`` of :func:`paged_decode_attention`'s page
+    walk over a ``[B, max_blocks]`` table: each trip gathers
+    ``chunk_pages`` columns of every row, and ``trips`` covers the
+    longest of ``lengths`` (tokens valid per row, capped at the table's
+    width).  ``chunk_pages`` is static: about :data:`WALK_POSITIONS`
+    positions, evened out over the table so that the last chunk is not
+    mostly padding, and the whole table when it is narrower than that.
+    ``trips`` follows ``lengths``: a traced scalar inside a program, an
+    ``int`` for the numpy array the engine keeps on the host — ONE
+    arithmetic, so the program's walk and the engine's
+    ``decode_pages_walked`` cannot drift."""
+    per_chunk = max(1, WALK_POSITIONS // block_size)
+    chunk_pages = -(-max_blocks // -(-max_blocks // per_chunk))
+    xp = np if isinstance(lengths, np.ndarray) else jnp
+    longest = xp.minimum(xp.max(lengths), max_blocks * block_size)
+    trips = -(-longest // (chunk_pages * block_size))
+    return (int(trips) if xp is np else trips), chunk_pages
+
+
 def paged_decode_attention(q, pool_k, pool_v, block_table, lengths,
                            scale: Optional[float] = None):
     """One decode step over a paged cache (reference
@@ -294,10 +320,16 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, lengths,
     block_table: [B, MB]; lengths: [B] tokens valid (AFTER appending the
     current token).  Returns [B, Hq, D].
 
-    The per-sequence page walk is ``jnp.take(pool, table)`` — one gather
-    producing [B, MB, BS, H, D] views; XLA fuses the mask+softmax chain
-    behind it, so HBM traffic is the same as a contiguous cache of length
-    MB*BS.
+    The table is walked in chunks of columns (:func:`decode_walk`): each
+    trip gathers that chunk's pages of every row ([B, chunk, BS, Hkv,
+    D]), scores them, masks positions at or past ``lengths`` and folds
+    them into a running maximum, sum and output in float32 (the
+    recurrence of ``ops/pallas/decode_attention.py``).  The trip count
+    is computed from ``lengths`` where the program runs, so the work
+    follows the LONGEST LIVE SEQUENCE, not the table's width, inside one
+    compiled program: columns past the last trip are never read.  An
+    unmapped entry (-1) reads page 0 and is masked like any position
+    past its row's length; a row of length 0 yields finite output.
 
     Raises :class:`PagedKVGeometryError` (trace time, offending shapes
     in the message) when the q/pool/table geometry is inconsistent —
@@ -310,26 +342,48 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, lengths,
     MB = block_table.shape[1]
     G = Hq // Hkv
     s = scale if scale is not None else 1.0 / math.sqrt(D)
+    lengths = jnp.minimum(jnp.asarray(lengths), MB * BS)
+    trips, C = decode_walk(lengths, MB, BS)
+    T = C * BS                                  # positions per trip
     bt = jnp.maximum(jnp.asarray(block_table), 0)     # -1 -> page 0 (masked)
-    if is_quantized_pool(pool_k):
-        # gather codes + scales, dequantize to fp32 views; the rest of
-        # the math is EXACTLY the full-width path's (the gathered pages
-        # are already fp32, so the einsum/softmax chain is shared)
-        k = dequantize_kv(jnp.take(pool_k.data, bt, axis=0),
-                          jnp.take(pool_k.scale, bt, axis=0))
-        v = dequantize_kv(jnp.take(pool_v.data, bt, axis=0),
-                          jnp.take(pool_v.scale, bt, axis=0))
-    else:
-        k = jnp.take(pool_k, bt, axis=0)              # [B, MB, BS, Hkv, D]
-        v = jnp.take(pool_v, bt, axis=0)
-    k = k.reshape(B, MB * BS, Hkv, D)
-    v = v.reshape(B, MB * BS, Hkv, D)
-    qg = q.reshape(B, Hkv, G, D)
-    logits = jnp.einsum("bkgd,btkd->bkgt", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) * s
-    mask = jnp.arange(MB * BS)[None, None, None, :] \
-        < jnp.asarray(lengths)[:, None, None, None]
-    logits = jnp.where(mask, logits, NEG_INF)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgt,btkd->bkgd", p, v.astype(jnp.float32))
+    bt = jnp.pad(bt, ((0, 0), (0, (-MB) % C)))  # whole chunks only
+    qg = q.reshape(B, Hkv, G, D).astype(jnp.float32)
+
+    def gather(pool, cols):                     # -> [B, T, Hkv, D] fp32
+        # mode="clip": the default ("fill") adds a select over every
+        # gathered element to NaN out-of-range pages; a table holds
+        # page numbers of its own pool
+        if is_quantized_pool(pool):
+            # codes + scales of this chunk only; the math after the
+            # dequantize is EXACTLY the full-width path's
+            x = dequantize_kv(
+                jnp.take(pool.data, cols, axis=0, mode="clip"),
+                jnp.take(pool.scale, cols, axis=0, mode="clip"))
+        else:
+            x = jnp.take(pool, cols, axis=0,
+                         mode="clip").astype(jnp.float32)
+        return x.reshape(B, T, Hkv, D)
+
+    def fold(i, carry):
+        m, l, acc = carry
+        cols = jax.lax.dynamic_slice_in_dim(bt, i * C, C, axis=1)
+        logits = jnp.einsum("bkgd,btkd->bkgt", qg,
+                            gather(pool_k, cols)) * s
+        live = (i * T + jnp.arange(T))[None, None, None, :] \
+            < lengths[:, None, None, None]
+        logits = jnp.where(live, logits, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
+        p = jnp.exp(logits - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + jnp.sum(p, axis=-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bkgt,btkd->bkgd", p, gather(pool_v, cols))
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(
+        0, trips, fold,
+        (jnp.full((B, Hkv, G), NEG_INF, jnp.float32),
+         jnp.zeros((B, Hkv, G), jnp.float32),
+         jnp.zeros((B, Hkv, G, D), jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.reshape(B, Hq, D).astype(q.dtype)

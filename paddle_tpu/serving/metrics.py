@@ -337,6 +337,10 @@ class ServeMetrics:
                 g("serve.sched.bucket_fill").set(sched["bucket_fill"])
             if sched["stalled_share"] is not None:
                 g("serve.sched.stalled_share").set(sched["stalled_share"])
+            if sched["kv_walk_share"] is not None:
+                g("serve.sched.kv_walk_share").set(sched["kv_walk_share"])
+            if sched["kv_walk_fill"] is not None:
+                g("serve.sched.kv_walk_fill").set(sched["kv_walk_fill"])
         fleet = engine.fleet_stats() \
             if hasattr(engine, "fleet_stats") else None
         if fleet is not None:

@@ -824,6 +824,63 @@ def test_timeline_counters_scripted_admission(model, tracer_on):
         and pf.attrs["cached_tokens"] == 0
 
 
+def test_decode_walk_counters_scripted(model):
+    """Two requests of known lengths through a table four chunks wide
+    (128 pages of 8 tokens, 32 pages a trip): the decode program's page
+    walk counted to the unit, tracer off, by the arithmetic the program
+    itself uses — and the operator's gauges and the router's rollup."""
+    from paddle_tpu.models.llama import llama_tiny
+    from paddle_tpu.ops.paged_kv import decode_walk
+    cfg = llama_tiny(max_position_embeddings=1024)
+    geom = dict(max_batch=2, block_size=8, num_blocks=128,
+                prefill_buckets=(128, 512), enable_prefix_caching=False)
+    eng = ContinuousBatchingEngine(cfg, model[1], **geom)
+    assert (eng.MB, decode_walk(eng.lengths + 1, eng.MB, eng.BS)) \
+        == (128, (1, 32))
+    assert eng.scheduler_stats()["kv_walk_share"] is None
+    reg = MetricsRegistry(enabled=True)
+    fe = ServingFrontend(eng, registry=reg)
+    a = fe.submit(_prompt(model, 20), 6)
+    fe.step()           # the attention sees 21 tokens of A: one trip
+    assert (eng.decode_pages_walked, eng.decode_pages_live) == (64, 3)
+    b = fe.submit(_prompt(model, 300), 3)
+    fe.step()           # A 22, B 301: two trips over both rows
+    assert (eng.decode_pages_walked, eng.decode_pages_live) \
+        == (64 + 128, 3 + 3 + 38)
+    _drain(fe)          # A 23 + B 302, then A alone at 24 and 25
+    assert (len(a.tokens()), len(b.tokens())) == (6, 3)
+    assert eng.decode_steps == 5
+    assert (eng.decode_pages_walked, eng.decode_pages_live) \
+        == (64 + 128 + 128 + 64 + 64, 3 + 41 + 41 + 3 + 4)
+    st = eng.scheduler_stats()
+    assert st["decode_pages_table"] == 5 * 2 * 128
+    assert st["kv_walk_share"] == 448 / 1280 < 1
+    assert st["kv_walk_fill"] == 92 / 448
+    assert reg.gauge("serve.sched.kv_walk_share").value == 448 / 1280
+    assert reg.gauge("serve.sched.kv_walk_fill").value == 92 / 448
+    text = reg.prometheus_text()
+    assert "serve_sched_kv_walk_share" in text \
+        and "serve_sched_kv_walk_fill" in text
+    # the router sums the integers and takes the ratios over the sums
+    cfg_params = (cfg, model[1])
+    fe = ServingFrontend(_router(cfg_params, **geom))
+    fe.submit(_prompt(model, 20), 3)
+    fe.submit(_prompt(model, 300), 3)
+    _drain(fe)
+    per = [r.sup.scheduler_stats() for r in fe.engine._live()]
+    assert all(p["decode_pages_walked"] for p in per)
+    tot = fe.engine.scheduler_stats()
+    for k in ("decode_pages_walked", "decode_pages_live",
+              "decode_pages_table"):
+        assert tot[k] == sum(p[k] for p in per)
+    # one replica walked one trip a step, the other two: 2 steps each
+    assert (tot["decode_pages_walked"], tot["decode_pages_table"]) \
+        == (2 * 64 + 2 * 128, 4 * 2 * 128)
+    assert tot["kv_walk_share"] == 384 / 1024
+    assert tot["kv_walk_fill"] == \
+        tot["decode_pages_live"] / tot["decode_pages_walked"]
+
+
 def test_timeline_ring_keeps_newest_and_counts_dropped(monkeypatch):
     monkeypatch.setattr(tracing, "TIMELINE_CAPACITY", 4)
     tl = Timeline()
