@@ -140,10 +140,11 @@ def sweep(args):
         traffic = copy.deepcopy(ctx.traffic)
         traffic["arrivals"]["rate_rps"] = rate
         run.plan = request_plan(traffic, args.seed, args.seconds,
-                                run.cfg.vocab_size)
+                                int(ctx.config["vocab_size"]))
         run.counters_at_open = run._counters()
         out = run.window()          # closes the frontend, cancels the rest
-        say(ctx, rate_rps=rate, window_s=out["window_s"], notes=out["notes"],
+        say(ctx, rate_rps=rate, window_s=out["window_s"],
+            attempted=out["attempted"], notes=out["notes"],
             end_to_end=out["end_to_end"],
             finished_per_s=out["notes"]["finished"] / out["window_s"])
         run.eng, run.fe = eng, ServingFrontend(eng)

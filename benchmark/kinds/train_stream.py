@@ -15,7 +15,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from ..lib import compare, flops, model
+from ..lib import compare, model
 from ..lib.traffic import PackedDocuments
 
 
@@ -24,25 +24,22 @@ class Run:
         self.ctx = ctx
         self.hooks = hooks
         self.train = ctx.config["assumed"]["train"]
+        self.prog = model.program_module(ctx.config)
         self.first: Dict = {}
         self.keep_batches = True     # until the followed steps are done
 
     # -- set-up --------------------------------------------------------
     def set_up(self) -> None:
         import jax
-        import jax.numpy as jnp
         from paddle_tpu import parallel as dist
         from paddle_tpu.io import DataLoader
-        from paddle_tpu.models.llama import build_llama_train_step
-        ctx, tr = self.ctx, self.train
-        cfg = model.program_config(ctx.config)
+        ctx, tr, prog = self.ctx, self.train, self.prog
+        cfg = prog.program_config(ctx.config)
         topo = dist.init_topology(devices=list(ctx.devices))
-        step_fn, init_fn = build_llama_train_step(
-            cfg, topo, num_microbatches=tr["num_microbatches"],
-            remat=tr["remat"], sharding_stage=tr["sharding_stage"],
-            learning_rate=tr["learning_rate"])
+        step_fn, init_fn = prog.build_train_step(cfg, topo, tr)
         self.batch, self.seq = ctx.traffic["batch"], ctx.traffic["seq_len"]
-        data = PackedDocuments(ctx.traffic, ctx.seed, cfg.vocab_size)
+        data = PackedDocuments(ctx.traffic, ctx.seed,
+                               ctx.config["vocab_size"])
         self.feed = iter(DataLoader(
             data, batch_size=self.batch, shuffle=False, drop_last=True,
             num_workers=0, device_prefetch=2))
@@ -51,7 +48,7 @@ class Run:
         # the state: the program's own layout, holding the reference's
         # draw of the weights and zero moments
         state = init_fn(0)
-        mine = model.make_params(ctx.config, ctx.seed)
+        mine = prog.make_params(ctx.config, ctx.seed)
         state["params"] = jax.tree.map(
             lambda new, old: jax.device_put(new, old.sharding),
             mine, state["params"])
@@ -68,38 +65,6 @@ class Run:
             # tests only: the timed path broken on purpose
             self.step = self.hooks.compiled(self.step)
 
-        b1 = tr["adam_betas"][0]
-
-        @jax.jit
-        def grad_norms(m, params):
-            """Per (layer, leaf): the first gradient as Adam got it,
-            from the first moment after one step, m1 = (1 - b1) g.  On
-            one chip a moment buffer is its leaf's rows, in order."""
-            out = {}
-            for k, p in params.items():
-                if k == "blocks":
-                    for n, q in p.items():
-                        g = m["blocks"][n].reshape(q.shape) / (1 - b1)
-                        out[n] = jnp.sqrt(jnp.sum(
-                            jnp.square(g), axis=tuple(range(2, q.ndim))))[0]
-                else:
-                    out[k] = jnp.sqrt(jnp.sum(jnp.square(
-                        m[k].reshape(p.shape) / (1 - b1))))
-            return out
-
-        @jax.jit
-        def delta_norms(params, fresh):
-            def nrm(a, b, axes):
-                return jnp.sqrt(jnp.sum(jnp.square(
-                    a.astype(jnp.float32) - b.astype(jnp.float32)),
-                    axis=axes))
-            out = {k: nrm(p, fresh[k], None) for k, p in params.items()
-                   if k != "blocks"}
-            for n, q in params["blocks"].items():
-                out[n] = nrm(q, fresh["blocks"][n],
-                             tuple(range(2, q.ndim)))[0]
-            return out
-
         # the first steps, through the window's own call and feed
         follow = int(ctx.traffic["follow_steps"])
         losses, batches = [], []
@@ -112,11 +77,11 @@ class Run:
             if k < follow:
                 losses.append(float(loss))
             if k == 0:
-                gn = grad_norms(state["opt"]["m"], state["params"])
+                gn = prog.first_grad_norms(state, tr)
                 gn = jax.tree.map(np.asarray, gn)
             if k == follow - 1:
-                dn = delta_norms(state["params"],
-                                 model.make_params(ctx.config, ctx.seed))
+                dn = prog.param_change_norms(
+                    state, prog.make_params(ctx.config, ctx.seed))
                 dn = jax.tree.map(np.asarray, dn)
         jax.block_until_ready(state)
         self.keep_batches = False
@@ -177,18 +142,17 @@ class Run:
         tokens = steps * self.batch * self.seq
         if cut_steps is None:
             cut_steps, cut_t = steps, window_s
-        z = ctx.config
         host_tok_s = cut_steps * self.batch * self.seq / cut_t
+        # the step's required work, under the program module's own
+        # names: x the steps before the trace, x the traced steps
+        work: Dict = {"window_s": cut_t}
+        for k, v in self.prog.train_step_work(
+                ctx.config, self.batch, self.seq).items():
+            work[f"window_{k}"] = v * cut_steps
+            work[f"traced_{k}"] = v * traced_steps
         readings = {
             "counters": {"steps": cut_steps, "window_ms": cut_t * 1e3},
-            "spans": {},
-            "work": {
-                "window_flops": flops.train_flops_per_token(z, self.seq)
-                * cut_steps * self.batch * self.seq,
-                "window_s": cut_t,
-                "traced_attn_flops": traced_steps
-                * flops.train_attention_flops(z, self.batch, self.seq)},
-        }
+            "spans": {}, "work": work}
         return {"attempted": steps,
                 "failed": sum(not math.isfinite(x) for x in losses),
                 "window_s": window_s,

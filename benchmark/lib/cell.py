@@ -151,8 +151,12 @@ def open_cell(name: str, seed: int, seconds: float, trace: bool, *,
     watch = setup.CompileWatch().install()
     devices = find_devices(int(workload["chips"]), allow_cpu)
     phases.mark("device")           # the runtime reaches the chip here
-    peaks = None if (allow_cpu and devices[0].platform != "tpu") \
-        else peaks_of(devices[0].device_kind)
+    try:
+        peaks = peaks_of(devices[0].device_kind)
+    except KeyError:
+        if not allow_cpu:
+            raise
+        peaks = None                # a CPU rehearsal: no share of a peak
     ctx = Context(workload, config, traffic, seed, seconds, trace, t0,
                   phases, watch, devices, peaks, cache_dir)
     ctx.runtime_env = runtime_env
@@ -203,8 +207,12 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         device["window_s"] = summary["window_s"]
         readings = dict(outcome["readings"], trace=summary, peaks=peaks)
         metrics = reduce_metrics(metric_defs, readings)
-        result["breakdown"] = {"device_ops": summary["device_ops"],
-                               "idle_gaps": summary["idle_gaps"]}
+        # the result line's lists hold ten entries at the most; what
+        # lies below the tenth is on a line of its own
+        say(out, event="breakdown", workload=name,
+            device_ops=summary["device_ops"], idle_gaps=summary["idle_gaps"])
+        result["breakdown"] = {"device_ops": summary["device_ops"][:10],
+                               "idle_gaps": summary["idle_gaps"][:10]}
     else:
         metrics = {k: outcome["end_to_end"][k]
                    for k in workload["end_to_end"]}

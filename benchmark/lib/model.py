@@ -1,10 +1,14 @@
-"""From a configuration file to what the program's entry points take.
+"""The benchmark's files, found by name, and the two modules that a
+configuration file names.
 
 The file holds the model's published keys (as in its ``config.json``)
-and, under ``assumed``, what a deployment sets itself.  The weights are
-the reference's draw from the seed, made on the device in ONE jitted
-call in the served dtype and laid out as the program's entry points
-expect them (``{"wte", "head", "lnf_w", "blocks": {name: [1, L, ...]}}``).
+and, under ``assumed``, what a deployment sets itself.  ``reference``
+names its plain reference (``reference/<name>.py``, which imports
+nothing of the program) and ``program`` its program module
+(``programs/<name>.py``, the only code here that imports the program's
+model and engine classes).  The harness knows no model family: what is
+a family's — the configuration object, the layout of the weights, the
+entry points, the required work — it reaches through ``program_module``.
 """
 
 from __future__ import annotations
@@ -38,41 +42,10 @@ def reference_module(config: Dict[str, Any]):
         f"benchmark.reference.{config['reference']}")
 
 
-def program_config(config: Dict[str, Any]):
-    """The program's ``LlamaConfig`` for a published config."""
-    from paddle_tpu.models.llama import LlamaConfig
-    if config.get("sliding_window") is not None:
-        raise ValueError("the program has no sliding-window attention")
-    return LlamaConfig(
-        vocab_size=config["vocab_size"],
-        hidden_size=config["hidden_size"],
-        intermediate_size=config["intermediate_size"],
-        num_layers=config["num_hidden_layers"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        max_position_embeddings=config["max_position_embeddings"],
-        rms_norm_eps=config["rms_norm_eps"],
-        rope_theta=config["rope_theta"],
-        initializer_range=config.get("initializer_range", 0.02),
-        tie_word_embeddings=config.get("tie_word_embeddings", False),
-        dtype=dtype_of(config))
-
-
-def make_params(config: Dict[str, Any], seed: int):
-    """The reference's weights for ``seed`` as the program's tree, on
-    the default device, in one jitted call."""
-    import jax
-    import jax.numpy as jnp
-    ref = reference_module(config)
-    dt = jnp.dtype(dtype_of(config))
-    L = ref.sizes(config)["L"]
-
-    @jax.jit
-    def draw(key):
-        blocks = jax.vmap(lambda i: ref.layer_weights(config, key, i, dt))(
-            jnp.arange(L, dtype=jnp.int32))
-        out = dict(ref.outer_weights(config, key, dt))
-        out["blocks"] = {k: v[None] for k, v in blocks.items()}
-        return out
-
-    return draw(ref.seed_key(seed))
+def program_module(config: Dict[str, Any]):
+    """``programs/<program>.py`` — the one seam to the system under
+    test: the family's configuration object, weight layout, entry
+    points and required work.  The key is required: a configuration
+    that named no program would be run as some other family's."""
+    return importlib.import_module(
+        f"benchmark.programs.{config['program']}")

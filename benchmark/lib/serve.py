@@ -1,8 +1,7 @@
-"""What the two serving kinds share: the engine built as
-``serving/http.py:build_frontend`` builds it, the scripted warm-up, the
-single-threaded window that submits what is due and pumps
-``ServingFrontend.step``, and the comparison of served tokens with the
-reference.
+"""What the two serving kinds share: the engine that the configuration's
+program module builds, the scripted warm-up, the single-threaded window
+that submits what is due and pumps ``ServingFrontend.step``, and the
+comparison of served tokens with the reference.
 
 One thread drives everything: ``submit`` and ``step`` take the same
 lock in the program, so a second thread could only wait for it; one
@@ -16,7 +15,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from . import compare, flops, model
+from . import compare, model
 from .traffic import request_plan, seeded_rng
 
 
@@ -40,24 +39,21 @@ class ServeRun:
     def __init__(self, ctx, hooks=None):
         self.ctx, self.hooks = ctx, hooks
         self.eng_kw = dict(ctx.config["assumed"]["engine"])
+        self.prog = model.program_module(ctx.config)
 
     # -- set-up --------------------------------------------------------
     def set_up(self) -> None:
         import jax
-        from paddle_tpu.inference.serving import ContinuousBatchingEngine
         from paddle_tpu.observability.tracing import TRACER
         from paddle_tpu.serving import ServingFrontend
-        ctx = self.ctx
-        self.cfg = model.program_config(ctx.config)
-        params = model.make_params(ctx.config, ctx.seed)
+        ctx, prog = self.ctx, self.prog
+        vocab = int(ctx.config["vocab_size"])
+        params = prog.make_params(ctx.config, ctx.seed)
         jax.block_until_ready(params)
         ctx.phases.mark("state")
         kw = self.eng_kw
-        self.eng = ContinuousBatchingEngine(
-            self.cfg, params, max_batch=kw["max_batch"],
-            block_size=kw["block_size"], num_blocks=kw["num_blocks"],
-            max_blocks_per_seq=kw["max_blocks_per_seq"],
-            prefill_buckets=tuple(kw["prefill_buckets"]))
+        self.eng = prog.build_engine(prog.program_config(ctx.config),
+                                     params, kw)
         del params
         if self.hooks is not None:
             self.hooks.wrap_engine(self.eng)
@@ -65,16 +61,14 @@ class ServeRun:
         if ctx.trace:
             TRACER.reset()
             TRACER.enable()
-        self.plan = request_plan(ctx.traffic, ctx.seed, ctx.seconds,
-                                 self.cfg.vocab_size)
+        self.plan = request_plan(ctx.traffic, ctx.seed, ctx.seconds, vocab)
         ctx.phases.mark("build")
         # warm-up: a fixed script that runs every program the mix can
         # reach — each prefill bucket and the decode step — and nothing
         # else: one prompt as long as all buckets together, one short
         rng = seeded_rng(ctx.seed, 5)
         lens = [sum(kw["prefill_buckets"]), min(kw["prefill_buckets"]) // 2]
-        hs = [self.fe.submit(rng.integers(0, self.cfg.vocab_size, n,
-                                          dtype=np.int32),
+        hs = [self.fe.submit(rng.integers(0, vocab, n, dtype=np.int32),
                              int(ctx.traffic["warmup_new_tokens"]))
               for n in lens]
         self.fe.run_until_drained(timeout_s=1100)
@@ -83,10 +77,19 @@ class ServeRun:
         self.counters_at_open = self._counters()
 
     def _counters(self) -> Dict[str, int]:
+        """The program's counts as they stand: every whole number of
+        ``engine.stats`` and ``engine.scheduler_stats()`` under its own
+        name (a metric file names the ones it reads; a counter the
+        program adds later is there without an edit here), beside the
+        three the benchmark has always read."""
         e = self.eng
-        return {"decode_steps": e.decode_steps,
-                "decode_slot_steps": e.decode_slot_steps,
-                "prefill_tokens": e.stats["prefill_tokens_computed"]}
+        out = {k: v for src in (e.stats, e.scheduler_stats())
+               for k, v in src.items()
+               if isinstance(v, int) and not isinstance(v, bool)}
+        out.update(decode_steps=e.decode_steps,
+                   decode_slot_steps=e.decode_slot_steps,
+                   prefill_tokens=e.stats["prefill_tokens_computed"])
+        return out
 
     # -- the window ----------------------------------------------------
     def _submit(self, p: Dict, index: int, due: float) -> Req:
@@ -108,7 +111,7 @@ class ServeRun:
         lateness: List[float] = []
         nxt = 0
         cut_t = None
-        traced = {"flops": 0, "bytes": 0, "steps": 0}
+        traced: Dict[str, float] = {}
         backlog_mid = None
         ctx.window_opens()
         t_open = time.monotonic()
@@ -150,9 +153,9 @@ class ServeRun:
             if ctx.tracing and eng.decode_steps > steps0:
                 ctxs = [int(eng.lengths[s]) for s in range(eng.B)
                         if eng.slots[s] is not None]
-                traced["flops"] += flops.decode_flops(ctx.config, ctxs)
-                traced["bytes"] += flops.decode_bytes(ctx.config, ctxs)
-                traced["steps"] += 1
+                for k, v in self.prog.decode_step_work(
+                        ctx.config, ctxs).items():
+                    traced[k] = traced.get(k, 0) + v
         t_close = time.monotonic()
         window_s = t_close - t_open
         ctx.stop_trace()
@@ -198,39 +201,50 @@ class ServeRun:
 
     def _readings(self, reqs, t_open, cut_t, cut_counters, traced) -> Dict:
         """Spans, counts and required work of the window BEFORE the
-        profiler started (the host clock is clean there)."""
+        profiler started (the host clock is clean there), as plain data:
+        the tracer is reset when the run is released.
+
+        ``counters``: every count of ``_counters`` as the window's
+        difference.  ``iterations``: the engine timeline's span trees
+        that lie in that part of the window, one list a scheduler
+        iteration, each span ``{"name", "t0", "t1", "attrs"}`` on the
+        monotonic clock; ``spans``: the same spans' durations by name,
+        and ``ttft`` from the requests' own traces.  ``work``: what the
+        program module's ``request_work`` returns, summed over the
+        requests as far as each got, as ``window_<name>``; its
+        ``decode_step_work`` summed over the traced steps as
+        ``traced_<name>``; ``window_s``."""
+        from paddle_tpu.observability.tracing import TRACER
         t_cut = t_open + cut_t
         cfg = self.ctx.config
         c0 = self.counters_at_open
-        steps = set()
         ttft = []
-        work = 0
+        work: Dict[str, float] = {"window_s": cut_t}
         for r in reqs:
-            ts = [t for t in r.token_t if t <= t_cut]
-            p = len(r.prompt)
-            if ts:
-                work += flops.prefill_flops(cfg, p)
-                work += flops.decode_flops(
-                    cfg, [p + j for j in range(1, len(ts))])
+            n = sum(t <= t_cut for t in r.token_t)
+            if n:
+                for k, v in self.prog.request_work(
+                        cfg, len(r.prompt), n).items():
+                    work[f"window_{k}"] = work.get(f"window_{k}", 0) + v
             tr = r.handle.trace
-            if tr is None:
-                continue
-            for s in tr.snapshot():
-                if s.name == "decode_step":
-                    a = tr.mono_t0 + s.t0
-                    if t_open <= a and tr.mono_t0 + s.t1 <= t_cut:
-                        steps.add((round(a, 6), round(s.t1 - s.t0, 9)))
-            first = tr.meta.get("ttft_s")
+            first = tr.meta.get("ttft_s") if tr is not None else None
             if first is not None and tr.mono_t0 + first <= t_cut:
                 ttft.append(tr.mono_t0 + first - r.due)
-        return {
-            "counters": {k: cut_counters[k] - c0[k] for k in c0},
-            "spans": {"decode_step": [d for _, d in sorted(steps)],
-                      "ttft": ttft},
-            "work": {"window_flops": work, "window_s": cut_t,
-                     "traced_decode_flops": traced["flops"],
-                     "traced_decode_bytes": traced["bytes"]},
-        }
+        for k, v in traced.items():
+            work[f"traced_{k}"] = v
+        iterations, spans = [], {"ttft": ttft}
+        tl = TRACER.timeline()
+        for it in tl.iterations() if tl is not None else []:
+            root = it.spans[0]
+            if root.t0 < t_open or root.t1 > t_cut:
+                continue
+            iterations.append([{"name": s.name, "t0": s.t0, "t1": s.t1,
+                                "attrs": s.attrs or {}} for s in it.spans])
+            for s in it.spans:
+                spans.setdefault(s.name, []).append(s.t1 - s.t0)
+        return {"counters": {k: cut_counters[k] - c0.get(k, 0)
+                             for k in cut_counters},
+                "spans": spans, "iterations": iterations, "work": work}
 
     def release(self) -> None:
         from paddle_tpu.observability.tracing import TRACER

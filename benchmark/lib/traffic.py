@@ -3,7 +3,8 @@
 Every seed gets the SAME schedule of sizes and arrival gaps (drawn once
 from the mix's own ``plan_seed``) and its own token ids (and weights):
 so two seeds offer the same work, and a difference between two runs is
-the system's, not the draw's.  (Reordering the schedule by the seed
+the system's, not the draw's.  A Poisson schedule is stretched to hold
+exactly the arrivals its rate states (``request_plan``).  (Reordering the schedule by the seed
 was tried and moved the chat cell's tail by a sixth: PERF.md §7.)  The
 arithmetic — exponential gaps, a burst state, inclusive length ranges —
 is that of the program's ``serving/loadgen.py``, copied here so that a
@@ -58,10 +59,29 @@ def arrival_gaps(arrivals: Dict, n: int, rng: np.random.Generator
     return rng.exponential(1.0, n) / rate
 
 
+def expected_arrivals(arrivals: Dict, seconds: float) -> int:
+    """How many requests a Poisson mix offers in ``seconds``: the window
+    over the mean gap, to the nearest whole number."""
+    frac = float(arrivals.get("burst_fraction", 0.0))
+    mean_gap = (1.0 - frac) / float(arrivals["rate_rps"])
+    if frac > 0.0:
+        mean_gap += frac / float(arrivals["burst_rate_rps"])
+    return int(round(seconds / mean_gap))
+
+
 def request_plan(traffic: Dict, seed: int, seconds: float, vocab: int
                  ) -> List[Dict]:
     """The requests of one run, in arrival order: ``{"at", "prompt",
-    "max_new"}``.  ``at`` is the due time from the window's start."""
+    "max_new"}``.  ``at`` is the due time from the window's start.
+
+    A Poisson schedule is stretched so that the window holds exactly
+    the number of arrivals its rate states (the first that many; the
+    next one falls on the window's end): a Poisson process conditioned
+    on its count, since exponential gaps over their sum are the
+    spacings of uniform order statistics.  ONE schedule serves every
+    run, and one unstretched draw is off by its own chance, some tenth
+    of the count (PR 29 found 125 arrivals in 30 s at a stated 3.6 a
+    second, and 116 at a stated 4.5: PERF.md section 6)."""
     arrivals = traffic["arrivals"]
     if arrivals["process"] == "backlog":
         n = int(traffic["plan_requests"])
@@ -72,6 +92,10 @@ def request_plan(traffic: Dict, seed: int, seconds: float, vocab: int
     o_len = draw_lengths(traffic["output_tokens"], n, shape)
     gap = arrival_gaps(arrivals, n, shape)
     at = np.cumsum(gap)
+    if arrivals["process"] == "poisson":
+        k = expected_arrivals(arrivals, seconds)
+        at = at * (seconds / at[k])
+        at[k:] = np.maximum(at[k:], seconds)    # rounding lets none in
     ids = seeded_rng(seed, 2)
     return [{"at": float(at[i]),
              "prompt": ids.integers(0, vocab, int(p_len[i]),

@@ -1,15 +1,19 @@
 """From a profiler trace (``.xplane.pb``) to device busy time, the
 operations that took most of it, and the idle gaps named by what the
-host was doing.  Read with nothing but JAX's own ``ProfileData``.
+host was doing: the harness's own ``bench:`` annotations and the
+program's ``pt:`` spans (its engine timeline, on the same clock).  Read
+with nothing but JAX's own ``ProfileData``.
 
 What the trace of a TPU v5e holds (looked at by hand, PR 26): one plane
 ``/device:TPU:<n>`` a chip, with the lines ``XLA Modules`` (one event
 per executed program, ``jit_<fn>(<hash>)``) and ``XLA Ops`` (one per
 operation, named by its whole HLO line, ``%<name> = ...``); the host's
 threads are lines of ``/host:CPU``, and ``jax.profiler.TraceAnnotation``
-spans are events of its ``python`` line.  The device's clock ran about a
-millisecond ahead of the host's there, so a gap is named by the host
-span it overlaps most, not by exact containment."""
+spans are events of its ``python`` line.  The device's clock is not the
+host's (a millisecond ahead in PR 26's sessions, 0.2-3 ms behind in PR
+27's), so a gap's pieces are named to within that: of two neighbouring
+short spans only the sum holds (``pt:decode_dispatch`` +
+``pt:logits_fetch``)."""
 
 from __future__ import annotations
 
@@ -21,7 +25,9 @@ from typing import Dict, List, Optional, Tuple
 Interval = Tuple[float, float]          # start, end in seconds
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
-HOST_PREFIX = "bench:"
+#: host annotations that name an idle gap: the harness's own and the
+#: program's timeline spans (``tracing.PROFILER_PREFIX``)
+HOST_PREFIX = ("bench:", "pt:")
 WINDOW_SPAN = "bench:window"
 
 
@@ -65,7 +71,8 @@ def gaps(intervals: List[Interval], lo: float, hi: float
 def name_gap(gap: Interval, host_spans: List[Tuple[str, float, float]]
              ) -> str:
     """The innermost (shortest) host span among those that overlap the
-    gap most; ``unannotated`` when none does."""
+    gap most; ``unannotated`` when none does.  (For a gap taken whole:
+    ``tools/trace_report.py`` names its pieces with it.)"""
     best, best_key = "unannotated", (0.0, 0.0)
     for name, a, b in host_spans:
         ov = min(b, gap[1]) - max(a, gap[0])
@@ -75,6 +82,35 @@ def name_gap(gap: Interval, host_spans: List[Tuple[str, float, float]]
         if key > best_key:
             best, best_key = name, key
     return best
+
+
+def idle_by_span(idle: List[Interval],
+                 host_spans: List[Tuple[str, float, float]]
+                 ) -> Dict[str, float]:
+    """Idle seconds by the innermost host span at each instant: a gap
+    is cut at every span boundary inside it, so each piece lies within
+    or outside every span, and goes to the shortest it lies within.
+    (What ``tools/trace_report.py:split_gaps`` does, kept here so that
+    the program cannot move it; one sweep over both lists, since a
+    trace holds a gap for every operation.)"""
+    spans = sorted(host_spans, key=lambda s: s[1])
+    out: Dict[str, float] = {}
+    live: List[Tuple[str, float, float]] = []
+    i = 0
+    for a, b in sorted(idle):
+        while i < len(spans) and spans[i][1] < b:
+            live.append(spans[i])
+            i += 1
+        live = [s for s in live if s[2] > a]
+        edges = sorted({a, b} | {t for _, s, e in live for t in (s, e)
+                                 if a < t < b})
+        for lo, hi in zip(edges, edges[1:]):
+            mid = (lo + hi) / 2
+            inside = [s for s in live if s[1] <= mid < s[2]]
+            name = min(inside, key=lambda s: s[2] - s[1])[0] \
+                if inside else "unannotated"
+            out[name] = out.get(name, 0.0) + (hi - lo)
+    return out
 
 
 def self_seconds(events: List[Tuple[str, float, float]]
@@ -98,7 +134,7 @@ def self_seconds(events: List[Tuple[str, float, float]]
 def read(path: str) -> Dict:
     """Everything the reducers need, times in seconds on the trace's own
     axis: per device its op and module events, and the host's
-    ``bench:`` spans."""
+    ``bench:`` and ``pt:`` spans."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     devices, host = [], []
@@ -125,10 +161,12 @@ def read(path: str) -> Dict:
     return {"devices": devices, "host": host}
 
 
-def summarize(path: str, top: int = 10) -> Dict:
+def summarize(path: str, top: int = 20) -> Dict:
     """``busy_s`` (averaged over the chips), ``window_s``, the ``top``
-    operations by their OWN device time, the ``top`` idle gaps by host
-    span, and per-name whole durations for the pattern reducers."""
+    operations by their OWN device time, the ``top`` idle gaps by the
+    innermost host span, and per-name whole durations for the pattern
+    reducers.  (The result line's ``breakdown`` keeps the first ten of
+    each list; the run prints all ``top`` on a line of their own.)"""
     raw = read(path)
     if not raw["devices"]:
         raise ValueError(f"{path}: no /device:TPU plane in the trace")
@@ -158,9 +196,8 @@ def summarize(path: str, top: int = 10) -> Dict:
         for name, a, b in dev["modules"]:
             mod_time[name] = mod_time.get(name, 0.0) + (b - a)
             mod_count[name] = mod_count.get(name, 0) + 1
-        for g in gaps(iv, lo, hi):
-            n = name_gap(g, spans)
-            gap_time[n] = gap_time.get(n, 0.0) + (g[1] - g[0])
+        for n, secs in idle_by_span(gaps(iv, lo, hi), spans).items():
+            gap_time[n] = gap_time.get(n, 0.0) + secs
     if not busy:
         raise ValueError(f"{path}: no operation ran on a device")
     rank = lambda d: [[k, v] for k, v in sorted(

@@ -1,9 +1,9 @@
 """Mean duration of the spans named ``span`` (seconds), times
 ``scale``."""
 
+from . import aggregate
+
 
 def reduce(metric, readings):
-    xs = readings["spans"].get(metric["span"])
-    if not xs:
-        return None
-    return metric.get("scale", 1.0) * sum(xs) / len(xs)
+    return aggregate(readings["spans"].get(metric["span"]),
+                     {"scale": metric.get("scale", 1.0)})

@@ -1,6 +1,7 @@
 """Share (%) of the chip's peak that the window's required work took:
 ``work[flops]`` over ``work[seconds] x peak FLOP/s x chips``.  The
-FLOPs are what the model requires (``lib/flops.py``), so this is a
+FLOPs are what the model requires (the configuration's program
+module counts them, ``programs/<program>.py``), so this is a
 model-FLOPs utilisation."""
 
 

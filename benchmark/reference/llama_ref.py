@@ -6,7 +6,7 @@ head) in straightforward ``jax.numpy`` at float32 with
 ``Precision.HIGHEST``: no kernels, no cache, no batching tricks.  It
 imports nothing of ``paddle_tpu`` and takes nothing the program made:
 the weights are drawn HERE from the seed, and the harness hands the
-program the same draw (``benchmark/lib/model.py``).
+program the same draw (``benchmark/programs/llama.py``).
 
 Two entries decide ``correct``:
 

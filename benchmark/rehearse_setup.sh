@@ -8,12 +8,20 @@
 #
 #   chiprun -- bash benchmark/rehearse_setup.sh mistral7b-train 8 5
 #
+# With a fifth argument "sets" the two directories get the SAME seeds,
+# RUNS each (a, b, a, b, ... on seed+2, seed+2, seed+3, ...): the two
+# sets that a bound is set from, at the run length of BENCHMARK.json:
+#
+#   chiprun --timeout 3000 -- bash benchmark/rehearse_setup.sh \
+#       mistral7b-chat 6 30 2147484000 sets
+#
 # Run it from the root of the checkout, on the machine with the chip.
 set -euo pipefail
-cell=${1:?usage: rehearse_setup.sh <workload> [runs] [seconds] [first_seed]}
+cell=${1:?usage: rehearse_setup.sh <workload> [runs] [seconds] [first_seed] [sets]}
 runs=${2:-8}
 seconds=${3:-5}
 seed=${4:-2147484000}
+mode=${5:-alternate}
 base=.scratch/rehearse
 errs=$PWD/chiprun_out/rehearse_err
 out=chiprun_out/rehearse_${cell}.jsonl
@@ -50,14 +58,24 @@ print(json.dumps({"side": side, "run": tag, "seed": setup["seed"],
     "hits": c["cache_hits"], "misses": c["cache_misses"],
     "missed": c["missed"],
     "compiles_in_window": win.get("compiles_in_window"),
-    "correct": last["correct"],
+    "correct": last["correct"], "failed": last["failed"],
+    "notes": {k: v for k, v in (win.get("notes") or {}).items()
+              if k != "kv"},
+    "checks": {k: v["value"] for k, v in last.get("checks", {}).items()},
     "metrics": {k: v["value"] for k, v in last["metrics"].items()}}))
 ' "$1" "$3" | tee -a "$out"
 }
 one a "$seed" first
 one b "$((seed + 1))" first
-for i in $(seq 1 "$runs"); do
-  if [ $((i % 2)) -eq 1 ]; then side=a; else side=b; fi
-  one "$side" "$((seed + 1 + i))" "$i"
-done
+if [ "$mode" = sets ]; then
+  for i in $(seq 1 "$runs"); do
+    one a "$((seed + 1 + i))" "$i"
+    one b "$((seed + 1 + i))" "$i"
+  done
+else
+  for i in $(seq 1 "$runs"); do
+    if [ $((i % 2)) -eq 1 ]; then side=a; else side=b; fi
+    one "$side" "$((seed + 1 + i))" "$i"
+  done
+fi
 rm -rf "$base"

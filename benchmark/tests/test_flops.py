@@ -1,10 +1,15 @@
-"""The FLOP and byte functions against counts made by hand from the
-published sizes of Mistral-7B-v0.3 (H 4096, F 14336, 32 x 128 query
-heads, 8 KV heads, V 32768)."""
+"""The dense GQA family's FLOP and byte functions (``programs/llama.py``,
+found as the harness finds them: through the configuration's
+``program`` key) against counts made by hand from the published sizes
+of Mistral-7B-v0.3 (H 4096, F 14336, 32 x 128 query heads, 8 KV heads,
+V 32768)."""
 
 import pytest
 
-from benchmark.lib import flops, model
+from benchmark.lib import model
+
+flops = model.program_module(
+    model.load_json("configs", "mistral-7b-v0.3-serve"))
 
 # by hand, per layer: q and o 4096 x 4096 each, k and v 4096 x 1024 each,
 # gate, up and down 4096 x 14336 each
@@ -38,6 +43,9 @@ def test_train_flops_per_token():
     # the peak that cannot pass 100 until tokens/s pass 40,350
     assert 100 * want * 14951 / 197e12 == pytest.approx(37.05, abs=0.1)
     assert flops.train_attention_flops(cfg, 4, 2048) == 3 * 4 * attn
+    # what the harness puts into readings["work"] of one step
+    assert flops.train_step_work(cfg, 4, 2048) == {
+        "flops": pytest.approx(want * 4 * 2048), "attn_flops": 3 * 4 * attn}
 
 
 def test_decode_step_flops_and_bytes():
@@ -52,6 +60,14 @@ def test_decode_step_flops_and_bytes():
     assert flops.weight_bytes(cfg) == pytest.approx(7.25e9, rel=2e-3)
     assert flops.prefill_flops(cfg, 512) == \
         2 * n * 512 + 4 * 32 * 128 * 16 * (512 * 513 // 2)
+    # what the harness puts into readings["work"]: a decode step, and a
+    # request that got 3 tokens out (the first comes from the prefill)
+    assert flops.decode_step_work(cfg, ctx) == {
+        "decode_flops": flops.decode_flops(cfg, ctx),
+        "decode_bytes": flops.decode_bytes(cfg, ctx)}
+    assert flops.request_work(cfg, 512, 3) == {
+        "flops": flops.prefill_flops(cfg, 512)
+        + flops.decode_flops(cfg, [513, 514])}
 
 
 def test_unknown_device_kind_is_an_error():

@@ -88,12 +88,25 @@ def test_traced_run_reports_per_layer_metrics(root, monkeypatch):
     rc, lines, err = run(root, "tiny-chat", 5, seconds=2.0, trace=True)
     last = lines[-1]
     assert rc == 0 and last["correct"] is True, err
-    assert {"decode_batch.chat", "decode_step_ms.chat", "ttft_p50_ms.chat",
+    # counters, request traces, the engine timeline (a stretch within an
+    # iteration, a span chosen by its attributes, one span's share of
+    # another) and the profiler's trace
+    assert {"decode_batch.chat", "stalled_share.chat", "bucket_fill.chat",
+            "ttft_p50_ms.chat", "decode_step_ms.chat", "iter_host_ms.chat",
+            "prefill_stall_p95_ms.chat", "prefill_share.chat",
             "device_idle.chat"} <= set(last["metrics"])
+    m = {k: v["value"] for k, v in last["metrics"].items()}
+    assert 0 < m["prefill_share.chat"] < 100 and m["bucket_fill.chat"] <= 100
+    # the host's part of an iteration is less than the whole of one that
+    # also waits for a decode step
+    assert 0 < m["iter_host_ms.chat"] and 0 < m["decode_step_ms.chat"]
     # no peaks for a CPU: the share of a peak is left out, never 0
     assert "serve_mfu.chat" not in last["metrics"]
     assert last["device"]["busy_s"] > 0 and last["device"]["window_s"] > 0
     assert set(last["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert all(len(v) <= 10 for v in last["breakdown"].values())
+    longer = next(x for x in lines if x.get("event") == "breakdown")
+    assert longer["device_ops"][:10] == last["breakdown"]["device_ops"]
 
 
 # ---- the timed path broken underneath --------------------------------
@@ -175,32 +188,99 @@ def test_control_serve_is_not_correct(root):
 
 
 # ---- data-driven: new files are found, none is edited ----------------
-def test_new_cell_mix_and_metric_are_found_as_files(root):
-    def put(kind, name, obj):
-        with open(os.path.join(root, kind, name + ".json"), "w") as f:
-            json.dump(obj, f)
+FIXTURE = os.path.join(os.path.dirname(__file__), "data", "small.xplane.pb")
+
+
+def put(root, kind, name, obj):
+    with open(os.path.join(root, kind, name + ".json"), "w") as f:
+        json.dump(obj, f)
+
+
+def metric(name, cell, **kw):
+    return dict({"name": name, "unit": "x", "better": "higher",
+                 "layer": "scheduler + KV", "source": "program_counter",
+                 "moves": "serve_tok_s", "workloads": [cell]}, **kw)
+
+
+def test_new_cell_mix_and_metric_are_found_as_files(root, monkeypatch):
+    from benchmark.lib import xplane
     mix = model.load_json("traffic", "offline-batch", root)
     mix["prompt_tokens"] = dict(mix["prompt_tokens"], min=2, max=9)
-    put("traffic", "short-batch", mix)
+    put(root, "traffic", "short-batch", mix)
     cell = model.load_json("workloads", "tiny-batch", root)
-    put("workloads", "tiny-short", dict(cell, traffic="short-batch"))
-    put("metrics", "prefill_tokens.short", {
-        "name": "prefill_tokens.short", "unit": "tokens", "better": "higher",
-        "layer": "scheduler + KV", "source": "program_counter",
-        "moves": "serve_tok_s", "workloads": ["tiny-short"],
-        "reducer": "counter_ratio", "numerator": "prefill_tokens",
-        "denominator": "decode_steps"})
-    from benchmark.lib import xplane
-    fixture = os.path.join(os.path.dirname(__file__), "data",
-                           "small.xplane.pb")
-    real = xplane.find_xplane
-    xplane.find_xplane = lambda d: fixture
-    try:
-        rc, lines, err = run(root, "tiny-short", 4, seconds=1.0, trace=True)
-    finally:
-        xplane.find_xplane = real
+    put(root, "workloads", "tiny-short", dict(cell, traffic="short-batch"))
+    put(root, "metrics", "prefill_tokens.short", metric(
+        "prefill_tokens.short", "tiny-short", reducer="counter_ratio",
+        numerator="prefill_tokens", denominator="decode_steps"))
+    monkeypatch.setattr(xplane, "find_xplane", lambda d: FIXTURE)
+    rc, lines, err = run(root, "tiny-short", 4, seconds=1.0, trace=True)
     assert rc == 0 and lines[-1]["correct"] is True, err
     assert lines[-1]["metrics"]["prefill_tokens.short"]["value"] > 0
+
+
+def test_second_architecture_is_files_alone(root, monkeypatch):
+    """A program module, its reference, a configuration naming both, a
+    cell, and three metrics that read what the harness names nowhere: a
+    span and a counter of the new engine's own, and a roofline over the
+    new program's own work keys.  Nothing that exists is edited: the two
+    modules are FILES dropped beside the others (here: a directory
+    added to each package's search path)."""
+    import benchmark.programs
+    import benchmark.reference
+    from benchmark.lib import peaks, xplane
+    second = os.path.join(os.path.dirname(__file__), "second")
+    for pkg, sub in ((benchmark.programs, "programs"),
+                     (benchmark.reference, "reference")):
+        monkeypatch.setattr(pkg, "__path__", list(pkg.__path__)
+                            + [os.path.join(second, sub)])
+    harness = os.path.dirname(os.path.dirname(__file__))
+    named = subprocess.run(
+        ["grep", "-rlE", "state_sync|mixer_", os.path.join(harness, "lib"),
+         os.path.join(harness, "kinds"), os.path.join(harness, "reducers"),
+         os.path.join(harness, "programs")], capture_output=True, text=True)
+    assert named.stdout == ""        # the harness holds none of the names
+
+    cfg = model.load_json("configs", "mistral-7b-v0.3-serve", root)
+    put(root, "configs", "second-serve",
+        dict(cfg, program="second", reference="second_ref"))
+    cell = model.load_json("workloads", "tiny-batch", root)
+    put(root, "workloads", "second-batch", dict(cell, config="second-serve"))
+    put(root, "metrics", "state_sync_us.second", metric(
+        "state_sync_us.second", "second-batch", source="program_span",
+        reducer="span_mean", span="state_sync", scale=1e6))
+    put(root, "metrics", "state_syncs.second", metric(
+        "state_syncs.second", "second-batch", reducer="counter_ratio",
+        numerator="state_syncs", denominator="decode_steps"))
+    put(root, "metrics", "mixer_roofline.second", metric(
+        "mixer_roofline.second", "second-batch", unit="%",
+        layer="kernels", source="device_trace", reducer="trace_roofline",
+        pattern="convolution_tanh", line="ops",
+        flops="traced_mixer_flops", bytes="traced_mixer_bytes"))
+    put(root, "metrics", "second_mfu", metric(
+        "second_mfu", "second-batch", unit="%", layer="whole step, serve",
+        reducer="work_share", flops="window_flops", seconds="window_s"))
+    # a CPU has no device plane and no peaks: the recorded TPU trace,
+    # and a row of peaks for the kind the CPU reports
+    monkeypatch.setattr(xplane, "find_xplane", lambda d: FIXTURE)
+    monkeypatch.setitem(peaks.PEAKS, "cpu",
+                        {"flops": 197e12, "bytes_per_s": 819e9})
+    rc, lines, err = run(root, "second-batch", 6, seconds=2.0, trace=True)
+    last = lines[-1]
+    assert rc == 0 and last["correct"] is True, err
+    got = {k: v["value"] for k, v in last["metrics"].items()}
+    assert set(got) == {"state_sync_us.second", "state_syncs.second",
+                        "mixer_roofline.second", "second_mfu"}
+    assert 0 < got["state_sync_us.second"] < 1e4
+    # one sync a scheduler iteration, so at least one a decode step
+    assert got["state_syncs.second"] >= 1
+    # the roofline divides the SECOND program's work (2 * 2048**3 a
+    # sequence and step) by the trace's kernel time: llama's counts at
+    # this size would read under a millionth of it
+    assert got["mixer_roofline.second"] > 1
+    # 7 FLOPs a token: llama's count for the tiny model is ~2e5 a token
+    window = next(x for x in lines if x.get("event") == "window")
+    tokens = window["notes"]["tokens"]
+    assert 0 < got["second_mfu"] * 197e12 / 100 < 7 * 60 * tokens
 
 
 def test_benchmark_json_matches_the_files():
@@ -212,6 +292,11 @@ def test_benchmark_json_matches_the_files():
         assert c["file"] == f"benchmark/configs/{c['name']}.json"
         cfg = model.load_json("configs", c["name"])
         assert c["reduced"] == sorted(cfg["published"])
+        # both of its modules are files, found by the names it gives
+        assert os.path.isfile(os.path.join(
+            model.HERE, "programs", cfg["program"] + ".py"))
+        assert os.path.isfile(os.path.join(
+            model.HERE, "reference", cfg["reference"] + ".py"))
     e2e = {m["name"]: m for m in b["end_to_end"]}
     for w in b["workloads"]:
         f = model.load_json("workloads", w["name"])

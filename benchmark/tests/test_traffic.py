@@ -40,6 +40,14 @@ def test_request_plans():
             rate = len(a) / a[-1]["at"]
             assert 0.6 * t["arrivals"]["rate_rps"] < rate \
                 < 1.6 * t["arrivals"]["rate_rps"]
+            # the window holds exactly what the rate states, whatever
+            # the rate and the window: one draw's chance is stretched out
+            for r, secs in ((t["arrivals"]["rate_rps"], 30.0), (4.5, 30.0),
+                            (2.0, 10.0)):
+                mix = dict(t, arrivals=dict(t["arrivals"], rate_rps=r))
+                due = [x["at"] for x in request_plan(mix, 7, secs, 32768)]
+                assert sum(x < secs for x in due) == round(r * secs)
+                assert abs(due[round(r * secs)] - secs) < 1e-9
         else:
             assert ats[-1] == 0.0
 

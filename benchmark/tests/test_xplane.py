@@ -24,6 +24,19 @@ def test_interval_arithmetic():
     assert xplane.name_gap((4.0, 5.0), spans) == "bench:engine_step"
     assert xplane.name_gap((11.0, 12.0), spans) == "unannotated"
     assert xplane.op_name("%fusion.12 = bf16[8]{0} fusion(%a)") == "fusion.12"
+    # a gap is cut at every span boundary inside it; each piece goes to
+    # the innermost span it lies in (the program's pt: spans nest in
+    # the harness's bench: ones)
+    spans = [("bench:engine_step", 0.0, 10.0), ("pt:iteration", 0.5, 9.5),
+             ("pt:decode_dispatch", 1.0, 2.0), ("pt:logits_fetch", 2.0, 6.0),
+             ("bench:submit", 10.0, 11.0)]
+    named = xplane.idle_by_span([(0.0, 3.0), (5.0, 10.5), (20.0, 21.0)],
+                                spans)
+    assert named == pytest.approx({
+        "bench:engine_step": 0.5 + 0.5, "pt:iteration": 0.5 + 3.5,
+        "pt:decode_dispatch": 1.0, "pt:logits_fetch": 1.0 + 1.0,
+        "bench:submit": 0.5, "unannotated": 1.0})
+    assert xplane.idle_by_span([(0.0, 1.0)], []) == {"unannotated": 1.0}
     # a while holds its body's operations: its own time is the rest
     own = dict(xplane.self_seconds([("while.1", 0.0, 10.0),
                                     ("kernel", 1.0, 4.0),
@@ -48,11 +61,15 @@ def test_recorded_trace():
     assert s["window_s"] == pytest.approx(78.665254e-3 - 43.882011e-3,
                                           rel=1e-3)
     assert s["device_ops"][0][0] == "convolution_tanh_fusion"
-    assert len(s["device_ops"]) <= 10 and len(s["idle_gaps"]) <= 10
-    # the three gaps between programs fall under the host's sleep
-    assert s["idle_gaps"][0][0] == "bench:fetch"
-    assert s["idle_gaps"][0][1] == pytest.approx(
-        s["window_s"] - s["busy_s"], rel=0.02)
+    assert len(s["device_ops"]) <= 20 and len(s["idle_gaps"]) <= 20
+    # the three gaps between programs are cut at the host spans' ends:
+    # most of each falls under the host's sleep, the rest under the
+    # step that follows it and the instants between the two
+    assert [n for n, _ in s["idle_gaps"]] == ["bench:fetch", "bench:step",
+                                              "unannotated"]
+    assert s["idle_gaps"][0][1] == pytest.approx(30.501e-3, rel=1e-3)
+    assert sum(v for _, v in s["idle_gaps"]) == pytest.approx(
+        s["window_s"] - s["busy_s"], rel=1e-6)
 
 
 def test_trace_reducers():

@@ -47,7 +47,7 @@ def make_root(dst: str, limits=None) -> str:
         else:
             t.update(prompt_tokens=dict(t["prompt_tokens"], min=4, max=40),
                      output_tokens=dict(t["output_tokens"], min=4, max=12),
-                     plan_requests=256, check_requests=3)
+                     plan_requests=4096, check_requests=3)
             if t["arrivals"]["process"] == "poisson":
                 t["arrivals"]["rate_rps"] = 20.0
             else:
