@@ -171,6 +171,12 @@ def export_engine(engine, directory: str, *,
     the atomic ``latest`` pointer once complete (``keep_last`` prunes
     older generations) — loaders passing the root as ``aot_dir`` follow
     the pointer."""
+    if getattr(engine, "ssm_state", None) is not None:
+        raise NotImplementedError(
+            "AOT export is not supported for a model with per-slot "
+            "recurrent state: the manifest hashes neither the state's "
+            "geometry nor the layer pattern, so a warm start could load "
+            "programs for another model")
     breg = buckets or getattr(engine, "_buckets", None) or \
         ShapeBucketRegistry(DEFAULT_CHUNK_BUCKETS)
     if breg.max_batch is None:

@@ -167,12 +167,21 @@ def sched_ratios(s: Dict[str, object]) -> Dict[str, object]:
 
 
 class ContinuousBatchingEngine:
-    """Llama-family continuous-batching engine (greedy by default,
-    per-request sampling via temperature/top_k/top_p on add_request).
+    """Continuous-batching engine (greedy by default, per-request
+    sampling via temperature/top_k/top_p on add_request).  The kind of
+    model is read from ``cfg``: a Llama-family decoder (identical
+    blocks, K/V pages for every layer), or a hybrid of state-space and
+    attention layers (``cfg.layer_types``,
+    ``models/granite_hybrid.py``), for which the engine keeps K/V pages
+    for the attention layers only and, beside the page table, a
+    recurrent state and a conv tail per decode slot (below).
 
     Args:
-      cfg: LlamaConfig (dense or MoE — the FFN follows the config).
-      params: train-step param pytree (wte/head/lnf_w + stacked blocks).
+      cfg: LlamaConfig (dense or MoE — the FFN follows the config), or
+        a GraniteHybridConfig.
+      params: the family's param pytree (Llama: the train-step tree,
+        wte/head/lnf_w + stacked blocks; hybrid: wte/lnf_w + one
+        stacked tree a run of layers of one kind).
       max_batch: decode-batch slots (static jit shape).
       block_size / num_blocks: shared KV page pool geometry.
       max_blocks_per_seq: page-table width per slot (caps per-sequence
@@ -257,6 +266,20 @@ class ContinuousBatchingEngine:
     ``scheduler_stats()["kv_walk_share"]``), so a wide table costs a
     short request nothing per token; the chunk fill still gathers the
     row's whole width.
+
+    Models with per-slot state (``cfg.layer_types``): ``ssm_state``
+    ``[L_mamba, B, heads, head width, state]`` float32 and
+    ``conv_state`` ``[L_mamba, B, channels, width - 1]`` ride through
+    every program beside the pools, donated like them.  A slot's rows
+    start at zero inside its first chunk fill (``start == 0``), every
+    later chunk and decode step continues them, a bucket's padding
+    leaves them alone, retirement needs no device work (the next
+    admission overwrites).  For such a model the prefix cache is off (a
+    page hit cannot restore a state: ``prefix_stats()`` stays at zero),
+    a preempted slot's snapshot carries its state rows with its pages
+    (CRC-checked; a snapshot the bounded tier dropped is replayed from
+    the committed tokens), and ``spec_config`` / ``quant_config`` /
+    ``aot_dir`` raise ``NotImplementedError``.
     """
 
     def __init__(self, cfg, params, *, max_batch: int = 4,
@@ -268,6 +291,23 @@ class ContinuousBatchingEngine:
                  fused_prefill: bool = True, spec_config=None,
                  enable_preemption: bool = True, spill_tier=None,
                  prefix_cache_config=None, quant_config=None):
+        self._hybrid = getattr(cfg, "layer_types", None) is not None
+        if self._hybrid:
+            for what, given, why in (
+                    ("spec_config", spec_config,
+                     "speculative decoding would have to roll a slot's "
+                     "recurrent state back over rejected tokens"),
+                    ("quant_config", quant_config,
+                     "the PTQ export knows neither expert banks nor "
+                     "state-space projections, and the recurrent state "
+                     "has no quantised form"),
+                    ("aot_dir", aot_dir,
+                     "the AOT manifest does not hash the per-slot state's "
+                     "geometry")):
+                if given is not None:
+                    raise NotImplementedError(
+                        f"{what}= is not supported for a model with "
+                        f"per-slot recurrent state ({why})")
         if getattr(cfg, "moe_num_experts", 0) and \
                 getattr(cfg, "moe_router", "topk") != "topk":
             raise NotImplementedError("decode serves token-choice only")
@@ -300,7 +340,9 @@ class ContinuousBatchingEngine:
         self.BS = block_size
         self.MB = max_blocks_per_seq or \
             -(-cfg.max_position_embeddings // block_size)
-        L = cfg.num_layers
+        # K/V pages exist for the layers that attend: all of them, or a
+        # hybrid's attention layers only
+        L = cfg.num_attention_layers if self._hybrid else cfg.num_layers
         kvh, hd = cfg.kv_heads, cfg.head_dim
         dt = jnp.dtype(cfg.dtype)
         # pools are built from HOST zeros through the same pool-shaped
@@ -319,6 +361,18 @@ class ContinuousBatchingEngine:
         self.pool_v = zeros_kv_pool(
             (L, num_blocks, block_size, kvh, hd), dt,
             kv_quant=self._kv_quant)
+        # per-slot recurrent state beside the pages (hybrid models)
+        self.ssm_state = self.conv_state = None
+        if self._hybrid:
+            from ..models.granite_hybrid import init_slot_state
+            self.ssm_state, self.conv_state = init_slot_state(
+                cfg, max_batch)
+        #: what every compiled program is given after the params,
+        #: donated, and hands back first: the pools, and a hybrid's two
+        #: state arrays (``_carried`` / ``_keep``)
+        self._carry = ("pool_k", "pool_v") + (
+            ("ssm_state", "conv_state") if self._hybrid else ())
+        self._donate = tuple(range(1, 1 + len(self._carry)))
         self.block_table = np.full((max_batch, self.MB), -1, np.int32)
         self.lengths = np.zeros((max_batch,), np.int32)
         self.tokens = np.zeros((max_batch,), np.int32)
@@ -331,7 +385,11 @@ class ContinuousBatchingEngine:
         # bounded CRC-checked host-RAM offload tier that restores by
         # exact-byte scatter instead of recompute
         from ..serving.prefix_cache import PrefixCache
-        self.enable_prefix_caching = bool(enable_prefix_caching)
+        # a cached page cannot bring back the state that stood at its
+        # end, so a model with per-slot state registers and matches
+        # nothing
+        self.enable_prefix_caching = bool(enable_prefix_caching) \
+            and not self._hybrid
         self.prefix_cache = PrefixCache(block_size,
                                         config=prefix_cache_config)
         self.stats = {"prefix_blocks_reused": 0,
@@ -394,6 +452,13 @@ class ContinuousBatchingEngine:
         # and those of them that hold a live token of an active slot
         self.decode_pages_walked = 0
         self.decode_pages_live = 0
+        # expert layers that hold a share of the router's experts
+        # (hybrid models): per decode dispatch, token-expert pairs that
+        # landed on held experts and distinct held experts hit (summed
+        # over the layers on the device, fetched with the logits), out
+        # of live slots x k x layers and held experts x layers
+        self.moe = {"moe_assignments_local": 0, "moe_experts_hit": 0,
+                    "moe_assignments_total": 0, "moe_expert_slots": 0}
         # the engine timeline while the tracer is on, else None: set
         # once per step(), read by the phases underneath it
         self._tl = None
@@ -425,14 +490,14 @@ class ContinuousBatchingEngine:
             # pools are donated: the decode step rewrites them every
             # iteration and the old buffers must not stay live
             self._step = jax.jit(self._build_step(),
-                                 donate_argnums=(1, 2))
+                                 donate_argnums=self._donate)
         if spec_config is not None:
             from ..spec_decode import SpecDecodeRunner
             self._spec = SpecDecodeRunner(
                 self, spec_config,
                 draft_fn=_spec_programs.get("draft"),
                 verify_fn=_spec_programs.get("verify"))
-        self.last_logits: Optional[np.ndarray] = None   # [B, V] debug/test
+        self._last_logits = None                        # [B, V] debug/test
 
     # ------------------------------------------------------------------
     # compiled per-iteration decode over every slot
@@ -449,6 +514,9 @@ class ContinuousBatchingEngine:
 
     def _build_step(self):
         cfg = self.cfg
+        if self._hybrid:
+            from ..models.granite_hybrid import build_hybrid_step
+            return build_hybrid_step(cfg, self.BS)
         from ..models.llama import _rope_cos_sin
         from ..models.generation import _collapse_blocks
         from ..ops.decode_block import decode_block, decode_block_spec
@@ -505,6 +573,9 @@ class ContinuousBatchingEngine:
         the last row.  With ``valid == Ts`` the computation is
         identical to the unpadded call."""
         cfg = self.cfg
+        if self._hybrid:
+            from ..models.granite_hybrid import build_hybrid_chunk_fill
+            return build_hybrid_chunk_fill(cfg, self.BS, Ts)
         from ..models.llama import _rope_cos_sin
         from ..models.generation import _collapse_blocks
         from ..ops.decode_block import decode_block_spec, prefill_block
@@ -565,9 +636,46 @@ class ContinuousBatchingEngine:
         fn = self._chunk_fill_cache.get(Ts)
         if fn is None:
             fn = jax.jit(self._build_chunk_fill(Ts),
-                         donate_argnums=(1, 2))
+                         donate_argnums=self._donate)
             self._chunk_fill_cache.put(Ts, fn)
         return fn
+
+    @property
+    def last_logits(self) -> Optional[np.ndarray]:
+        """``[B, V]`` float32 logits of the last decode dispatch (None
+        when the last iteration decoded nothing), for tests and
+        debugging.  A hybrid's step leaves them on the device (it picks
+        the greedy tokens itself); they are fetched here, on demand."""
+        if self._last_logits is not None \
+                and not isinstance(self._last_logits, np.ndarray):
+            self._last_logits = np.asarray(self._last_logits)
+        return self._last_logits
+
+    @last_logits.setter
+    def last_logits(self, value) -> None:
+        self._last_logits = value
+
+    def _carried(self):
+        """The donated arguments of every compiled program, as they
+        stand."""
+        return tuple(getattr(self, name) for name in self._carry)
+
+    def _keep(self, out):
+        """Take back what a compiled program returned for
+        :meth:`_carried`; the rest of its results."""
+        for name, value in zip(self._carry, out):
+            setattr(self, name, value)
+        return out[len(self._carry):]
+
+    def _run_fill(self, fill, slot: int, bt_row, start, toks, *valid):
+        """Call a compiled chunk fill for ``slot`` (a hybrid's fill is
+        told the slot: its state rows are that slot's); returns the
+        logits."""
+        where = (jnp.int32(slot),) if self._hybrid else ()
+        (logits,) = self._keep(fill(
+            self.params, *self._carried(), bt_row, start, toks, *where,
+            *valid))
+        return logits
 
     def _bucket_fill(self, size: int):
         """Compiled bucketed fill for a DECLARED chunk size: AOT-loaded
@@ -577,7 +685,7 @@ class ContinuousBatchingEngine:
         fn = self._bucket_fills.get(size)
         if fn is None:
             fn = jax.jit(self._build_chunk_fill(size),
-                         donate_argnums=(1, 2))
+                         donate_argnums=self._donate)
             self._bucket_fills[size] = fn
         return fn
 
@@ -597,9 +705,8 @@ class ContinuousBatchingEngine:
             toks = np.zeros((size,), np.int32)
             toks[:valid] = suffix[off:off + valid]
             fill = self._bucket_fill(size)
-            self.pool_k, self.pool_v, logits = fill(
-                self.params, self.pool_k, self.pool_v, bt_row,
-                jnp.int32(pos), jnp.asarray(toks), jnp.int32(valid))
+            logits = self._run_fill(fill, slot, bt_row, jnp.int32(pos),
+                                    jnp.asarray(toks), jnp.int32(valid))
             self.prefill_chunks += 1
             self.prefill_tokens_dispatched += size
             if tl:
@@ -1059,6 +1166,14 @@ class ContinuousBatchingEngine:
         if (getattr(snap, "k_scale", None) is not None) != \
                 self._kv_quant:
             return False
+        state = getattr(snap, "ssm_state", None)
+        if (state is not None) != self._hybrid or (
+                self._hybrid and any(
+                    row.shape != full.shape[:1] + full.shape[2:]
+                    for row, full in ((state, self.ssm_state),
+                                      (snap.conv_state,
+                                       self.conv_state)))):
+            return False
         ref = self.pool_k.data if self._kv_quant else self.pool_k
         return (snap.k_pages.shape[0] == ref.shape[0]
                 and snap.k_pages.shape[2:] == ref.shape[2:]
@@ -1219,8 +1334,10 @@ class ContinuousBatchingEngine:
             # declared-bucket prefill (cold prompts AND cache-hit
             # suffixes): fixed chunk programs, no per-length jit
             return self._fill_prompt_bucketed(slot, req, L * self.BS)
-        if L or self.quant_config is not None:
-            # suffix-only prefill against the cached pages.  Quantized
+        if L or self.quant_config is not None or self._hybrid:
+            # suffix-only prefill against the cached pages (a hybrid's
+            # whole prompt, start=0: the chunk fill is its one prefill
+            # program, the state's hand-over lives there).  Quantized
             # engines route COLD prompts here too (start=0): the dense
             # tier below computes full-width KV and scatters it into
             # the pool raw, which would skip both the quantized matmul
@@ -1231,9 +1348,8 @@ class ContinuousBatchingEngine:
             sp = tl and tl.enter("prefill_chunk", size=len(suffix),
                                 valid=len(suffix))
             fill = self._chunk_fill(len(suffix))
-            self.pool_k, self.pool_v, logits = fill(
-                self.params, self.pool_k, self.pool_v,
-                jnp.asarray(self.block_table[slot]),
+            logits = self._run_fill(
+                fill, slot, jnp.asarray(self.block_table[slot]),
                 jnp.int32(L * self.BS), jnp.asarray(suffix))
             self.prefill_chunks += 1
             self.prefill_tokens_dispatched += len(suffix)
@@ -1531,16 +1647,34 @@ class ContinuousBatchingEngine:
             np.sum(-(-seen[active] // self.BS)))
         m0 = time.monotonic() if tl else 0.0
         sp = tl and tl.enter("decode_dispatch", batch=len(active))
-        self.pool_k, self.pool_v, logits = self._step(
-            self.params, self.pool_k, self.pool_v,
-            jnp.asarray(self.block_table), jnp.asarray(self.lengths),
-            jnp.asarray(self.tokens))
+        # a hybrid's step also returns its expert layers' two counts
+        # and every row's first choice
+        logits, *extra = self._keep(self._step(
+            self.params, *self._carried(), jnp.asarray(self.block_table),
+            jnp.asarray(self.lengths), jnp.asarray(self.tokens)))
         if tl:
             tl.leave(sp)
         sp = tl and tl.enter("logits_fetch")     # waits for the device
-        self.last_logits = np.asarray(logits)
+        greedy = None
+        if extra:
+            # two small arrays come to the host; the logits ([B, V]
+            # float32: 25.7 MB a step at 64 slots and a 100k vocabulary)
+            # stay on the device unless a row samples or a test looks
+            counts, greedy = (np.asarray(a) for a in extra)
+            self.last_logits = logits
+            fetched = counts.nbytes + greedy.nbytes
+            local, hit = (int(c) for c in counts)
+            cfg, m = self.cfg, self.moe
+            m["moe_assignments_local"] += local
+            m["moe_experts_hit"] += hit
+            m["moe_assignments_total"] += \
+                len(active) * cfg.num_experts_per_tok * cfg.num_layers
+            m["moe_expert_slots"] += cfg.experts_held * cfg.num_layers
+        else:
+            self.last_logits = np.asarray(logits)
+            fetched = self.last_logits.nbytes
         if tl:
-            tl.leave(sp, bytes=self.last_logits.nbytes)
+            tl.leave(sp, bytes=fetched)
         sp = tl and tl.enter("pick")
         for s in active:
             self.lengths[s] += 1            # the fed token's KV is stored
@@ -1558,7 +1692,8 @@ class ContinuousBatchingEngine:
             req = self.slots[s]
             tok = picks.get(s)
             if tok is None:
-                tok = int(self.last_logits[s].argmax())
+                tok = int(self.last_logits[s].argmax() if greedy is None
+                          else greedy[s])
             self._append_tok(req, int(tok))
             self.tokens[s] = int(tok)
         if tl:
@@ -1627,7 +1762,7 @@ class ContinuousBatchingEngine:
         leaked = sum(1 for p, r in self.alloc.ref.items()
                      if held.get(p, 0) != r)
         leaked += sum(1 for p in held if p not in self.alloc.ref)
-        return {
+        report = {
             "free_blocks": self.alloc.free_blocks,
             "index_blocks": len(self.prefix_index),
             "slot_blocks": sum(len(p) for p in self.slot_pages),
@@ -1635,6 +1770,12 @@ class ContinuousBatchingEngine:
             "unaccounted": (self.alloc.num_blocks - self.alloc.free_blocks
                             - len(self.alloc.ref)),
         }
+        if self._hybrid:
+            # a slot's state rows belong to the request in the slot and
+            # to nothing else: rows in use = slots running (a drained
+            # engine reads 0; a row cannot outlive its slot)
+            report["state_rows"] = self.active_requests
+        return report
 
     @property
     def spilled_bytes(self) -> int:
@@ -1690,6 +1831,8 @@ class ContinuousBatchingEngine:
                 self.stats["prefill_tokens_computed"],
             "stalled_slot_iterations": self.stalled_slot_iterations,
             "decode_slot_steps": self.decode_slot_steps}
+        if self._hybrid:
+            s.update(self.moe)
         return sched_ratios(s)
 
     def spec_stats(self) -> Optional[Dict[str, object]]:
@@ -1718,6 +1861,18 @@ class ContinuousBatchingEngine:
         from ..ops.decode_block import (decode_block_spec,
                                         decode_block_tier,
                                         prefill_block_tier)
+        if self._hybrid:
+            from ..ops.ssm import ssm_state_update_tier
+            row = {"tier": "xla", "reason":
+                   "state-space and expert layers have the reference "
+                   "tier only"}
+            sizes = self._buckets.chunk_sizes \
+                if self._buckets is not None else ()
+            tier, why = ssm_state_update_tier(
+                self.ssm_state.shape, self.cfg.mamba_n_groups)
+            return {"decode_block": dict(row),
+                    "ssm_state_update": {"tier": tier, "reason": why},
+                    **{f"prefill_block[{c}]": dict(row) for c in sizes}}
 
         def per_layer(tree, lead):
             return jax.tree.map(

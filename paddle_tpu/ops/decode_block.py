@@ -51,12 +51,13 @@ from .paged_kv import (QuantizedKVPool, dequantize_kv, is_quantized_pool,
                        validate_paged_decode_geometry)
 
 __all__ = ["DecodeBlockSpec", "DecodeBlockUnsupportedError",
-           "PrefillBlockUnsupportedError", "decode_block",
-           "decode_block_spec", "decode_block_tier",
+           "PrefillBlockUnsupportedError", "decode_attention_xla",
+           "decode_block", "decode_block_spec", "decode_block_tier",
            "decode_block_unsupported_reason",
            "hbm_traffic_per_chunk", "hbm_traffic_per_token", "make_norm",
            "make_ffn", "make_mm",
-           "make_norm_ffn", "prefill_block", "prefill_block_xla",
+           "make_norm_ffn", "prefill_attention_xla", "prefill_block",
+           "prefill_block_xla",
            "prefill_block_tier", "prefill_block_unsupported_reason",
            "rotate_half"]
 
@@ -95,6 +96,12 @@ class DecodeBlockSpec:
     # export layout.  Norm gains and biases stay full width.
     weight_dtype: Optional[str] = None   # None | "int8" | "int4"
     group_size: int = -1                 # -1 | 64 | 128 (scale grouping)
+    # a stated softmax scale (None: 1/sqrt(head_dim)) and a multiplier
+    # on what the attention adds to the residual stream — the granite
+    # family's ``attention_multiplier`` / ``residual_multiplier``;
+    # reference tier only
+    attn_scale: Optional[float] = None
+    residual_scale: float = 1.0
 
     def __post_init__(self):
         if self.norm not in ("rms", "ln"):
@@ -282,10 +289,34 @@ def decode_block_xla(x, lp, pool_k, pool_v, block_table, lengths, cos, sin,
     off); returns ``(x_out, pool_k, pool_v)`` with the new token's KV
     appended.  This is byte-for-byte the composition the engine's
     ``_build_step`` inlined before ISSUE 9 — the bit-identity anchor."""
+    norm = make_norm(spec)
+    ffn = ffn or make_ffn(spec)
+    x, pool_k, pool_v = decode_attention_xla(
+        x, lp, pool_k, pool_v, block_table, lengths, cos, sin, spec=spec)
+    x = x + ffn(lp, norm(x, lp["ln2_w"], lp.get("ln2_b")))
+    return x, pool_k, pool_v
+
+
+def _residual(x, proj, lp, spec: DecodeBlockSpec):
+    """The attention's term added to the stream (the multiplier only
+    where a spec states one, so the other families' arithmetic is the
+    same operation for operation)."""
+    proj = proj + lp["proj_b"] if spec.bias else proj
+    if spec.residual_scale != 1.0:
+        proj = proj * jnp.asarray(spec.residual_scale, proj.dtype)
+    return x + proj
+
+
+def decode_attention_xla(x, lp, pool_k, pool_v, block_table, lengths, cos,
+                         sin, *, spec: DecodeBlockSpec):
+    """The attention half of :func:`decode_block_xla`: norm, q/k/v,
+    RoPE (``cos``/``sin`` may be None when ``spec.rope`` is off), paged
+    append, paged decode attention, out-projection, residual.  A model
+    whose FFN half is its own (an expert layer that reports counts)
+    calls this and adds the rest itself."""
     B = x.shape[0]
     norm = make_norm(spec)
     mm = make_mm(spec)
-    ffn = ffn or make_ffn(spec)
     y = norm(x, lp["ln1_w"], lp.get("ln1_b"))
     q, k, v = _qkv(y, lp, spec, (B,), mm)
     if spec.rope:
@@ -295,11 +326,9 @@ def decode_block_xla(x, lp, pool_k, pool_v, block_table, lengths, cos, sin,
     pool_k, pool_v = paged_append(pool_k, pool_v, k, v, block_table,
                                   lengths, spec.block_size)
     attn = paged_decode_attention(q, pool_k, pool_v, block_table,
-                                  lengths + 1)
+                                  lengths + 1, scale=spec.attn_scale)
     proj = _proj(attn.reshape(B, -1), lp, spec, mm)
-    x = x + (proj + lp["proj_b"] if spec.bias else proj)
-    x = x + ffn(lp, norm(x, lp["ln2_w"], lp.get("ln2_b")))
-    return x, pool_k, pool_v
+    return _residual(x, proj, lp, spec), pool_k, pool_v
 
 
 def prefill_block_xla(x, lp, pool_k, pool_v, blk, off, bt_row, mask, cos,
@@ -312,12 +341,28 @@ def prefill_block_xla(x, lp, pool_k, pool_v, blk, off, bt_row, mask, cos,
     single-token append.  Shares every numeric closure with the decode
     step so the two compiled paths cannot drift (the pre-ISSUE 9
     contract of ``_make_rms_ffn``, now op-level)."""
+    norm = make_norm(spec)
+    ffn = ffn or make_ffn(spec)
+    x, pool_k, pool_v = prefill_attention_xla(
+        x, lp, pool_k, pool_v, blk, off, bt_row, mask, cos, sin,
+        spec=spec, scale=scale)
+    x = x + ffn(lp, norm(x, lp["ln2_w"], lp.get("ln2_b")))
+    return x, pool_k, pool_v
+
+
+def prefill_attention_xla(x, lp, pool_k, pool_v, blk, off, bt_row, mask,
+                          cos, sin, *, spec: DecodeBlockSpec,
+                          scale: Optional[float] = None):
+    """The attention half of :func:`prefill_block_xla` (the twin of
+    :func:`decode_attention_xla`); ``scale`` defaults to the spec's
+    ``attn_scale``, then to ``1/sqrt(head_dim)``."""
     from ..models.generation import _dense_masked_attention
     Ts = x.shape[1]
     H, Hkv, D = spec.num_heads, spec.kv_heads, spec.head_dim
     norm = make_norm(spec)
     mm = make_mm(spec)
-    ffn = ffn or make_ffn(spec)
+    if scale is None:
+        scale = spec.attn_scale
     s = scale if scale is not None else 1.0 / (D ** 0.5)
     y = norm(x, lp["ln1_w"], lp.get("ln1_b"))
     q, k, v = _qkv(y, lp, spec, (1, Ts), mm)
@@ -350,9 +395,7 @@ def prefill_block_xla(x, lp, pool_k, pool_v, blk, off, bt_row, mask, cos,
     attn = _dense_masked_attention(q, k_all, v_all, mask,
                                    s).reshape(1, Ts, -1)
     proj = _proj(attn, lp, spec, mm)
-    x = x + (proj + lp["proj_b"] if spec.bias else proj)
-    x = x + ffn(lp, norm(x, lp["ln2_w"], lp.get("ln2_b")))
-    return x, pool_k, pool_v
+    return _residual(x, proj, lp, spec), pool_k, pool_v
 
 
 # ---------------------------------------------------------------------------
@@ -457,10 +500,17 @@ _NOT_ON_TPU = ("not on a TPU (and neither pallas_interpret nor "
 _CUSTOM_FFN = "custom FFN closures (MoE) run the reference tier only"
 
 
-def _auto_tier(ffn, kernel_reason: Optional[str]):
+_STATED_SCALES = ("a stated softmax scale or residual multiplier runs "
+                  "the reference tier only")
+
+
+def _auto_tier(ffn, kernel_reason: Optional[str], spec=None):
     """``(tier, reason)`` of a ``backend=None`` dispatch, given the
     kernel's own unsupported reason (None = it can run this layer)."""
     reason = _CUSTOM_FFN if ffn is not None else kernel_reason
+    if reason is None and spec is not None and (
+            spec.attn_scale is not None or spec.residual_scale != 1.0):
+        reason = _STATED_SCALES
     if reason is None and not _pallas_platform():
         reason = _NOT_ON_TPU
     return ("pallas" if reason is None else "xla"), reason
@@ -471,7 +521,7 @@ def decode_block_tier(spec: DecodeBlockSpec, lp, pool_k, ffn=None):
     this layer on, and — when that is ``"xla"`` — why the Pallas
     megakernel stood down."""
     return _auto_tier(ffn, decode_block_unsupported_reason(spec, lp,
-                                                           pool_k))
+                                                           pool_k), spec)
 
 
 def decode_block(x, lp, pool_k, pool_v, block_table, lengths, cos, sin, *,
@@ -513,8 +563,9 @@ def decode_block(x, lp, pool_k, pool_v, block_table, lengths, cos, sin, *,
         if ffn is not None:
             raise DecodeBlockUnsupportedError(
                 f"decode_block: {_CUSTOM_FFN}")
-        reason = decode_block_unsupported_reason(spec, lp, pool_k)
-        if reason is not None:
+        reason = _auto_tier(None, decode_block_unsupported_reason(
+            spec, lp, pool_k), spec)[1]
+        if reason not in (None, _NOT_ON_TPU):
             raise DecodeBlockUnsupportedError(f"decode_block: {reason}")
         from .pallas.decode_block import decode_block_pallas
         return decode_block_pallas(x, lp, pool_k, pool_v, block_table,
@@ -543,7 +594,7 @@ def prefill_block_tier(spec: DecodeBlockSpec, lp, pool_k, chunk: int,
     with ``start=`` at this chunk length — the twin of
     :func:`decode_block_tier`."""
     return _auto_tier(ffn, prefill_block_unsupported_reason(
-        spec, lp, pool_k, chunk))
+        spec, lp, pool_k, chunk), spec)
 
 
 def prefill_block(x, lp, pool_k, pool_v, blk, off, bt_row, mask, cos,
@@ -582,9 +633,9 @@ def prefill_block(x, lp, pool_k, pool_v, blk, off, bt_row, mask, cos,
             raise PrefillBlockUnsupportedError(
                 "prefill_block: the Pallas tier needs the committed-"
                 "prefix length (start=)")
-        reason = prefill_block_unsupported_reason(spec, lp, pool_k,
-                                                  x.shape[1])
-        if reason is not None:
+        reason = _auto_tier(None, prefill_block_unsupported_reason(
+            spec, lp, pool_k, x.shape[1]), spec)[1]
+        if reason not in (None, _NOT_ON_TPU):
             raise PrefillBlockUnsupportedError(f"prefill_block: {reason}")
         from .pallas.prefill_block import prefill_block_pallas
         return prefill_block_pallas(x, lp, pool_k, pool_v, blk, off,

@@ -45,7 +45,7 @@ __all__ = ["inject_aux_grad", "topk_scatter_routing", "moe_ffn_ep",
            "moe_swiglu_ffn_ep", "moe_dispatch_combine", "compute_capacity",
            "schedule_aux_coef", "expert_choice_routing",
            "moe_expert_choice_ffn", "moe_swiglu_ffn_grouped",
-           "moe_gelu_ffn_grouped"]
+           "moe_swiglu_ffn_masked", "route_held", "moe_gelu_ffn_grouped"]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -209,6 +209,91 @@ def moe_expert_choice_ffn(x: jax.Array, gate_w: jax.Array,
         (w[..., None].astype(jnp.float32)
          * out.astype(jnp.float32)).reshape(E * C, h))
     return res.astype(x.dtype).reshape(shape)
+
+
+def route_held(logits: jax.Array, top_k: int, n_held: int, *,
+               normalize: bool = True, gate: str = "softmax_topk",
+               expert_offset: int = 0):
+    """Token-choice routing over ALL experts of the router for a rank
+    that holds experts ``[expert_offset, expert_offset + n_held)``.
+
+    ``logits [T, E]`` float32.  ``gate="softmax_topk"``: softmax over all
+    experts, the ``top_k`` largest, renormalised when ``normalize`` (the
+    Mixtral gate); ``"topk_softmax"``: the ``top_k`` largest logits,
+    softmax over those (the granite gate).  Either way a token's gates
+    are those of its full choice, UNCHANGED by what this rank holds.
+    Returns ``(w [T, k], local [T, k], held [T, k])``: the gates, each
+    choice's index into the held bank (``n_held`` where it is not held)
+    and whether it is held."""
+    if gate == "softmax_topk":
+        w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        if normalize and top_k > 1:
+            w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+    elif gate == "topk_softmax":
+        lg, idx = lax.top_k(logits, top_k)
+        w = jax.nn.softmax(lg, axis=-1)
+    else:
+        raise ValueError(f"unknown gate {gate!r}")
+    local = idx - expert_offset
+    held = (local >= 0) & (local < n_held)
+    return w, jnp.where(held, local, n_held), held
+
+
+def _held_counts(held, local, n_held: int, mask=None):
+    """``[assignments on held experts, distinct held experts hit]`` as
+    int32: the two sums a serving step reports beside its logits, over
+    the tokens of ``mask [T]`` (all of them when None)."""
+    if mask is not None:
+        held = held & mask[:, None]
+        local = jnp.where(held, local, n_held)
+    hit = jnp.zeros((n_held + 1,), jnp.int32).at[local.reshape(-1)].max(
+        1)[:n_held]
+    return jnp.stack([jnp.sum(held, dtype=jnp.int32),
+                      jnp.sum(hit, dtype=jnp.int32)])
+
+
+def moe_swiglu_ffn_masked(x: jax.Array, router_w: jax.Array,
+                          wg: jax.Array, wu: jax.Array, wd: jax.Array, *,
+                          top_k: int = 2, normalize: bool = True,
+                          gate: str = "softmax_topk",
+                          expert_offset: int = 0,
+                          with_counts: bool = False, count_mask=None):
+    """Exact SwiGLU MoE for a rank that holds a SHARE of the router's
+    experts, ``wg/wu/wd [n_held, ...]`` from ``expert_offset`` on: every
+    token is routed over all of ``router_w``'s experts
+    (:func:`route_held`), runs through every HELD expert as one batched
+    matmul, and the gates (zero where an expert was not chosen or is not
+    held) weigh the sum — the partial sum that expert parallelism's
+    ranks add up, the gates unchanged by what a rank holds.  No sort, no
+    gather, no copy of the bank: ``E / top_k`` times the FLOPs a token's
+    own choices require, the same weight bytes.
+
+    Why not the sorted form (:func:`moe_swiglu_ffn_grouped`) for a
+    share: on a TPU v5e, 36 of 72 experts of 4096 x 768 held, top 10,
+    this form serves twice the tokens of ``ragged_dot`` in the 128- and
+    512-token chunk fills and in the 64-row decode step alike (PERF.md
+    §6, PR 30: each grouped matmul first copies the layer's bank out of
+    the stacked weights).  ``with_counts`` also returns the int32 pair
+    ``[assignments on held experts, distinct held experts hit]`` over
+    the tokens of ``count_mask``."""
+    shape = x.shape
+    tokens = x.reshape(-1, shape[-1])
+    E = wg.shape[0]
+    logits = tokens.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    w, local, held = route_held(logits, top_k, E, normalize=normalize,
+                                gate=gate, expert_offset=expert_offset)
+    # [T, E] gates: a token's gate for each held expert, else 0
+    dense = jnp.zeros((tokens.shape[0], E + 1), jnp.float32).at[
+        jnp.arange(tokens.shape[0])[:, None], local].add(w)[:, :E]
+    g = jnp.einsum("th,ehf->etf", tokens, wg)
+    u = jnp.einsum("th,ehf->etf", tokens, wu)
+    act = (jax.nn.silu(g) * u) * dense.T[..., None].astype(g.dtype)
+    res = jnp.einsum("etf,efh->th", act, wd,
+                     preferred_element_type=jnp.float32)
+    res = res.astype(x.dtype).reshape(shape)
+    if with_counts:
+        return res, _held_counts(held, local, E, count_mask)
+    return res
 
 
 def moe_swiglu_ffn_grouped(x: jax.Array, router_w: jax.Array,

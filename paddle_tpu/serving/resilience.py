@@ -50,6 +50,7 @@ engine faults, not for the process being killed.
 from __future__ import annotations
 
 import collections
+import functools
 import time
 import zlib
 from dataclasses import dataclass
@@ -122,13 +123,23 @@ class KVSnapshot:
     # over codes THEN scales, so bit-rot in either is caught
     k_scale: Optional[np.ndarray] = None   # [L, used_pages, BS, Hkv]
     v_scale: Optional[np.ndarray] = None
+    # a model with per-slot recurrent state (hybrid of state-space and
+    # attention layers): the slot's rows of the engine's two state
+    # arrays, CRC-stamped like the pages — the pages alone could not
+    # resume such a sequence
+    ssm_state: Optional[np.ndarray] = None   # [L_mamba, heads, P, N] f32
+    conv_state: Optional[np.ndarray] = None  # [L_mamba, channels, W-1]
     crc_k: int = 0
     crc_v: int = 0
+    crc_state: int = 0
 
     def __post_init__(self):
         if not self.crc_k and not self.crc_v:
             self.crc_k = self._crc(self.k_pages, self.k_scale)
             self.crc_v = self._crc(self.v_pages, self.v_scale)
+            if self.ssm_state is not None:
+                self.crc_state = self._crc(self.ssm_state,
+                                           self.conv_state)
 
     @staticmethod
     def _crc(pages: np.ndarray, scale: Optional[np.ndarray]) -> int:
@@ -142,6 +153,8 @@ class KVSnapshot:
         n = self.k_pages.nbytes + self.v_pages.nbytes
         if self.k_scale is not None:
             n += self.k_scale.nbytes + self.v_scale.nbytes
+        if self.ssm_state is not None:
+            n += self.ssm_state.nbytes + self.conv_state.nbytes
         return n
 
     def verify(self) -> None:
@@ -149,7 +162,10 @@ class KVSnapshot:
         match their spill-time checksums (framework/io.py convention:
         every array member carries a CRC32, verified on read)."""
         if self._crc(self.k_pages, self.k_scale) != self.crc_k or \
-                self._crc(self.v_pages, self.v_scale) != self.crc_v:
+                self._crc(self.v_pages, self.v_scale) != self.crc_v or (
+                    self.ssm_state is not None and self._crc(
+                        self.ssm_state, self.conv_state)
+                    != self.crc_state):
             raise SpillCorruptError(
                 f"spilled KV snapshot for request {self.req_id} failed "
                 "its CRC check — host-RAM bit-rot or a write raced the "
@@ -186,10 +202,31 @@ def snapshot_slot(engine, slot: int) -> KVSnapshot:
     else:
         k = np.asarray(engine.pool_k)[:, idx].copy()
         v = np.asarray(engine.pool_v)[:, idx].copy()
+    ssm = conv = None
+    if getattr(engine, "ssm_state", None) is not None:
+        ssm, conv = (np.asarray(a) for a in _state_row_programs()[0](
+            engine.ssm_state, engine.conv_state, np.int32(slot)))
     return KVSnapshot(req_id=req.req_id, length=length,
                       next_token=int(engine.tokens[slot]),
                       num_blocks=len(pages), k_pages=k, v_pages=v,
-                      k_scale=ks, v_scale=vs)
+                      k_scale=ks, v_scale=vs, ssm_state=ssm,
+                      conv_state=conv)
+
+
+@functools.lru_cache(maxsize=1)
+def _state_row_programs():
+    """``(read, write)``: a slot's rows of the two per-slot state arrays
+    out of / into them, each ONE compiled program whatever the slot (the
+    index is an argument)."""
+    import jax
+    take = jax.lax.dynamic_index_in_dim
+    put = jax.lax.dynamic_update_index_in_dim
+    read = jax.jit(lambda s, c, i: (take(s, i, 1, keepdims=False),
+                                    take(c, i, 1, keepdims=False)))
+    write = jax.jit(lambda s, c, sr, cr, i: (put(s, sr, i, 1),
+                                             put(c, cr, i, 1)),
+                    donate_argnums=(0, 1))
+    return read, write
 
 
 def restore_into_slot(engine, slot: int, snap: KVSnapshot) -> None:
@@ -211,6 +248,18 @@ def restore_into_slot(engine, slot: int, snap: KVSnapshot) -> None:
             "quantization scales but the engine's pool "
             f"{'is' if quant else 'is not'} quantized — the snapshot "
             "cannot scatter; replay from the committed token prefix")
+    has_state = getattr(engine, "ssm_state", None) is not None
+    if (snap.ssm_state is not None) != has_state:
+        raise SpillCorruptError(
+            f"KV snapshot for request {snap.req_id} "
+            f"{'carries' if snap.ssm_state is not None else 'lacks'} a "
+            "recurrent state but the engine's model "
+            f"{'keeps' if has_state else 'does not keep'} one — replay "
+            "from the committed token prefix")
+    if has_state:
+        engine.ssm_state, engine.conv_state = _state_row_programs()[1](
+            engine.ssm_state, engine.conv_state, snap.ssm_state,
+            snap.conv_state, np.int32(slot))
     used = snap.k_pages.shape[1]
     pages = np.asarray(engine.slot_pages[slot][:used], np.int64)
     # jnp.array (owned copy), NOT jax.device_put/jnp.asarray: both can

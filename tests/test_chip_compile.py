@@ -254,6 +254,19 @@ def test_decode_attention(chip):
         chip((8, 32, 128)), cache, cache, chip((8,), jnp.int32))
 
 
+def test_ssm_state_update(chip):
+    """The hybrid's decode-step state update at the published Mamba-2
+    widths (128 heads x 64, state 128), 64 slots, 9 layers: in place."""
+    from paddle_tpu.ops.pallas.ssm import ssm_state_update_rows
+    f32 = jnp.float32
+    compiled = compile_kernel(
+        ssm_state_update_rows, chip((64, 128, 64)), chip((64, 128), f32),
+        chip((128,), f32), chip((64, 1, 128)), chip((64, 1, 128)),
+        chip((128,), f32), chip((9, 64, 128, 64, 128), f32),
+        chip((), jnp.int32))
+    assert "ssm_state_update" in compiled.as_text()
+
+
 def test_quant_linear_int8(chip):
     from paddle_tpu.ops.pallas.quant_linear import weight_only_matmul
     compile_kernel(weight_only_matmul, chip((8, 4096)),
