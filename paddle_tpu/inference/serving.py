@@ -32,13 +32,40 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.decode_block import make_norm_ffn as _make_rms_ffn  # noqa: F401
-from ..ops.paged_kv import decode_walk
+from ..ops.paged_kv import (decode_walk, layer_pages, layers_as_one_pool,
+                            pool_geometry)
 #   ^ the norm/FFN closure pair moved to ops/decode_block.py (ISSUE 9)
 #     so the decode step, the chunk fill, and the spec-decode draft all
 #     read one definition; the old name stays importable for callers.
 
 __all__ = ["ContinuousBatchingEngine", "GenRequest", "build_sampler",
            "derive_sample_seed"]
+
+
+def _scan_layers(layer, x, blocks, pool_k, pool_v):
+    """``lax.scan`` of ``layer(x, lp, pool_k, pool_v, i) -> (x, pool_k,
+    pool_v)`` over the stacked ``blocks`` with the pools WHOLE in the
+    carry, all layers as one pool (``layers_as_one_pool``): layer ``i``
+    reaches its own pages through ``layer_pages`` and its append lands
+    in place.  Scanned as inputs and outputs instead, the compiler
+    copies both stacks whole on every call (the donated input is still
+    being read while the stacked output is written) and moves each
+    layer's ``[NB, ...]`` out of the stack and back: 4.3 GB a call at
+    16 layers of 32 MiB pools, for 32 appended rows
+    (``tests/test_chip_compile.py`` holds the compiled programs to
+    it).  Returns ``(x, pool_k, pool_v)``, the pools stacked as they
+    came."""
+    def body(carry, inp):
+        x, pk, pv = carry
+        lp, i = inp
+        return layer(x, lp, pk, pv, i), None
+
+    n_layers = jax.tree.leaves(pool_k)[0].shape[0]
+    (x, pk, pv), _ = jax.lax.scan(
+        body, (x, layers_as_one_pool(pool_k), layers_as_one_pool(pool_v)),
+        (blocks, jnp.arange(n_layers)))
+    return (x, layers_as_one_pool(pk, like=pool_k),
+            layers_as_one_pool(pv, like=pool_v))
 
 
 def derive_sample_seed(seed: int, sample_idx: int) -> int:
@@ -267,6 +294,16 @@ class ContinuousBatchingEngine:
     short request nothing per token; the chunk fill still gathers the
     row's whole width.
 
+    What the compiled programs move (docs/serving.md, "The layer
+    scan"): ``pool_k`` / ``pool_v`` are ``[L, NB, BS, Hkv, D]`` out
+    here and ride through the step's and the fills' layer scan WHOLE,
+    in its carry, as one pool of ``L*NB`` pages (``_scan_layers``);
+    ``self.params["blocks"]`` holds q, k and v ``[N, K]`` as
+    ``q_wt`` / ``k_wt`` / ``v_wt`` (``ops.decode_block.
+    serving_layout``, made once in the constructor; the caller's tree
+    keeps its layout, and a tree that is already laid out is taken as
+    it is).
+
     Models with per-slot state (``cfg.layer_types``): ``ssm_state``
     ``[L_mamba, B, heads, head width, state]`` float32 and
     ``conv_state`` ``[L_mamba, B, channels, width - 1]`` ride through
@@ -361,6 +398,18 @@ class ContinuousBatchingEngine:
         self.pool_v = zeros_kv_pool(
             (L, num_blocks, block_size, kvh, hd), dt,
             kv_quant=self._kv_quant)
+        if not self._hybrid:
+            # q, k and v laid out ONCE as the compiled programs read
+            # them (a tree that already is comes back as it is); the
+            # caller's tree, which training and checkpoints share, is
+            # not touched.  After the pools stand: their host-side
+            # staging copies are gone by then, and the three leaves
+            # that exist twice until the caller lets go of its tree set
+            # no higher peak than building the pools did
+            from ..ops.decode_block import serving_layout
+            jax.block_until_ready((self.pool_k, self.pool_v))
+            self.params = dict(params,
+                               blocks=serving_layout(params["blocks"]))
         # per-slot recurrent state beside the pages (hybrid models)
         self.ssm_state = self.conv_state = None
         if self._hybrid:
@@ -541,16 +590,14 @@ class ContinuousBatchingEngine:
             cos = jnp.take(cos_full, lengths, axis=0)         # [B, D]
             sin = jnp.take(sin_full, lengths, axis=0)
 
-            def body(carry, inp):
-                x = carry
-                lp, pk, pv = inp
-                x, pk, pv = decode_block(
-                    x, lp, pk, pv, bt, lengths, cos, sin, spec=spec,
-                    ffn=ffn_override, backend=backend)
-                return x, (pk, pv)
+            NB = pool_geometry(pool_k)[0]
 
-            x, (pk2, pv2) = jax.lax.scan(body, x,
-                                         (blocks, pool_k, pool_v))
+            def layer(x, lp, pk, pv, i):
+                return decode_block(
+                    x, lp, pk, pv, layer_pages(bt, i, NB), lengths, cos,
+                    sin, spec=spec, ffn=ffn_override, backend=backend)
+
+            x, pk2, pv2 = _scan_layers(layer, x, blocks, pool_k, pool_v)
             xf = rms(x, params["lnf_w"])
             logits = jnp.einsum("bh,hv->bv", xf, params["head"],
                                 preferred_element_type=jnp.float32)
@@ -602,27 +649,27 @@ class ContinuousBatchingEngine:
             cos = jnp.take(cos_full, pos, axis=0)
             sin = jnp.take(sin_full, pos, axis=0)
             blk = jnp.take(jnp.maximum(bt_row, 0), pos // BS)
-            if valid is not None:
-                # bucketed call: padded rows scatter out of range (the
-                # update is dropped) so stale pool pages stay intact
-                from ..ops.paged_kv import pool_geometry
-                blk = jnp.where(jnp.arange(Ts) < valid, blk,
-                                pool_geometry(pool_k)[0])
             off = pos % BS
             jpos = jnp.arange(bt_row.shape[0] * BS)[None, None, None, :]
             mask = jpos <= pos[None, None, :, None]
+            NB = pool_geometry(pool_k)[0]
 
-            def body(carry, inp):
-                x = carry
-                lp, pk, pv = inp
-                x, pk, pv = prefill_block(
-                    x, lp, pk, pv, blk, off, bt_row, mask, cos, sin,
-                    spec=spec, start=start, ffn=ffn_override,
-                    scale=scale, backend=backend)
-                return x, (pk, pv)
+            def layer(x, lp, pk, pv, i):
+                blk_i = blk + i * NB
+                if valid is not None:
+                    # bucketed call: padded rows scatter out of range
+                    # of ALL layers' pages (``pk`` is the one pool of
+                    # them; the update is dropped) so stale pool pages
+                    # stay intact: one layer's page NB is the next
+                    # layer's page 0
+                    blk_i = jnp.where(jnp.arange(Ts) < valid, blk_i,
+                                      pool_geometry(pk)[0])
+                return prefill_block(
+                    x, lp, pk, pv, blk_i, off, layer_pages(bt_row, i, NB),
+                    mask, cos, sin, spec=spec, start=start,
+                    ffn=ffn_override, scale=scale, backend=backend)
 
-            x, (pk2, pv2) = jax.lax.scan(body, x,
-                                         (blocks, pool_k, pool_v))
+            x, pk2, pv2 = _scan_layers(layer, x, blocks, pool_k, pool_v)
             last = x[:, -1] if valid is None \
                 else jnp.take(x, valid - 1, axis=1)
             xf = rms(last, params["lnf_w"])

@@ -335,8 +335,8 @@ def build_llama_decoder(cfg, max_len: int,
                   * mm(lp, "up_w", y))
 
     if quant is None:
-        def mm(lp, name, y):
-            return y @ lp[name]
+        # a serving engine's tree stores q/k/v [N, K]: one definition
+        from ..ops.decode_block import matmul_stored as mm
     else:
         wdt = "int4" if quant == "weight_only_int4" else "int8"
 

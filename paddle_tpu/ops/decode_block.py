@@ -55,7 +55,7 @@ __all__ = ["DecodeBlockSpec", "DecodeBlockUnsupportedError",
            "decode_block", "decode_block_spec", "decode_block_tier",
            "decode_block_unsupported_reason",
            "hbm_traffic_per_chunk", "hbm_traffic_per_token", "make_norm",
-           "make_ffn", "make_mm",
+           "make_ffn", "make_mm", "matmul_stored", "serving_layout",
            "make_norm_ffn", "prefill_attention_xla", "prefill_block",
            "prefill_block_xla",
            "prefill_block_tier", "prefill_block_unsupported_reason",
@@ -177,9 +177,51 @@ def make_norm(spec: DecodeBlockSpec) -> Callable:
     return norm
 
 
+def serving_layout(blocks):
+    """The stacked blocks of a Llama-family tree as a serving engine
+    holds them: the full-width ``q_w`` / ``k_w`` / ``v_w``
+    ``[..., K, N]`` become ``q_wt`` / ``k_wt`` / ``v_wt``
+    ``[..., N, K]``, every other leaf as it is.  For these three
+    matmuls the TPU compiler assigns the weight the layout ``[N, K]``;
+    given the tree's ``[K, N]`` it transposes the whole stacked leaf at
+    the top of EVERY call of the decode step and of a chunk fill (1.6 GB
+    of traffic a call at 16 layers of Mistral-7B's widths;
+    ``tests/test_chip_compile.py`` holds the programs to it).  Laid out
+    once, where the engine is built, they are read in place
+    (:func:`matmul_stored`).  The tree that training, checkpoints and
+    the PTQ export share keeps its layout: a tree that is already laid
+    out, or holds ``q_w__q`` codes and no ``q_w``, comes back as it
+    is."""
+    out = dict(blocks)
+    names = [n for n in ("q_w", "k_w", "v_w") if n in out]
+    if names:          # ONE program for the three (COMPILE_BUDGET.md)
+        laid = _swap_last_two([out.pop(n) for n in names])
+        out.update((n + "t", w) for n, w in zip(names, laid))
+    return out
+
+
+@jax.jit
+def _swap_last_two(ws):
+    return [jnp.swapaxes(w, -1, -2) for w in ws]
+
+
+def matmul_stored(lp, name, y):
+    """``y @ W`` for the full-width weight ``name`` of layer dict
+    ``lp``, contracted in the layout it is stored in: ``[K, N]`` under
+    its own name, or ``[N, K]`` under ``name + "t"``
+    (:func:`serving_layout`).  The same products either way; the
+    order they are summed in is the backend's (the CPU tier's differs
+    between the two layouts at some shapes, by a last bit in float32:
+    tests/test_decode_block.py)."""
+    wt = lp.get(name + "t")
+    if wt is None:
+        return y @ lp[name]
+    return jnp.einsum("...k,nk->...n", y, wt)
+
+
 def make_mm(spec: DecodeBlockSpec) -> Callable:
     """``mm(lp, name, y)`` — the ONE matmul closure of every reference-
-    tier serve program.  Full width: ``y @ lp[name]``.  Weight-only
+    tier serve program.  Full width: :func:`matmul_stored`.  Weight-only
     quantized: dequantizing matmul over the export layout — per-channel
     scales post-multiply the int-code matmul (fp32 accumulation), grouped
     scales dequantize the weight tile first (a per-channel post-multiply
@@ -187,9 +229,7 @@ def make_mm(spec: DecodeBlockSpec) -> Callable:
     ``ops/pallas/quant_linear._block_scale`` makes, so the Pallas tier
     mirrors this structure."""
     if spec.weight_dtype is None:
-        def mm(lp, name, y):
-            return y @ lp[name]
-        return mm
+        return matmul_stored
     wdt, gs = spec.weight_dtype, spec.group_size
 
     def mm(lp, name, y):

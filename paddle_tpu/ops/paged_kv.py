@@ -27,7 +27,8 @@ __all__ = ["BlockAllocator", "PagedKVCache", "PagedKVGeometryError",
            "QuantizedKVPool", "paged_decode_attention", "paged_append",
            "validate_paged_decode_geometry", "quantize_kv",
            "dequantize_kv", "kv_page_bytes", "zeros_kv_pool",
-           "pool_geometry", "is_quantized_pool", "decode_walk"]
+           "pool_geometry", "is_quantized_pool", "decode_walk",
+           "layers_as_one_pool", "layer_pages"]
 
 NEG_INF = -1e30
 
@@ -70,6 +71,30 @@ def pool_geometry(pool):
     D]-shaped pool, full-width or quantized."""
     arr = pool.data if isinstance(pool, QuantizedKVPool) else pool
     return tuple(arr.shape[-4:])
+
+
+def layers_as_one_pool(pool, like=None):
+    """The pools of all layers, stacked ``[L, NB, BS, ...]``, as ONE
+    pool ``[L*NB, BS, ...]`` whose pages ``i*NB .. (i+1)*NB - 1`` are
+    layer ``i``'s (every array of a :class:`QuantizedKVPool` alike);
+    with ``like`` (the stacked pool it came from) the way back.  A
+    reshape of the leading dimensions: no byte moves.  A layer scan
+    that carries this pool whole and hands each layer its pages through
+    :func:`layer_pages` appends in place; one that slices a layer's
+    ``[NB, ...]`` out of the stack and puts it back moves the whole
+    stack through memory every call."""
+    if like is not None:
+        return jax.tree.map(lambda a, b: a.reshape(b.shape), pool, like)
+    return jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), pool)
+
+
+def layer_pages(table, layer, num_blocks: int):
+    """A table of one layer's page numbers as page numbers of
+    :func:`layers_as_one_pool`'s pool: a mapped entry moves into layer
+    ``layer``'s range, an unmapped one (negative) stays negative, so it
+    is still dropped by an append and masked by a walk (which reads the
+    whole pool's page 0 for it: any finite page does)."""
+    return jnp.where(table >= 0, table + layer * num_blocks, table)
 
 
 def quantize_kv(kv):

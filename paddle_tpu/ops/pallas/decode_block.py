@@ -103,14 +103,20 @@ def _weight_names(spec) -> Tuple[str, ...]:
             "up_w", "down_w")
 
 
-def _param_keys(spec) -> Tuple[str, ...]:
-    """The layer-dict keys the kernel streams, in ref order: matmul
-    weights expand to (codes, scales) pairs under weight-only quant."""
+def _param_keys(spec, lp=()) -> Tuple[str, ...]:
+    """The keys of layer dict ``lp`` the kernel streams, in ref order:
+    matmul weights expand to (codes, scales) pairs under weight-only
+    quant; a full-width weight that ``lp`` stores ``[N, K]`` under
+    ``name + "t"`` (``ops.decode_block.serving_layout``: a serving
+    engine's q/k/v) streams under that key and is contracted as it
+    lies (:func:`_mmw`)."""
     wdt = getattr(spec, "weight_dtype", None)
     keys = []
     for n in _weight_names(spec):
         if wdt is not None and n in _MATMUL_NAMES:
             keys.extend((n + "__q", n + "__s"))
+        elif n not in lp and n + "t" in lp:
+            keys.append(n + "t")
         else:
             keys.append(n)
     return tuple(keys)
@@ -145,7 +151,7 @@ def unsupported_reason(spec, lp, pool_k) -> Optional[str]:
     half the rows) plus fp32 ``__s`` scales, which is how int8/int4
     provably admits layer widths whose full-width weights overflow the
     budget (the fusion-envelope pin)."""
-    keys = _param_keys(spec)
+    keys = _param_keys(spec, lp)
     missing = [n for n in keys if n not in lp]
     if missing:
         return (f"layer dict lacks {missing} — not a dense "
@@ -182,12 +188,13 @@ def _norm_rows(x, w, b, meta: _Meta):
     return xc * jax.lax.rsqrt(var + meta.eps) * w[None, :] + b[None, :]
 
 
-def _mm(a32, w_ref):
+def _mm(a32, w_ref, w_contract: int = 0):
     """[1, n] fp32 × weight ref [n, m] → [1, m] fp32 (MXU dot in the
-    weight's storage dtype, fp32 accumulation — the per-op precision)."""
+    weight's storage dtype, fp32 accumulation — the per-op precision);
+    ``w_contract=1``: the ref holds the weight ``[m, n]``."""
     w = w_ref[:]
     return jax.lax.dot_general(a32.astype(w.dtype), w,
-                               (((1,), (0,)), ((), ())),
+                               (((1,), (w_contract,)), ((), ())),
                                preferred_element_type=jnp.float32)
 
 
@@ -221,6 +228,8 @@ def _mmw(a32, w, name, meta: "_Meta"):
     """Matmul against logical weight ``name`` — full width or the
     quantized (codes, scales) pair, decided by the spec."""
     if meta.weight_dtype is None:
+        if name + "t" in w:
+            return _mm(a32, w[name + "t"], 1)
         return _mm(a32, w[name])
     return _mm_quant(a32, w[name + "__q"], w[name + "__s"], meta)
 
@@ -434,7 +443,7 @@ def _fitting_candidates(spec, mb: int, pool_itemsize: int, wbytes: int,
 
 def _tuned_pages(spec, lp, pool_k, mb: int, args) -> int:
     from .autotune import FLAGS, lookup, pick
-    keys = _param_keys(spec)
+    keys = _param_keys(spec, lp)
     wbytes = sum(lp[n].size * lp[n].dtype.itemsize for n in keys)
     x_isz = lp[keys[0]].dtype.itemsize
     kvq = is_quantized_pool(pool_k)
@@ -472,7 +481,7 @@ def _call(x, lp, pool_k, pool_v, block_table, lengths, cos, sin, *,
     BS = spec.block_size
     mb = block_table.shape[1]
     nt = -(-mb // pages)
-    keys = _param_keys(spec)
+    keys = _param_keys(spec, lp)
     kvq = is_quantized_pool(pool_k)
     meta = _Meta(hidden=H, num_heads=Hq, kv_heads=Hkv, head_dim=D,
                  block_size=BS, norm=spec.norm,

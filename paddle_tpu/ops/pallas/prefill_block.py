@@ -106,7 +106,7 @@ def unsupported_reason(spec, lp, pool_k, chunk: int) -> Optional[str]:
     Layout checks (a dense layer dict) live here; every byte/cap limit
     is delegated to the shared cost model so the static analysis and
     this runtime gate cannot drift."""
-    keys = _param_keys(spec)
+    keys = _param_keys(spec, lp)
     missing = [n for n in keys if n not in lp]
     if missing:
         return (f"layer dict lacks {missing} — not a dense "
@@ -333,7 +333,7 @@ def _fitting_candidates(spec, chunk: int, mb: int, pool_itemsize: int,
 
 def _tuned_pages(spec, lp, pool_k, mb: int, chunk: int, args) -> int:
     from .autotune import FLAGS, lookup, pick
-    keys = _param_keys(spec)
+    keys = _param_keys(spec, lp)
     wbytes = sum(lp[n].size * lp[n].dtype.itemsize for n in keys)
     x_isz = lp[keys[0]].dtype.itemsize
     kvq = is_quantized_pool(pool_k)
@@ -372,7 +372,7 @@ def _call(x, lp, pool_k, pool_v, bt_row, start, cos, sin, *, spec,
     BS = spec.block_size
     mb = bt_row.shape[0]
     nt = -(-mb // pages)
-    keys = _param_keys(spec)
+    keys = _param_keys(spec, lp)
     kvq = is_quantized_pool(pool_k)
     meta = _Meta(hidden=H, num_heads=Hq, kv_heads=Hkv, head_dim=D,
                  block_size=BS, norm=spec.norm,

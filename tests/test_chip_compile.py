@@ -13,6 +13,9 @@ load libtpu, and every xdist worker imports every test file; every
 compile happens in this process; all such tests live in this ONE file.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -156,13 +159,27 @@ def _block_case(chip):
     return cfg, decode_block_spec(cfg, 16), lp, pool
 
 
-def test_decode_block_megakernel(chip):
+def _laid_out(chip, lp, layout):
+    """``lp`` as the tree stores it, or as a serving engine holds it
+    (q/k/v ``[N, K]``: the kernels contract them as they lie)."""
+    if layout == "tree":
+        return lp
+    from paddle_tpu.ops.decode_block import serving_layout
+    return on(chip, jax.eval_shape(serving_layout, lp))
+
+
+LAYOUTS = ("tree", "serving")
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_decode_block_megakernel(chip, layout):
     """Refused by the lowering until PR 22: a (1, H) row block of a
     [B, H] array (now a squeezed [B, 1, H] block) and a scatter
     (``.at[].set``, now a concatenate)."""
     from paddle_tpu.ops.decode_block import (
         decode_block, decode_block_unsupported_reason)
     cfg, spec, lp, pool = _block_case(chip)
+    lp = _laid_out(chip, lp, layout)
     assert decode_block_unsupported_reason(spec, lp, pool) is None
     B, MB, D = 8, 128, cfg.head_dim
     compile_kernel(
@@ -173,10 +190,12 @@ def test_decode_block_megakernel(chip):
         chip((B, D)))
 
 
-def test_prefill_block_megakernel(chip):
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_prefill_block_megakernel(chip, layout):
     from paddle_tpu.ops.decode_block import (
         prefill_block, prefill_block_unsupported_reason)
     cfg, spec, lp, pool = _block_case(chip)
+    lp = _laid_out(chip, lp, layout)
     Ts, MB, D = 64, 128, cfg.head_dim
     assert prefill_block_unsupported_reason(spec, lp, pool, Ts) is None
     compile_kernel(
@@ -244,6 +263,79 @@ def test_block_megakernels_refuse_narrow_heads_with_a_reason(chip):
             chip((B, cfg.hidden_size)), lp, pool, pool,
             chip((B, 16), jnp.int32), chip((B,), jnp.int32),
             chip((B, 64)), chip((B, 64))).compile()
+
+
+# the Mistral serve cells' engine (benchmark/configs: 16 layers of the
+# published widths, 32 slots, 1024 pages of 16, a table of 256)
+_SERVE = dict(layers=16, slots=32, pages=1024, page=16, table=256)
+_MIB = 1 << 20
+_COPY = re.compile(r"= (\w+)\[([\d,]*)\]\S* copy(?:-start)?\(")
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+          "u32": 4, "f32": 4}
+
+
+@pytest.mark.parametrize("program, temp_mib", [
+    ("step", 64), ("fill128", 64), ("fill512", 512)])
+def test_engine_programs_move_no_byte_twice(chip, program, temp_mib):
+    """The engine's decode step and its 128 and 512 chunk fills at the
+    Mistral cells' sizes, from abstract arguments: the pools ride whole
+    in the layer scan's carry and q/k/v are read in the layout they are
+    stored in, so the compiled program holds no temporaries to speak of
+    and copies no large array.
+
+    Before ISSUE 31 the three read ``temp_size_in_bytes`` 1,880,259,072
+    / 2,015,508,480 / 2,547,390,976: both ``[16, 1024, 16, 8, 128]``
+    pools copied whole every call (scanned as inputs and outputs), each
+    layer's ``[1024, 16, 8, 128]`` pool sliced out of the stack and put
+    back, and the stacked ``q_w`` / ``k_w`` / ``v_w`` transposed whole.
+    Now 1,032,192 / 387,072 / 269,467,136 (the 512 fill's own float32
+    scores, ``[32, 512, 4096]``)."""
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models.llama import LlamaConfig, stack_block_params
+    from paddle_tpu.ops.decode_block import serving_layout
+    z = _SERVE
+    cfg = LlamaConfig(vocab_size=32768, hidden_size=4096,
+                      intermediate_size=14336, num_layers=z["layers"],
+                      num_heads=32, num_kv_heads=8,
+                      max_position_embeddings=32768, rms_norm_eps=1e-5,
+                      rope_theta=1e6, dtype="bfloat16")
+    H, V = cfg.hidden_size, cfg.vocab_size
+    params = on(chip, {
+        "wte": jax.ShapeDtypeStruct((V, H), BF16),
+        "head": jax.ShapeDtypeStruct((H, V), BF16),
+        "lnf_w": jax.ShapeDtypeStruct((H,), BF16),
+        "blocks": jax.eval_shape(lambda: serving_layout(
+            stack_block_params(cfg, jax.random.key(0), 1)))})
+    # the builders read these of an engine and nothing else: no pools,
+    # no weights
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.cfg, eng.BS, eng._hybrid, eng.quant_config = \
+        cfg, z["page"], False, None
+    eng.fused_decode_block = eng.fused_prefill = True
+    pool = chip((z["layers"], z["pages"], z["page"], cfg.kv_heads,
+                 cfg.head_dim))
+    i32 = jnp.int32
+    if program == "step":
+        fn, args = eng._build_step(), (
+            chip((z["slots"], z["table"]), i32), chip((z["slots"],), i32),
+            chip((z["slots"],), i32))
+    else:
+        Ts = int(program[4:])
+        fn, args = eng._build_chunk_fill(Ts), (
+            chip((z["table"],), i32), chip((), i32), chip((Ts,), i32),
+            chip((), i32))
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, pool, pool, *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < temp_mib * _MIB
+    text = compiled.as_text()
+    copied = [(dt, dims) for dt, dims in _COPY.findall(text)
+              if _BYTES.get(dt, 4) * math.prod(int(d) for d in dims.split(",")
+                                        if d) >= 32 * _MIB]
+    assert not copied, f"large arrays copied: {copied}"
+    # no layer's pool is sliced out of the stack (or put back into it)
+    assert "bf16[1024,16,8,128]" not in text
+    assert "bf16[16384,16,8,128]" in text
 
 
 def test_decode_attention(chip):

@@ -427,3 +427,286 @@ def test_decode_block_spec_from_configs():
                                     max_position_embeddings=32), 8)
     assert (g.norm, g.activation, g.rope, g.fused_qkv, g.bias) == \
         ("ln", "gelu", False, True, True)
+
+
+# ---------------------------------------------------------------------------
+# the engine's layer scan (ISSUE 31): the pools whole in the carry, all
+# layers as one pool, and q/k/v contracted in the layout they are stored
+# in — against the formulation the engine had before, kept HERE
+# ---------------------------------------------------------------------------
+def _scan_model(kv_quant, dtype="float32"):
+    """A 3-layer engine (page 0 of every layer holds its own garbage,
+    so a masked read of the wrong layer's page would show) and the tree
+    it was built from."""
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models.llama import llama_tiny, stack_block_params
+    from paddle_tpu.ops.paged_kv import QuantizedKVPool
+    from paddle_tpu.quantization import ServeQuantConfig
+    cfg = llama_tiny(num_layers=3, dtype=dtype)
+    k1, k2, k3 = jax.random.split(jax.random.key(31), 3)
+    dt = jnp.dtype(dtype)
+    params = {
+        "wte": jax.random.normal(k1, (cfg.vocab_size, cfg.hidden_size),
+                                 dt) * 0.05,
+        "head": jax.random.normal(k2, (cfg.hidden_size, cfg.vocab_size),
+                                  dt) * 0.05,
+        "lnf_w": jnp.ones(cfg.hidden_size, dt),
+        "blocks": stack_block_params(cfg, k3, 1)}
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_batch=3, block_size=4, num_blocks=8,
+        max_blocks_per_seq=4, prefill_buckets=(8,),
+        quant_config=ServeQuantConfig(kv_dtype="int8") if kv_quant
+        else None)
+    shape = (cfg.num_layers, 8, 4, cfg.kv_heads, cfg.head_dim)
+
+    def pool():
+        if kv_quant:
+            return QuantizedKVPool(
+                data=jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+                scale=jnp.asarray(rng.uniform(1e-3, 2e-2, shape[:-1]),
+                                  jnp.float32))
+        return _w(*shape, dtype=dt, scale=1.0)
+
+    return cfg, params, eng, pool(), pool()
+
+
+def _scan_as_it_was(body_of, params, pool_k, pool_v):
+    """The scan of the engine's programs before ISSUE 31: the stacked
+    pools as scan INPUTS and outputs, each layer handed its own
+    ``[NB, ...]`` pool.  ``params``: the engine's tree for the bits of
+    the scan alone, the tree it was built from (``y @ w``) for q/k/v
+    too."""
+    from paddle_tpu.models.generation import _collapse_blocks
+
+    def body(x, inp):
+        lp, pk, pv = inp
+        x, pk, pv = body_of(x, lp, pk, pv)
+        return x, (pk, pv)
+
+    def run(x):
+        return jax.lax.scan(
+            body, x, (_collapse_blocks(params["blocks"]), pool_k, pool_v))
+    return run
+
+
+def _head(cfg, params, x):
+    from paddle_tpu.inference.serving import _make_rms_ffn
+    xf = _make_rms_ffn(cfg)[0](x, params["lnf_w"])
+    return jnp.einsum("bh,hv->bv", xf, params["head"],
+                      preferred_element_type=jnp.float32)
+
+
+def _rope_tables(cfg):
+    from paddle_tpu.models.llama import _rope_cos_sin
+    return _rope_cos_sin(cfg.max_position_embeddings, cfg.head_dim,
+                         cfg.rope_theta, jnp.dtype(cfg.dtype), None)
+
+
+def _step_as_it_was(eng, params):
+    cfg = eng.cfg
+    cos_full, sin_full = _rope_tables(cfg)
+    spec = decode_block_spec(cfg, eng.BS)
+
+    def step(pool_k, pool_v, bt, lengths, tokens):
+        cos = jnp.take(cos_full, lengths, axis=0)
+        sin = jnp.take(sin_full, lengths, axis=0)
+        x, (pk, pv) = _scan_as_it_was(
+            lambda x, lp, pk, pv: decode_block(
+                x, lp, pk, pv, bt, lengths, cos, sin, spec=spec),
+            params, pool_k, pool_v)(jnp.take(params["wte"], tokens, axis=0))
+        return pk, pv, _head(cfg, params, x)
+
+    return step
+
+
+def _fill_as_it_was(eng, params, Ts):
+    from paddle_tpu.ops.decode_block import prefill_block
+    cfg, BS = eng.cfg, eng.BS
+    cos_full, sin_full = _rope_tables(cfg)
+    spec = decode_block_spec(cfg, BS)
+
+    def fill(pool_k, pool_v, bt_row, start, toks, valid=None):
+        pos = start + jnp.arange(Ts)
+        blk = jnp.take(jnp.maximum(bt_row, 0), pos // BS)
+        if valid is not None:        # padded rows: one layer's page NB
+            blk = jnp.where(jnp.arange(Ts) < valid, blk,
+                            jax.tree.leaves(pool_k)[0].shape[1])
+        mask = jnp.arange(bt_row.shape[0] * BS)[None, None, None, :] \
+            <= pos[None, None, :, None]
+        cos = jnp.take(cos_full, pos, axis=0)
+        sin = jnp.take(sin_full, pos, axis=0)
+        x, (pk, pv) = _scan_as_it_was(
+            lambda x, lp, pk, pv: prefill_block(
+                x, lp, pk, pv, blk, pos % BS, bt_row, mask, cos, sin,
+                spec=spec, start=start,
+                scale=1.0 / (cfg.head_dim ** 0.5)),
+            params, pool_k, pool_v)(
+                jnp.take(params["wte"], toks, axis=0)[None])
+        last = x[:, -1] if valid is None \
+            else jnp.take(x, valid - 1, axis=1)
+        return pk, pv, _head(cfg, params, last)
+
+    return fill
+
+
+def _same_bits(got, want, what):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype, what
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32),
+                                      err_msg=what)
+
+
+# slot 0: 5 tokens on pages 2, 5, the rest of its row unmapped; slot 1:
+# 3 tokens on pages 1, 4 (verify appends three more); slot 2: idle (nothing mapped, length 0)
+_BT = np.array([[2, 5, -1, -1], [1, 4, -1, -1], [-1, -1, -1, -1]],
+               np.int32)
+_LEN = np.array([5, 3, 0], np.int32)
+_LIVE = np.array([0, 1])
+
+
+@pytest.mark.parametrize("dtype", ("float32", "bfloat16"))
+@pytest.mark.parametrize("kv_quant", (False, True),
+                         ids=("full_kv", "int8_kv"))
+def test_step_scan_over_whole_pools_bit_identical(kv_quant, dtype):
+    """Decode step: pools (every bit, every layer) and the live rows'
+    logits equal the old formulation's, with an idle slot and unmapped
+    table entries.  (An idle row reads page 0 of the WHOLE pool where
+    it read its layer's: garbage in, garbage out, never read.)"""
+    cfg, params, eng, pk, pv = _scan_model(kv_quant, dtype)
+    assert "q_wt" in eng.params["blocks"] and "q_w" in params["blocks"]
+    args = (jnp.asarray(_BT), jnp.asarray(_LEN),
+            jnp.asarray([7, 11, 0], jnp.int32))
+    want = jax.jit(_step_as_it_was(eng, eng.params))(pk, pv, *args)
+    got = jax.jit(eng._build_step())(eng.params, pk, pv, *args)
+    _same_bits(got[:2], want[:2], "pools")
+    _same_bits(got[2][_LIVE], want[2][_LIVE], "live logits")
+    # and from the tree's own layout: the same products, summed in the
+    # order the backend chooses for each layout (one bit apart at most)
+    tree = jax.jit(_step_as_it_was(eng, params))(pk, pv, *args)
+    np.testing.assert_allclose(
+        np.asarray(got[2][_LIVE]), np.asarray(tree[2][_LIVE]),
+        **(dict(rtol=2e-2, atol=2e-2) if dtype == "bfloat16"
+           else dict(rtol=1e-4, atol=1e-5)))
+    # the rows landed, in every layer (not only where the old code put
+    # them too): the pools changed at page 5 / 1, offset 1 / 3
+    assert not np.array_equal(
+        np.asarray(jax.tree.leaves(got[0])[0], np.float32),
+        np.asarray(jax.tree.leaves(pk)[0], np.float32))
+
+
+@pytest.mark.parametrize("valid", (None, 8, 5),
+                         ids=("unpadded", "full_bucket", "padded"))
+@pytest.mark.parametrize("kv_quant", (False, True),
+                         ids=("full_kv", "int8_kv"))
+def test_fill_scan_over_whole_pools_bit_identical(kv_quant, valid):
+    """Chunk fill of 8 rows after a committed page: a padded bucket's
+    rows (``valid`` 5) are dropped in EVERY layer — sent to one layer's
+    page NB they would land in the next layer's page 0."""
+    cfg, params, eng, pk, pv = _scan_model(kv_quant)
+    bt_row = jnp.asarray([3, 6, 1, -1], jnp.int32)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, 8), jnp.int32)
+    tail = () if valid is None else (jnp.int32(valid),)
+    # the old scan compiles itself; the head after it runs per op (in
+    # ONE program the CPU compiler fuses the old form's last row into
+    # its loop and rounds the logits differently from the old form run
+    # per op, with which the new program agrees to the bit)
+    want = _fill_as_it_was(eng, eng.params, 8)(
+        pk, pv, bt_row, jnp.int32(4), toks, *tail)
+    got = jax.jit(eng._build_chunk_fill(8))(
+        eng.params, pk, pv, bt_row, jnp.int32(4), toks, *tail)
+    _same_bits(got, want, "pools and logits")
+    if valid == 5:                   # page 0 of every layer is untouched
+        for g, p in zip(jax.tree.leaves(got[:2]),
+                        jax.tree.leaves((pk, pv))):
+            np.testing.assert_array_equal(np.asarray(g[:, 0]),
+                                          np.asarray(p[:, 0]))
+
+
+@pytest.mark.parametrize("kv_quant", (False, True),
+                         ids=("full_kv", "int8_kv"))
+def test_verify_scan_inherits_the_whole_pool_step(kv_quant):
+    """The spec-decode verify program scans the SAME step closure: three
+    positions through it equal three through the old formulation."""
+    from paddle_tpu.spec_decode.verify import build_verify_program
+    cfg, params, eng, pk, pv = _scan_model(kv_quant)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (3, 3)), jnp.int32)
+    old = _step_as_it_was(eng, eng.params)
+    want = jax.jit(build_verify_program(
+        lambda _, *a: old(*a)))(None, pk, pv, jnp.asarray(_BT),
+                                jnp.asarray(_LEN), toks)
+    got = jax.jit(build_verify_program(eng._build_step()))(
+        eng.params, pk, pv, jnp.asarray(_BT), jnp.asarray(_LEN), toks)
+    _same_bits(got[:2], want[:2], "pools")
+    _same_bits(got[2][_LIVE], want[2][_LIVE], "live logits")
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=("fp32", "bf16"))
+@pytest.mark.parametrize("lead", ((3,), (1, 8)), ids=("rows", "tile"))
+def test_matmul_stored_contracts_either_layout(lead, dtype):
+    """``[N, K]`` under ``name + "t"`` contracts to ``y @ w``: the same
+    bits wherever every sum is exact (small whole numbers), and within
+    rounding on random values, where the CPU backend sums the two
+    layouts in different orders at some shapes; eager and compiled.
+    The layout is made once: a tree that has it, or holds codes, comes
+    back as it is."""
+    from paddle_tpu.ops.decode_block import matmul_stored, serving_layout
+    own = np.random.default_rng(31)
+    w = _w(2, 64, 24, dtype=dtype)
+    laid = serving_layout({"q_w": w, "o_w": w, "k_w__q": w})
+    assert set(laid) == {"q_wt", "o_w", "k_w__q"}
+    assert laid["q_wt"].shape == (2, 24, 64)
+    assert serving_layout(laid).keys() == laid.keys() \
+        and serving_layout(laid)["q_wt"] is laid["q_wt"]
+    tol = dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 \
+        else dict(rtol=1e-5, atol=1e-6)
+    for exact in (True, False):
+        if exact:
+            y = jnp.asarray(own.integers(-2, 3, (*lead, 64)), dtype)
+            wk = jnp.asarray(own.integers(-2, 3, (64, 24)), dtype)
+        else:
+            y, wk = _w(*lead, 64, dtype=dtype), w[1]
+        want = np.asarray(y @ wk, np.float32)
+        for f in (matmul_stored, jax.jit(matmul_stored, static_argnums=1)):
+            for lp in ({"q_wt": wk.T}, {"q_w": wk}):
+                got = np.asarray(f(lp, "q_w", y), np.float32)
+                if exact:
+                    np.testing.assert_array_equal(got, want)
+                else:
+                    np.testing.assert_allclose(got, want, **tol)
+
+
+@pytest.mark.parametrize("program", ("decode", "prefill"))
+def test_pallas_tiers_read_qkv_as_stored(program):
+    """Both megakernels stream ``q_wt``/``k_wt``/``v_wt`` and contract
+    them as they lie: the same values as from the tree's layout."""
+    from paddle_tpu.ops.decode_block import prefill_block, serving_layout
+    spec, lp, x, pk, pv, bt, ln, cos, sin = _variant("llama_gqa",
+                                                     np.float32)
+    laid = serving_layout(lp)
+    assert decode_block_unsupported_reason(spec, laid, pk) is None
+    old = FLAGS.pallas_interpret
+    set_flags({"pallas_interpret": True})
+    try:
+        if program == "decode":
+            def run(w):
+                return decode_block(x, w, pk, pv, bt, ln, cos, sin,
+                                    spec=spec, backend="pallas")
+        else:
+            Ts, start = 4, 4
+            pos = start + jnp.arange(Ts)
+            xt = _w(1, Ts, spec.hidden, scale=0.5)
+            c, s = _w(Ts, spec.head_dim, scale=1.0), \
+                _w(Ts, spec.head_dim, scale=1.0)
+
+            def run(w):
+                return prefill_block(
+                    xt, w, pk, pv, jnp.take(bt[0], pos // 4), pos % 4,
+                    bt[0], None, c, s, spec=spec, start=jnp.int32(start),
+                    backend="pallas")
+        got, want = run(laid), run(lp)
+    finally:
+        set_flags({"pallas_interpret": old})
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-6, atol=1e-6)

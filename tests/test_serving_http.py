@@ -919,6 +919,10 @@ def test_cli_builds_the_model_its_flags_name():
                                             num_microbatches=1)
         ref = init_fn(3)["params"]
         set_topology(HybridTopology())
+        # the engine holds q/k/v as its programs read them
+        from paddle_tpu.ops.decode_block import serving_layout
+        ref = dict(ref, blocks=serving_layout(ref["blocks"]))
+        assert "q_wt" in eng.params["blocks"]
         for got, want in zip(jax.tree.leaves(eng.params),
                              jax.tree.leaves(ref)):
             assert got.dtype == want.dtype == np.dtype("bfloat16")

@@ -109,7 +109,8 @@ def gpt_train() -> Callable[[], None]:
 
 def serve_fresh() -> Callable[[], None]:
     """bench.py --config serve at liveness shapes: cold engine start
-    (decode step + one declared-bucket fill compile) + full drain."""
+    (the one-time q/k/v relayout, 13 -> 14 with ISSUE 31; decode step +
+    one declared-bucket fill compile) + full drain."""
     cfg, params, prompts = _tiny_llama()
 
     def workload():
