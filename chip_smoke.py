@@ -172,7 +172,6 @@ def serve_phase(tiny: bool, seed: int, monitor) -> None:
           "some decode step ran a batch > 1")
     check(report["kv_leaked_blocks"] == 0,
           f"zero leaked KV blocks at shutdown, got {report}")
-    tiers = eng.kernel_tiers()
     decode = dict(steps=eng.decode_steps, slot_steps=eng.decode_slot_steps)
     stats = dict(eng.stats, **eng.aot_stats())
     mem_served = memory(jax.devices()[0])
@@ -204,9 +203,6 @@ def serve_phase(tiny: bool, seed: int, monitor) -> None:
         kv_pool_tokens=geometry["num_blocks"] * geometry["block_size"],
         requests=len(prompts), prompt_lens=lens, new_tokens=NEW_TOKENS,
         decode=decode, engine_stats=stats, shutdown=report,
-        tiers=dict(tiers, attention={
-            "tier": "xla", "reason": "paged decode / chunk-prefill "
-            "attention is part of the per-op block tier"}),
         build_secs=round(built_s, 2), serve_secs=round(served_s, 2),
         memory_after_build=mem_built, memory_after_serving=mem_served,
         compiles=compile_report(monitor, c0))

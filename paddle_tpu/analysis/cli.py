@@ -2,8 +2,8 @@
 
 Modes:
 
-* file/dir:  ``python -m paddle_tpu.analysis paddle_tpu/ bench.py``
-  (no paths: the repo's lint surface — paddle_tpu/, bench.py, tools/)
+* file/dir:  ``python -m paddle_tpu.analysis paddle_tpu/ tools/``
+  (no paths: the repo's lint surface — paddle_tpu/, tools/)
 * diff:      ``python -m paddle_tpu.analysis --diff HEAD~1`` — only
   files changed versus the git ref
 * output:    human (default) or ``--json``
@@ -29,7 +29,7 @@ from typing import List, Optional
 from . import baseline as baseline_mod
 from . import core
 
-DEFAULT_LINT_SURFACE = ("paddle_tpu", "bench.py", "tools")
+DEFAULT_LINT_SURFACE = ("paddle_tpu", "tools")
 
 
 def default_paths() -> List[str]:
@@ -61,7 +61,7 @@ def build_parser() -> argparse.ArgumentParser:
                     "jit/shard_map/donation code")
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to analyze (default: the repo lint "
-                         "surface: paddle_tpu/, bench.py, tools/)")
+                         "surface: paddle_tpu/, tools/)")
     ap.add_argument("--diff", metavar="REF",
                     help="analyze only .py files changed vs the git ref")
     ap.add_argument("--select", metavar="IDS",

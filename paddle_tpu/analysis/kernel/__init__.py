@@ -4,10 +4,11 @@ The package has two faces sharing one cost model:
 
 * :mod:`.cost` — the VMEM cost model.  Closed-form per-kernel byte
   estimates plus the per-generation budget table.  This is ALSO the
-  runtime source of truth: ``ops/decode_block.py``'s fusion-fallback
-  gate and ``ops/pallas``'s autotune config-validity filter import it,
-  so the number the static analyzer checks against is the number the
-  serving dispatch actually enforces — they cannot drift.
+  runtime source of truth: the fused CE head's dispatch
+  (``ops/fused_cross_entropy.py``) and ``ops/pallas``'s autotune
+  config-validity filter import it, so the number the static analyzer
+  checks against is the number the dispatch actually enforces — they
+  cannot drift.
 * :mod:`.extract` + the ``kl00X_*`` rule modules — an AST model of
   every ``pl.pallas_call`` site (grid, BlockSpecs, index maps,
   scratch_shapes, dtypes) feeding the KL001–KL006 rules, registered in

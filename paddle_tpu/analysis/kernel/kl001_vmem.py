@@ -8,9 +8,10 @@ configured generation's core — Mosaic would reject it on hardware
 after a compile this rule catches at review time.
 
 Runtime-dependent geometries (most real kernels) are NOT flagged: for
-those, the same cost model is enforced dynamically by the fusion
-fallback in ``ops/decode_block.py`` and the autotune validity filters,
-which this package is the single source of truth for.
+those, the same cost model is enforced dynamically by the fused CE
+head's fallback in ``ops/fused_cross_entropy.py`` and the autotune
+validity filters, which this package is the single source of truth
+for.
 """
 
 from __future__ import annotations

@@ -12,8 +12,7 @@ least one masking construct in its transitive body: ``pl.when``,
 ``jnp.where``, a ``broadcasted_iota``/``iota`` position stream, or an
 index clamp (``minimum``/``maximum``/``clip``).  This matches how
 every masked kernel in the repo is written (linear_ce masks
-``cols < V``; decode_block clamps the block-table index and masks
-``t < length``).
+``cols < V``; decode_attention masks ``t < length``).
 """
 
 from __future__ import annotations

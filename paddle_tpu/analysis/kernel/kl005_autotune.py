@@ -16,8 +16,7 @@ trace time.  Two drift modes have bitten similar stacks:
 The cost-model half of autotune hygiene ("a candidate that can never
 fit") is enforced at RUNTIME, where the true shapes exist: candidate
 lists are filtered through ``analysis/kernel/cost.py`` before timing
-(``decode_block._fitting_candidates``, ``linear_ce._tuned_blocks``)
-and ``pick(valid=...)`` refuses provably-overflowing configs instead
+(``linear_ce._tuned_blocks``) and ``pick(valid=...)`` refuses provably-overflowing configs instead
 of burning a compile to discover them.
 """
 

@@ -83,17 +83,6 @@ def engine_config(engine) -> Dict[str, Any]:
         # signature — hash it explicitly so an artifact exported at one
         # quantization can never half-warm-start another (ISSUE 16)
         "quant": qc.describe() if qc is not None else None,
-        # the ISSUE 9 fusion knob changes which kernel tier a RE-compile
-        # of the decode step would take, so a warm start must not cross
-        # it — an artifact exported fused never half-warms an unfused
-        # engine (and vice versa)
-        "decode_block_fused": bool(getattr(engine, "fused_decode_block",
-                                           True)),
-        # likewise the ISSUE 18 prefill-fusion knob: it changes which
-        # kernel tier a RE-compile of the chunk fills would take, so an
-        # artifact exported unfused must never half-warm a fused engine
-        "prefill_block_fused": bool(getattr(engine, "fused_prefill",
-                                            True)),
         # the cross-request prefix cache (ISSUE 14) never changes a
         # compiled program, so its POLICY knobs (offload capacity,
         # enabled flag) stay out of the hash — but the block-key SCHEME

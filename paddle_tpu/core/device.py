@@ -35,8 +35,8 @@ def enable_compile_cache() -> str:
     nothing is set in code).  Otherwise the cache lives at the fixed
     path ``<checkout>/.jax_cache`` — the path is part of the cache key,
     so it is never a temp name, pid or time.  Called at the top of the
-    entry points (``chip_smoke.py``, ``bench.py``'s child,
-    ``serving/http.py:main``), never at import."""
+    entry points (``chip_smoke.py``, ``serving/http.py:main``), never
+    at import."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

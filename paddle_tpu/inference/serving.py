@@ -228,23 +228,6 @@ class ContinuousBatchingEngine:
         donation-unsafe artifact) falls back to fresh compiles with an
         ``aot`` telemetry event; the reason is kept on
         ``self.aot_error``.
-      fused_decode_block: route every per-layer decode (and the
-        spec-decode verify scan, which wraps the same step closure)
-        through the fused block op ``ops/decode_block.py`` (ISSUE 9).
-        On the CPU/reference tier the fused op IS the per-op chain —
-        greedy output is bit-identical either way (pinned) — while on
-        TPU it dispatches to the VMEM-resident Pallas megakernel when
-        the layer geometry fits (per-op fallback otherwise).  The knob
-        is covered by the AOT artifact config hash (docs/aot.md).
-      fused_prefill: route every chunk-fill layer (bucketed prompt
-        fills AND prefix-cache suffix fills) through the fused prefill
-        block op ``ops/decode_block.prefill_block`` (ISSUE 18).  On the
-        CPU/reference tier the fused op IS the per-op chain — greedy
-        output is bit-identical either way (pinned) — while on TPU it
-        dispatches to the VMEM-resident Pallas prefill megakernel with
-        double-buffered page DMA when the layer geometry and chunk
-        length fit (per-op fallback otherwise).  The knob is covered by
-        the AOT artifact config hash (docs/aot.md).
       spec_config: a :class:`~paddle_tpu.spec_decode.SpecDecodeConfig`
         enabling speculative decoding — every decode iteration drafts
         ``k`` tokens per active request and verifies them in one
@@ -324,10 +307,9 @@ class ContinuousBatchingEngine:
                  max_blocks_per_seq: Optional[int] = None,
                  enable_prefix_caching: bool = True,
                  prefill_buckets=None, aot_dir: Optional[str] = None,
-                 fused_decode_block: bool = True,
-                 fused_prefill: bool = True, spec_config=None,
-                 enable_preemption: bool = True, spill_tier=None,
-                 prefix_cache_config=None, quant_config=None):
+                 spec_config=None, enable_preemption: bool = True,
+                 spill_tier=None, prefix_cache_config=None,
+                 quant_config=None):
         self._hybrid = getattr(cfg, "layer_types", None) is not None
         if self._hybrid:
             for what, given, why in (
@@ -371,8 +353,6 @@ class ContinuousBatchingEngine:
             from ..quantization.serve import quantize_params_for_serving
             params = quantize_params_for_serving(params, quant_config)
         self.params = params
-        self.fused_decode_block = bool(fused_decode_block)
-        self.fused_prefill = bool(fused_prefill)
         self.B = max_batch
         self.BS = block_size
         self.MB = max_blocks_per_seq or \
@@ -577,10 +557,6 @@ class ContinuousBatchingEngine:
         spec = decode_block_spec(cfg, self.BS, **self._quant_kw())
         ffn_override = moe_ffn if getattr(cfg, "moe_num_experts", 0) \
             else None
-        # fused on: auto tier (per-op reference on CPU — bit-identical —
-        # Pallas megakernel on TPU when the geometry fits); off: the
-        # per-op composition, always
-        backend = None if self.fused_decode_block else "xla"
 
         def step(params, pool_k, pool_v, bt, lengths, tokens):
             blocks = _collapse_blocks(params["blocks"])
@@ -595,7 +571,7 @@ class ContinuousBatchingEngine:
             def layer(x, lp, pk, pv, i):
                 return decode_block(
                     x, lp, pk, pv, layer_pages(bt, i, NB), lengths, cos,
-                    sin, spec=spec, ffn=ffn_override, backend=backend)
+                    sin, spec=spec, ffn=ffn_override)
 
             x, pk2, pv2 = _scan_layers(layer, x, blocks, pool_k, pool_v)
             xf = rms(x, params["lnf_w"])
@@ -636,10 +612,6 @@ class ContinuousBatchingEngine:
         spec = decode_block_spec(cfg, BS, **self._quant_kw())
         ffn_override = moe_ffn if getattr(cfg, "moe_num_experts", 0) \
             else None
-        # fused on: auto tier (per-op reference on CPU — bit-identical —
-        # Pallas prefill megakernel on TPU when the geometry and chunk
-        # length fit); off: the per-op composition, always
-        backend = None if self.fused_prefill else "xla"
 
         def fill(params, pool_k, pool_v, bt_row, start, toks, valid=None):
             # toks [Ts]; bt_row [MB]; start: prefix length
@@ -666,8 +638,8 @@ class ContinuousBatchingEngine:
                                       pool_geometry(pk)[0])
                 return prefill_block(
                     x, lp, pk, pv, blk_i, off, layer_pages(bt_row, i, NB),
-                    mask, cos, sin, spec=spec, start=start,
-                    ffn=ffn_override, scale=scale, backend=backend)
+                    mask, cos, sin, spec=spec, ffn=ffn_override,
+                    scale=scale)
 
             x, pk2, pv2 = _scan_layers(layer, x, blocks, pool_k, pool_v)
             last = x[:, -1] if valid is None \
@@ -1900,52 +1872,18 @@ class ContinuousBatchingEngine:
         return s
 
     def kernel_tiers(self) -> Dict[str, Dict[str, Optional[str]]]:
-        """Which tier the decode step and each declared prefill bucket
-        run their layers on — ``{"tier": "pallas" | "xla", "reason":
-        why the megakernel stood down, or None}`` from the SAME
-        functions the dispatch reads (``ops.decode_block.*_tier``), so a
-        printed tier is the one that ran."""
-        from ..ops.decode_block import (decode_block_spec,
-                                        decode_block_tier,
-                                        prefill_block_tier)
-        if self._hybrid:
-            from ..ops.ssm import ssm_state_update_tier
-            row = {"tier": "xla", "reason":
-                   "state-space and expert layers have the reference "
-                   "tier only"}
-            sizes = self._buckets.chunk_sizes \
-                if self._buckets is not None else ()
-            tier, why = ssm_state_update_tier(
-                self.ssm_state.shape, self.cfg.mamba_n_groups)
-            return {"decode_block": dict(row),
-                    "ssm_state_update": {"tier": tier, "reason": why},
-                    **{f"prefill_block[{c}]": dict(row) for c in sizes}}
-
-        def per_layer(tree, lead):
-            return jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape[lead:], a.dtype),
-                tree)
-
-        spec = decode_block_spec(self.cfg, self.BS, **self._quant_kw())
-        lp = per_layer(dict(self.params["blocks"]), 2)
-        pool = per_layer(self.pool_k, 1)
-        ffn = _make_rms_ffn(self.cfg)[1] \
-            if getattr(self.cfg, "moe_num_experts", 0) else None
-        def row(fused: bool, knob: str, tier):
-            tier, reason = tier() if fused else (
-                "xla", f"the engine was built with {knob}=False")
-            return {"tier": tier, "reason": reason}
-
-        tiers = {"decode_block": row(
-            self.fused_decode_block, "fused_decode_block",
-            lambda: decode_block_tier(spec, lp, pool, ffn))}
-        sizes = self._buckets.chunk_sizes if self._buckets is not None \
-            else ()
-        for c in sizes:
-            tiers[f"prefill_block[{c}]"] = row(
-                self.fused_prefill, "fused_prefill",
-                lambda c=c: prefill_block_tier(spec, lp, pool, c, ffn))
-        return tiers
+        """The kernel choices the code still makes for this engine, from
+        the SAME function the dispatch reads, so a printed tier is the
+        one that ran: ``{"ssm_state_update": {"tier": "pallas" | "xla",
+        "reason": why the kernel stood down, or None}}`` for a model
+        with per-slot state; nothing for the Llama family, whose layers
+        have one implementation (``ops/decode_block.py``)."""
+        if not self._hybrid:
+            return {}
+        from ..ops.ssm import ssm_state_update_tier
+        tier, why = ssm_state_update_tier(
+            self.ssm_state.shape, self.cfg.mamba_n_groups)
+        return {"ssm_state_update": {"tier": tier, "reason": why}}
 
     def aot_stats(self) -> Dict[str, object]:
         """Warm-start observability for bench rows/telemetry: whether

@@ -130,7 +130,7 @@ class BertModel(Layer):
 
 
 class BertForSequenceClassification(Layer):
-    """Fine-tune head — the BERT-base baseline config (BASELINE.json)."""
+    """Fine-tune head — the BERT-base baseline config."""
 
     def __init__(self, cfg: BertConfig, num_classes: int = 2):
         super().__init__()

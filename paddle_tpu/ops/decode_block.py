@@ -1,41 +1,20 @@
-"""Fused decode-step transformer block (ROADMAP item 2, ISSUE 9).
+"""One transformer layer of the serving path: the decode step's and the
+chunk fill's layer bodies, and the closures they are made of.
 
-The serving decode hot loop used to run one token through a CHAIN of
-per-op kernels — norm, three projections, RoPE, paged append, paged
-decode attention, out-projection, norm again, the FFN matmuls — and on
-memory-bound hardware every boundary between them is a round-trip of the
-``[B, H]`` residual stream through HBM.  ClusterFusion-style block
-fusion (PAPERS.md) removes those round-trips by keeping the token's
-residual stream on-chip across the WHOLE layer: the only HBM traffic
-left is the weights (which must stream once regardless) and the paged
-KV pages the attention reads.
-
-:func:`decode_block` is that layer body behind one API, in the same
-three-tier shape as the PR 3 fused CE head:
-
-* **XLA reference tier** (``backend="xla"``): the exact per-op
-  composition the engine ran before — same ops, same order, same
-  dtypes — so fusing on the CPU tier-1 lane is BIT-IDENTICAL to the
-  per-op baseline (pinned by tests/test_decode_block.py and the engine
-  greedy bit-identity test).  This is also the anchor the Pallas tier
-  is value-compared against.
-* **Pallas TPU megakernel** (``backend="pallas"``,
-  ``ops/pallas/decode_block.py``): one kernel per layer holding the
-  residual stream, q/k/v, and the online-softmax state in VMEM scratch;
-  KV pages are DMA-gathered from the pool through the engine's block
-  table.  Page-chunk size comes from the ``ops/pallas/autotune``
-  registry under the ``"decode_block"`` key.
-* **graceful fallback**: geometry outside the kernel's limits (head
-  dim, weights that cannot fit VMEM, MoE FFNs) auto-dispatches to the
-  reference tier; forcing ``backend="pallas"`` raises the typed
-  :class:`DecodeBlockUnsupportedError` instead of failing inside the
-  kernel.
-
-Both serving compiled paths route through this module (the decode step
-via :func:`decode_block`, the chunked prefill fill via
-:func:`prefill_block_xla`), and :func:`make_norm_ffn` is the single
-source for the norm/FFN closures they and the spec-decode draft share —
-the numerics of every compiled serve program come from one file.
+Every compiled serve program reads its layer from this module: the
+decode step and the speculative verify scan (:func:`decode_block`, one
+token per sequence against the paged pool), the bucketed and suffix
+chunk fills (:func:`prefill_block`, ``Ts`` prompt tokens of one
+sequence), the spec-decode draft (:func:`make_norm_ffn`), and the
+hybrid's attention layers (:func:`decode_attention_xla` /
+:func:`prefill_attention_xla`, whose FFN half is the model's own).  So
+there is ONE definition of the norm (:func:`make_norm`), the matmul
+over a stored weight (:func:`make_mm`: full width in either layout, or
+weight-only quantized), the FFN (:func:`make_ffn`) and the two
+attention halves, and the programs cannot drift apart.  Each layer is a
+chain of XLA ops (norm, projections, RoPE, paged append or positional
+scatter, attention, out-projection, FFN) that the compiler fuses inside
+the engine's layer scan.
 """
 
 from __future__ import annotations
@@ -50,27 +29,10 @@ from .paged_kv import (QuantizedKVPool, dequantize_kv, is_quantized_pool,
                        paged_append, paged_decode_attention, quantize_kv,
                        validate_paged_decode_geometry)
 
-__all__ = ["DecodeBlockSpec", "DecodeBlockUnsupportedError",
-           "PrefillBlockUnsupportedError", "decode_attention_xla",
-           "decode_block", "decode_block_spec", "decode_block_tier",
-           "decode_block_unsupported_reason",
-           "hbm_traffic_per_chunk", "hbm_traffic_per_token", "make_norm",
-           "make_ffn", "make_mm", "matmul_stored", "serving_layout",
-           "make_norm_ffn", "prefill_attention_xla", "prefill_block",
-           "prefill_block_xla",
-           "prefill_block_tier", "prefill_block_unsupported_reason",
-           "rotate_half"]
-
-
-class DecodeBlockUnsupportedError(ValueError):
-    """Raised when ``backend="pallas"`` is forced on a geometry the
-    megakernel does not support (auto dispatch falls back silently)."""
-
-
-class PrefillBlockUnsupportedError(ValueError):
-    """Raised when ``backend="pallas"`` is forced on a chunk-fill
-    geometry the prefill megakernel does not support (auto dispatch
-    falls back silently to the reference tier)."""
+__all__ = ["DecodeBlockSpec", "decode_attention_xla", "decode_block",
+           "decode_block_spec", "make_norm", "make_ffn", "make_mm",
+           "matmul_stored", "serving_layout", "make_norm_ffn",
+           "prefill_attention_xla", "prefill_block", "rotate_half"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,8 +60,7 @@ class DecodeBlockSpec:
     group_size: int = -1                 # -1 | 64 | 128 (scale grouping)
     # a stated softmax scale (None: 1/sqrt(head_dim)) and a multiplier
     # on what the attention adds to the residual stream — the granite
-    # family's ``attention_multiplier`` / ``residual_multiplier``;
-    # reference tier only
+    # family's ``attention_multiplier`` / ``residual_multiplier``
     attn_scale: Optional[float] = None
     residual_scale: float = 1.0
 
@@ -148,7 +109,7 @@ def decode_block_spec(cfg, block_size: int,
 
 def rotate_half(x):
     """RoPE rotate-half convention ([-x2, x1]); identical math to the
-    model-side helper so the fused and per-op paths cannot drift."""
+    model-side helper so the serving and training paths cannot drift."""
     d2 = x.shape[-1] // 2
     return jnp.concatenate([-x[..., d2:], x[..., :d2]], axis=-1)
 
@@ -220,14 +181,13 @@ def matmul_stored(lp, name, y):
 
 
 def make_mm(spec: DecodeBlockSpec) -> Callable:
-    """``mm(lp, name, y)`` — the ONE matmul closure of every reference-
-    tier serve program.  Full width: :func:`matmul_stored`.  Weight-only
+    """``mm(lp, name, y)`` — the ONE matmul closure of every serve
+    program.  Full width: :func:`matmul_stored`.  Weight-only
     quantized: dequantizing matmul over the export layout — per-channel
     scales post-multiply the int-code matmul (fp32 accumulation), grouped
     scales dequantize the weight tile first (a per-channel post-multiply
     cannot represent per-K-group scales) — the same split
-    ``ops/pallas/quant_linear._block_scale`` makes, so the Pallas tier
-    mirrors this structure."""
+    ``ops/pallas/quant_linear._block_scale`` makes."""
     if spec.weight_dtype is None:
         return matmul_stored
     wdt, gs = spec.weight_dtype, spec.group_size
@@ -268,11 +228,10 @@ def make_ffn(spec: DecodeBlockSpec) -> Callable:
 
 def make_norm_ffn(cfg, weight_dtype: Optional[str] = None,
                   group_size: int = -1):
-    """The Llama-engine (norm, ffn) closure pair — formerly
-    ``inference.serving._make_rms_ffn``, now housed with the block op so
-    the decode step, the chunk fill, and the spec-decode draft all read
-    one definition.  Handles the MoE FFN variants the fused kernel does
-    not (those route through the reference tier)."""
+    """The Llama-engine (norm, ffn) closure pair, housed with the layer
+    bodies so the decode step, the chunk fill, and the spec-decode draft
+    all read one definition.  For an MoE config the FFN is the grouped
+    expert layer (plus the shared experts where the config has them)."""
     moe = getattr(cfg, "moe_num_experts", 0)
     if moe and weight_dtype is not None:
         raise NotImplementedError(
@@ -301,7 +260,7 @@ def make_norm_ffn(cfg, weight_dtype: Optional[str] = None,
 
 
 # ---------------------------------------------------------------------------
-# tier 1: XLA reference — the exact per-op composition (bit anchor)
+# the layer bodies
 # ---------------------------------------------------------------------------
 def _qkv(y, lp, spec: DecodeBlockSpec, leading, mm=None):
     """Project the normed stream into per-head q/k/v."""
@@ -321,14 +280,28 @@ def _proj(attn, lp, spec: DecodeBlockSpec, mm):
     return mm(lp, "proj_w" if spec.fused_qkv else "o_w", attn)
 
 
-def decode_block_xla(x, lp, pool_k, pool_v, block_table, lengths, cos, sin,
-                     *, spec: DecodeBlockSpec, ffn=None):
-    """Reference tier: one decode token per sequence through the
-    layer's per-op chain.  ``x`` [B, H]; ``cos``/``sin`` [B, D] rows at
-    each sequence's absolute position (ignored when ``spec.rope`` is
-    off); returns ``(x_out, pool_k, pool_v)`` with the new token's KV
-    appended.  This is byte-for-byte the composition the engine's
-    ``_build_step`` inlined before ISSUE 9 — the bit-identity anchor."""
+def decode_block(x, lp, pool_k, pool_v, block_table, lengths, cos, sin, *,
+                 spec: DecodeBlockSpec, ffn=None):
+    """One transformer layer for one decode token per sequence.
+
+    ``x``: [B, H] residual stream; ``lp``: the layer's weight dict
+    (Llama ``q_w/k_w/v_w/o_w/ln*_w/gate_w/up_w/down_w`` or GPT
+    ``qkv_w/qkv_b/proj_w/proj_b/ln*_{w,b}/fc*_{w,b}``); ``pool_k/v``:
+    [NB, BS, Hkv, D] paged KV pools; ``block_table``: [B, MB];
+    ``lengths``: [B] tokens already stored; ``cos``/``sin``: [B, D]
+    RoPE rows at each sequence's absolute position (ignored when
+    ``spec.rope`` is off); ``ffn``: the FFN closure of a model whose
+    FFN is not the spec's dense one (MoE).  Returns
+    ``(x_out [B, H], pool_k, pool_v)`` with the new token's KV appended.
+
+    A row whose CURRENT page (``block_table[b, lengths[b] // BS]``) is
+    unmapped (-1) attends the clamped page-0 pool rows: garbage the
+    engine never exposes (pages are mapped for a request's full budget
+    at admission; inactive slots' outputs are never read).
+    """
+    validate_paged_decode_geometry(
+        (x.shape[0], spec.num_heads, spec.head_dim), pool_k, pool_v,
+        block_table, lengths, op="decode_block")
     norm = make_norm(spec)
     ffn = ffn or make_ffn(spec)
     x, pool_k, pool_v = decode_attention_xla(
@@ -349,7 +322,7 @@ def _residual(x, proj, lp, spec: DecodeBlockSpec):
 
 def decode_attention_xla(x, lp, pool_k, pool_v, block_table, lengths, cos,
                          sin, *, spec: DecodeBlockSpec):
-    """The attention half of :func:`decode_block_xla`: norm, q/k/v,
+    """The attention half of :func:`decode_block`: norm, q/k/v,
     RoPE (``cos``/``sin`` may be None when ``spec.rope`` is off), paged
     append, paged decode attention, out-projection, residual.  A model
     whose FFN half is its own (an expert layer that reports counts)
@@ -371,16 +344,22 @@ def decode_attention_xla(x, lp, pool_k, pool_v, block_table, lengths, cos,
     return _residual(x, proj, lp, spec), pool_k, pool_v
 
 
-def prefill_block_xla(x, lp, pool_k, pool_v, blk, off, bt_row, mask, cos,
-                      sin, *, spec: DecodeBlockSpec, ffn=None,
-                      scale: Optional[float] = None):
-    """The chunk-fill layer body (``Ts`` prompt tokens of ONE sequence
-    against the paged pool): same per-op chain as :func:`decode_block_xla`
-    but with a dense masked attention over the sequence's gathered pages
-    and a positional scatter (``blk``/``off`` [Ts]) instead of the
-    single-token append.  Shares every numeric closure with the decode
-    step so the two compiled paths cannot drift (the pre-ISSUE 9
-    contract of ``_make_rms_ffn``, now op-level)."""
+def prefill_block(x, lp, pool_k, pool_v, blk, off, bt_row, mask, cos,
+                  sin, *, spec: DecodeBlockSpec, ffn=None,
+                  scale: Optional[float] = None):
+    """One transformer layer for ``Ts`` prompt tokens of ONE sequence
+    against the paged pool — the chunk-fill twin of :func:`decode_block`:
+    the same chain, with a dense masked attention over the sequence's
+    gathered pages and a positional scatter instead of the single-token
+    append.  Shares every numeric closure with the decode step so the
+    two compiled paths cannot drift.
+
+    ``x``: [1, Ts, H] residual tile; ``blk``/``off``: [Ts] positional
+    scatter targets; ``bt_row``: [MB] block-table row; ``mask``:
+    [1, 1, Ts, MB*BS] causal mask; ``cos``/``sin``: [Ts, D] RoPE rows at
+    the tile's absolute positions; ``ffn``: as for :func:`decode_block`.
+    Returns ``(x_out [1, Ts, H], pool_k, pool_v)`` with the tile's KV
+    written."""
     norm = make_norm(spec)
     ffn = ffn or make_ffn(spec)
     x, pool_k, pool_v = prefill_attention_xla(
@@ -393,7 +372,7 @@ def prefill_block_xla(x, lp, pool_k, pool_v, blk, off, bt_row, mask, cos,
 def prefill_attention_xla(x, lp, pool_k, pool_v, blk, off, bt_row, mask,
                           cos, sin, *, spec: DecodeBlockSpec,
                           scale: Optional[float] = None):
-    """The attention half of :func:`prefill_block_xla` (the twin of
+    """The attention half of :func:`prefill_block` (the twin of
     :func:`decode_attention_xla`); ``scale`` defaults to the spec's
     ``attn_scale``, then to ``1/sqrt(head_dim)``."""
     from ..models.generation import _dense_masked_attention
@@ -436,253 +415,3 @@ def prefill_attention_xla(x, lp, pool_k, pool_v, blk, off, bt_row, mask,
                                    s).reshape(1, Ts, -1)
     proj = _proj(attn, lp, spec, mm)
     return _residual(x, proj, lp, spec), pool_k, pool_v
-
-
-# ---------------------------------------------------------------------------
-# HBM-traffic model (docs/performance.md + bench.py --config decode_block)
-# ---------------------------------------------------------------------------
-# residual-stream HBM round-trips per layer in the PER-OP decode chain:
-# norm1, qkv-in, rope q/k, attention out, o-proj + residual, norm2,
-# gate/up in, down + residual — each boundary re-reads and re-writes the
-# [B, H]-class activations the fused kernel keeps in VMEM.
-PER_OP_STREAM_ROUND_TRIPS = 8
-
-
-def hbm_traffic_per_token(spec: DecodeBlockSpec, ffn_size: int,
-                          batch: int, itemsize: int) -> dict:
-    """Modelled HBM bytes per decode step per LAYER: both paths stream
-    the weights and the KV pages once (unavoidable); the per-op chain
-    additionally round-trips the residual stream at every fusion
-    boundary, the fused kernel only reads ``x`` once and writes
-    ``x_out`` once.  The CPU tier-1 proxy is compute-bound, so this
-    model — not its wall clock — is the memory-bound-hardware-facing
-    claim (docs/performance.md)."""
-    weights = _layer_weight_stream_bytes(spec, ffn_size, itemsize)
-    stream = batch * spec.hidden * itemsize
-    return {
-        "weights_bytes": weights,
-        "per_op_bytes": weights + PER_OP_STREAM_ROUND_TRIPS * 2 * stream,
-        "fused_bytes": weights + 2 * stream,
-    }
-
-
-def _layer_weight_stream_bytes(spec: DecodeBlockSpec, ffn_size: int,
-                               itemsize: int) -> int:
-    H, Hq, Hkv, D, F = (spec.hidden, spec.num_heads, spec.kv_heads,
-                        spec.head_dim, ffn_size)
-    if spec.fused_qkv:
-        attn_w = H * 3 * H + 3 * H + Hq * D * H + H
-        ffn_w = H * F + F + F * H + H
-    else:
-        attn_w = H * (Hq + 2 * Hkv) * D + Hq * D * H
-        ffn_w = 2 * H * F + F * H
-    norm_w = 2 * H * (2 if spec.bias else 1)
-    return (attn_w + ffn_w + norm_w) * itemsize
-
-
-def hbm_traffic_per_chunk(spec: DecodeBlockSpec, ffn_size: int,
-                          chunk: int, mb: int, itemsize: int,
-                          pool_itemsize: Optional[int] = None,
-                          pages: int = 1) -> dict:
-    """Modelled HBM bytes per LAYER for one ``[chunk]``-token prefill
-    tile: both paths stream the weights, gather the row's committed KV
-    pages, and scatter the chunk's new KV once (unavoidable); the
-    per-op chain additionally round-trips the ``[chunk, H]`` residual
-    stream at every fusion boundary, the fused megakernel keeps it in
-    VMEM for the whole layer.  The double-buffered page DMA changes no
-    byte count — it hides the copy LATENCY of every page-chunk after
-    the first behind the previous chunk's flash-attention fold
-    (``dma_overlap_fraction`` of the gather bytes arrive under
-    compute); docs/performance.md walks the math."""
-    weights = _layer_weight_stream_bytes(spec, ffn_size, itemsize)
-    psz = itemsize if pool_itemsize is None else pool_itemsize
-    stream = chunk * spec.hidden * itemsize
-    page_gather = 2 * mb * spec.block_size * spec.kv_heads \
-        * spec.head_dim * psz
-    kv_scatter = 2 * chunk * spec.kv_heads * spec.head_dim * psz
-    shared = weights + page_gather + kv_scatter
-    nt = max(1, -(-mb // max(1, pages)))
-    return {
-        "weights_bytes": weights,
-        "page_gather_bytes": page_gather,
-        "kv_scatter_bytes": kv_scatter,
-        "per_op_bytes": shared + PER_OP_STREAM_ROUND_TRIPS * 2 * stream,
-        "fused_bytes": shared + 2 * stream,
-        "dma_overlap_fraction": round(1.0 - 1.0 / nt, 4),
-    }
-
-
-# ---------------------------------------------------------------------------
-# dispatch
-# ---------------------------------------------------------------------------
-def _pallas_platform() -> bool:
-    """Same dispatch rule as every other kernel: real accelerator,
-    forced interpret (CPU correctness lane), or forced Mosaic compile."""
-    from ..core.device import on_tpu
-    from ..core.flags import FLAGS
-    return bool(FLAGS.pallas_interpret or FLAGS.pallas_force_compile
-                or on_tpu())
-
-
-def decode_block_unsupported_reason(spec: DecodeBlockSpec, lp,
-                                    pool_k) -> Optional[str]:
-    """None when the Pallas megakernel can run this layer, else a
-    human-readable reason (the typed-fallback signal).  Limits are the
-    kernel's own: the whole layer's weights plus the page-chunk staging
-    buffers must fit the VMEM budget, and head_dim is capped by the
-    attention scratch layout."""
-    from .pallas.decode_block import unsupported_reason
-    return unsupported_reason(spec, lp, pool_k)
-
-
-_NOT_ON_TPU = ("not on a TPU (and neither pallas_interpret nor "
-               "pallas_force_compile is set)")
-_CUSTOM_FFN = "custom FFN closures (MoE) run the reference tier only"
-
-
-_STATED_SCALES = ("a stated softmax scale or residual multiplier runs "
-                  "the reference tier only")
-
-
-def _auto_tier(ffn, kernel_reason: Optional[str], spec=None):
-    """``(tier, reason)`` of a ``backend=None`` dispatch, given the
-    kernel's own unsupported reason (None = it can run this layer)."""
-    reason = _CUSTOM_FFN if ffn is not None else kernel_reason
-    if reason is None and spec is not None and (
-            spec.attn_scale is not None or spec.residual_scale != 1.0):
-        reason = _STATED_SCALES
-    if reason is None and not _pallas_platform():
-        reason = _NOT_ON_TPU
-    return ("pallas" if reason is None else "xla"), reason
-
-
-def decode_block_tier(spec: DecodeBlockSpec, lp, pool_k, ffn=None):
-    """``(tier, reason)``: the tier ``decode_block(backend=None)`` runs
-    this layer on, and — when that is ``"xla"`` — why the Pallas
-    megakernel stood down."""
-    return _auto_tier(ffn, decode_block_unsupported_reason(spec, lp,
-                                                           pool_k), spec)
-
-
-def decode_block(x, lp, pool_k, pool_v, block_table, lengths, cos, sin, *,
-                 spec: DecodeBlockSpec, ffn=None,
-                 backend: Optional[str] = None):
-    """One fused transformer layer for one decode token per sequence.
-
-    ``x``: [B, H] residual stream; ``lp``: the layer's weight dict
-    (Llama ``q_w/k_w/v_w/o_w/ln*_w/gate_w/up_w/down_w`` or GPT
-    ``qkv_w/qkv_b/proj_w/proj_b/ln*_{w,b}/fc*_{w,b}``); ``pool_k/v``:
-    [NB, BS, Hkv, D] paged KV pools; ``block_table``: [B, MB];
-    ``lengths``: [B] tokens already stored; ``cos``/``sin``: [B, D]
-    RoPE rows at each sequence's absolute position.  Returns
-    ``(x_out [B, H], pool_k, pool_v)``.
-
-    ``backend``: ``"xla"`` = per-op reference tier (bit-identical to
-    the pre-fusion engine), ``"pallas"`` = the VMEM-resident megakernel
-    (raises :class:`DecodeBlockUnsupportedError` outside its limits),
-    ``None`` = pallas on TPU when the geometry fits, else the reference
-    tier.  ``ffn``: optional FFN closure override (MoE) — reference
-    tier only.
-
-    Contract caveat (both tiers, engine-invisible): a row whose CURRENT
-    page (``block_table[b, lengths[b] // BS]``) is unmapped (-1)
-    produces tier-dependent garbage — the per-op chain attends the
-    clamped page-0 pool rows, the kernel folds the new token from VMEM.
-    The engine never exposes such rows (pages are mapped for a
-    request's full budget at admission; inactive slots' outputs are
-    never read), so engine/stream/spec outputs stay bit-identical
-    across tiers — the tier-1 pins.  Tier parity is only claimed for
-    rows with a mapped current page.
-    """
-    validate_paged_decode_geometry(
-        (x.shape[0], spec.num_heads, spec.head_dim), pool_k, pool_v,
-        block_table, lengths, op="decode_block")
-    if backend is None:
-        backend = decode_block_tier(spec, lp, pool_k, ffn)[0]
-    if backend == "pallas":
-        if ffn is not None:
-            raise DecodeBlockUnsupportedError(
-                f"decode_block: {_CUSTOM_FFN}")
-        reason = _auto_tier(None, decode_block_unsupported_reason(
-            spec, lp, pool_k), spec)[1]
-        if reason not in (None, _NOT_ON_TPU):
-            raise DecodeBlockUnsupportedError(f"decode_block: {reason}")
-        from .pallas.decode_block import decode_block_pallas
-        return decode_block_pallas(x, lp, pool_k, pool_v, block_table,
-                                   lengths, cos, sin, spec=spec)
-    if backend != "xla":
-        raise ValueError(f"unknown backend {backend!r}")
-    return decode_block_xla(x, lp, pool_k, pool_v, block_table, lengths,
-                            cos, sin, spec=spec, ffn=ffn)
-
-
-def prefill_block_unsupported_reason(spec: DecodeBlockSpec, lp, pool_k,
-                                     chunk: int) -> Optional[str]:
-    """None when the prefill megakernel can run this layer at this
-    chunk length, else a human-readable reason (the typed-fallback
-    signal).  Limits are the kernel's own: the whole layer's weights
-    plus the double-buffered page staging plus the chunk-tile scratch
-    must fit the VMEM budget, and head_dim is capped by the attention
-    scratch layout — all read from the shared cost model."""
-    from .pallas.prefill_block import unsupported_reason
-    return unsupported_reason(spec, lp, pool_k, chunk)
-
-
-def prefill_block_tier(spec: DecodeBlockSpec, lp, pool_k, chunk: int,
-                       ffn=None):
-    """``(tier, reason)`` for ``prefill_block(backend=None)`` called
-    with ``start=`` at this chunk length — the twin of
-    :func:`decode_block_tier`."""
-    return _auto_tier(ffn, prefill_block_unsupported_reason(
-        spec, lp, pool_k, chunk), spec)
-
-
-def prefill_block(x, lp, pool_k, pool_v, blk, off, bt_row, mask, cos,
-                  sin, *, spec: DecodeBlockSpec, start=None, ffn=None,
-                  scale: Optional[float] = None,
-                  backend: Optional[str] = None):
-    """One fused transformer layer for ``Ts`` prompt tokens of ONE
-    sequence against the paged pool — the chunked-prefill twin of
-    :func:`decode_block`, same three-tier dispatch.
-
-    ``x``: [1, Ts, H] residual tile; ``blk``/``off``: [Ts] positional
-    scatter targets; ``bt_row``: [MB] block-table row; ``mask``:
-    [1, 1, Ts, MB*BS] causal mask (reference tier); ``cos``/``sin``:
-    [Ts, D] RoPE rows at the tile's absolute positions; ``start``: the
-    committed-prefix length (``pos = start + arange(Ts)``) — required
-    by the Pallas tier, which derives the causal/committed masking from
-    it instead of the dense ``mask``.  Returns
-    ``(x_out [1, Ts, H], pool_k, pool_v)`` with the tile's KV written.
-
-    ``backend``: ``"xla"`` = the per-op reference chain
-    (:func:`prefill_block_xla`, bit-identical to the pre-fusion
-    engine), ``"pallas"`` = the VMEM-resident megakernel (raises
-    :class:`PrefillBlockUnsupportedError` outside its limits),
-    ``None`` = pallas on TPU when ``start`` is given and the geometry
-    fits, else the reference tier.  ``ffn``: optional FFN closure
-    override (MoE) — reference tier only."""
-    if backend is None:
-        backend = prefill_block_tier(spec, lp, pool_k, x.shape[1],
-                                     ffn)[0] if start is not None \
-            else "xla"
-    if backend == "pallas":
-        if ffn is not None:
-            raise PrefillBlockUnsupportedError(
-                f"prefill_block: {_CUSTOM_FFN}")
-        if start is None:
-            raise PrefillBlockUnsupportedError(
-                "prefill_block: the Pallas tier needs the committed-"
-                "prefix length (start=)")
-        reason = _auto_tier(None, prefill_block_unsupported_reason(
-            spec, lp, pool_k, x.shape[1]), spec)[1]
-        if reason not in (None, _NOT_ON_TPU):
-            raise PrefillBlockUnsupportedError(f"prefill_block: {reason}")
-        from .pallas.prefill_block import prefill_block_pallas
-        return prefill_block_pallas(x, lp, pool_k, pool_v, blk, off,
-                                    bt_row, mask, cos, sin, spec=spec,
-                                    start=start, scale=scale)
-    if backend != "xla":
-        raise ValueError(f"unknown backend {backend!r}")
-    return prefill_block_xla(x, lp, pool_k, pool_v, blk, off, bt_row,
-                             mask, cos, sin, spec=spec, ffn=ffn,
-                             scale=scale)

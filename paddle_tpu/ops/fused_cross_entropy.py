@@ -320,9 +320,7 @@ class LinearCEUnsupportedError(ValueError):
 def pallas_unsupported_reason(x, w, axis_name: Optional[str] = None
                               ) -> Optional[str]:
     """None when the Pallas tier can serve this head, else the reason
-    (the typed-fallback signal, same shape as
-    ``ops.decode_block.decode_block_unsupported_reason``): the kernel is
-    dense-only, and its backward must fit VMEM at ``x``'s hidden size
+    (the typed-fallback signal): the kernel is dense-only, and its backward must fit VMEM at ``x``'s hidden size
     (``analysis/kernel/cost.linear_ce_unsupported_reason``)."""
     if axis_name is not None:
         return ("the Pallas tier is dense-only; the vocab-parallel tier "

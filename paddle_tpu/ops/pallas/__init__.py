@@ -12,7 +12,4 @@ from .norms import (  # noqa: F401
 from .linear_ce import (  # noqa: F401
     linear_cross_entropy_pallas, tune_linear_ce,
 )
-from .decode_block import (  # noqa: F401
-    decode_block_pallas, tune_decode_block,
-)
 from .rope import fused_rope, rope_cos_sin  # noqa: F401

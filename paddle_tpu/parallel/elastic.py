@@ -14,8 +14,8 @@ single-controller SPMD runtime actually needs:
    COMPLETES but blows the step deadline ``deadline_strikes`` times in
    a row is treated the same way (a wedging worker is a failing
    worker).  The deadline check is post-hoc — a truly hung collective
-   needs an out-of-process watchdog (bench.py's pattern); in-process we
-   can only observe elapsed time between dispatches.
+   needs an out-of-process watchdog; in-process we can only observe
+   elapsed time between dispatches.
 
 2. **Elastic reshape with state carryover** — on worker loss the mesh
    is rebuilt over the survivors at the nearest valid topology: the

@@ -11,7 +11,7 @@ batching engine serves raw param PYTREES.  This module is the bridge:
 * :func:`quantize_params_for_serving` — PTQ-export a zoo param tree to
   the ``<name>__q`` / ``<name>__s`` leaf convention that
   ``models.generation.build_llama_decoder(quant=...)`` and the quantized
-  ``ops/decode_block`` tiers consume.  Scales are per-output-channel (or
+  ``ops/decode_block`` matmul (``make_mm``) consume.  Scales are per-output-channel (or
   per (input-group, channel)) fp32 absmax — optionally the OBSERVER-
   calibrated per-channel absmax (:func:`calibrate_weight_thresholds`,
   the same ``PerChannelAbsMaxObserver`` statistic the layer-graph deploy

@@ -142,129 +142,8 @@ def test_rope_fwd_bwd(chip):
 
 
 # ---------------------------------------------------------------------
-# serving path: block megakernels, decode attention, int8 matmul
+# serving path: the engine's programs, decode attention, int8 matmul
 # ---------------------------------------------------------------------
-def _block_case(chip):
-    """The widest bf16 SwiGLU layer the megakernels' own cost model
-    admits (weights must fit 0.75 x 16 MiB of VMEM): H 512, 4 x 128
-    heads, F 1408."""
-    from paddle_tpu.models.llama import LlamaConfig, init_block_params
-    from paddle_tpu.ops.decode_block import decode_block_spec
-    cfg = LlamaConfig(vocab_size=32000, hidden_size=512,
-                      intermediate_size=1408, num_layers=2, num_heads=4,
-                      max_position_embeddings=2048, dtype="bfloat16")
-    lp = on(chip, jax.eval_shape(
-        lambda: init_block_params(cfg, jax.random.key(0))))
-    pool = chip((256, 16, cfg.kv_heads, cfg.head_dim))
-    return cfg, decode_block_spec(cfg, 16), lp, pool
-
-
-def _laid_out(chip, lp, layout):
-    """``lp`` as the tree stores it, or as a serving engine holds it
-    (q/k/v ``[N, K]``: the kernels contract them as they lie)."""
-    if layout == "tree":
-        return lp
-    from paddle_tpu.ops.decode_block import serving_layout
-    return on(chip, jax.eval_shape(serving_layout, lp))
-
-
-LAYOUTS = ("tree", "serving")
-
-
-@pytest.mark.parametrize("layout", LAYOUTS)
-def test_decode_block_megakernel(chip, layout):
-    """Refused by the lowering until PR 22: a (1, H) row block of a
-    [B, H] array (now a squeezed [B, 1, H] block) and a scatter
-    (``.at[].set``, now a concatenate)."""
-    from paddle_tpu.ops.decode_block import (
-        decode_block, decode_block_unsupported_reason)
-    cfg, spec, lp, pool = _block_case(chip)
-    lp = _laid_out(chip, lp, layout)
-    assert decode_block_unsupported_reason(spec, lp, pool) is None
-    B, MB, D = 8, 128, cfg.head_dim
-    compile_kernel(
-        lambda x, lp, pk, pv, bt, ln, c, s: decode_block(
-            x, lp, pk, pv, bt, ln, c, s, spec=spec, backend="pallas"),
-        chip((B, cfg.hidden_size)), lp, pool, pool,
-        chip((B, MB), jnp.int32), chip((B,), jnp.int32), chip((B, D)),
-        chip((B, D)))
-
-
-@pytest.mark.parametrize("layout", LAYOUTS)
-def test_prefill_block_megakernel(chip, layout):
-    from paddle_tpu.ops.decode_block import (
-        prefill_block, prefill_block_unsupported_reason)
-    cfg, spec, lp, pool = _block_case(chip)
-    lp = _laid_out(chip, lp, layout)
-    Ts, MB, D = 64, 128, cfg.head_dim
-    assert prefill_block_unsupported_reason(spec, lp, pool, Ts) is None
-    compile_kernel(
-        lambda x, lp, pk, pv, blk, off, bt, m, c, s, st: prefill_block(
-            x, lp, pk, pv, blk, off, bt, m, c, s, spec=spec, start=st,
-            backend="pallas"),
-        chip((1, Ts, cfg.hidden_size)), lp, pool, pool,
-        chip((Ts,), jnp.int32), chip((Ts,), jnp.int32),
-        chip((MB,), jnp.int32), chip((1, 1, Ts, MB * 16), jnp.bool_),
-        chip((Ts, D)), chip((Ts, D)), chip((), jnp.int32))
-
-
-def test_block_megakernels_refuse_7b_width_with_a_reason(chip):
-    """At llama_7b width a layer's weights are 386 MB against a 12 MB
-    VMEM budget: both dispatches stand down to the XLA tier with a
-    reason that names the cause, and forcing the kernel raises it."""
-    from paddle_tpu.models.llama import init_block_params, llama_7b
-    from paddle_tpu.ops.decode_block import (
-        DecodeBlockUnsupportedError, decode_block, decode_block_spec,
-        decode_block_tier, prefill_block_tier)
-    cfg = llama_7b(dtype="bfloat16")
-    spec = decode_block_spec(cfg, 16)
-    lp = on(chip, jax.eval_shape(
-        lambda: init_block_params(cfg, jax.random.key(0))))
-    pool = chip((512, 16, cfg.kv_heads, cfg.head_dim))
-    for tier, reason in (decode_block_tier(spec, lp, pool),
-                         prefill_block_tier(spec, lp, pool, 128)):
-        assert tier == "xla" and "VMEM" in reason and "budget" in reason
-    B = 4
-    with pytest.raises(DecodeBlockUnsupportedError, match="VMEM"):
-        jax.eval_shape(
-            lambda *a: decode_block(*a, spec=spec, backend="pallas"),
-            chip((B, cfg.hidden_size)), lp, pool, pool,
-            chip((B, 256), jnp.int32), chip((B,), jnp.int32),
-            chip((B, 128)), chip((B, 128)))
-
-
-def test_block_megakernels_refuse_narrow_heads_with_a_reason(chip):
-    """Mosaic lowers the kernels' lanes-to-heads shape cast only for a
-    head_dim that is a multiple of 128 (on the chip, head_dim 16 died
-    in "infer-vector-layout: unsupported shape cast"): narrower heads
-    take the XLA tier with a reason, as the compiler confirms."""
-    from paddle_tpu.models.llama import LlamaConfig, init_block_params
-    from paddle_tpu.ops.decode_block import (decode_block_spec,
-                                             decode_block_tier,
-                                             prefill_block_tier)
-    cfg = LlamaConfig(vocab_size=256, hidden_size=256,
-                      intermediate_size=512, num_layers=2, num_heads=4,
-                      max_position_embeddings=2048, dtype="bfloat16")
-    assert cfg.head_dim == 64
-    spec = decode_block_spec(cfg, 16)
-    lp = on(chip, jax.eval_shape(
-        lambda: init_block_params(cfg, jax.random.key(0))))
-    pool = chip((64, 16, cfg.kv_heads, cfg.head_dim))
-    for tier, reason in (decode_block_tier(spec, lp, pool),
-                         prefill_block_tier(spec, lp, pool, 32)):
-        assert tier == "xla" and "head_dim 64" in reason \
-            and "128-lane" in reason
-    # the limit is the compiler's, not ours: the kernel body is refused
-    from jax._src.pallas.mosaic.error_handling import MosaicError
-    from paddle_tpu.ops.pallas.decode_block import _call
-    B = 4
-    with pytest.raises(MosaicError, match="unsupported shape cast"):
-        jax.jit(lambda *a: _call(*a, spec=spec, pages=8)).lower(
-            chip((B, cfg.hidden_size)), lp, pool, pool,
-            chip((B, 16), jnp.int32), chip((B,), jnp.int32),
-            chip((B, 64)), chip((B, 64))).compile()
-
-
 # the Mistral serve cells' engine (benchmark/configs: 16 layers of the
 # published widths, 32 slots, 1024 pages of 16, a table of 256)
 _SERVE = dict(layers=16, slots=32, pages=1024, page=16, table=256)
@@ -311,7 +190,6 @@ def test_engine_programs_move_no_byte_twice(chip, program, temp_mib):
     eng = object.__new__(ContinuousBatchingEngine)
     eng.cfg, eng.BS, eng._hybrid, eng.quant_config = \
         cfg, z["page"], False, None
-    eng.fused_decode_block = eng.fused_prefill = True
     pool = chip((z["layers"], z["pages"], z["page"], cfg.kv_heads,
                  cfg.head_dim))
     i32 = jnp.int32

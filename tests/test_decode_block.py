@@ -1,21 +1,18 @@
-"""Fused decode-step block op (ISSUE 9): value parity vs the per-op
-composition across GPT and Llama block variants, the Pallas interpret
-tier, autotune cache roundtrip, geometry fallback, engine greedy
-bit-identity with fusion on/off (engine + ServingFrontend stream,
-spec-decode enabled and disabled), and the typed paged-KV geometry
-errors the fallback tier keys off."""
+"""The serving layer bodies (``ops/decode_block.py``): decode_block and
+prefill_block bit-identical to the per-op composition written out here
+across GPT and Llama block variants, the chain against the train step's
+``block_apply`` on the same weights, speculative decoding on against
+off, the typed paged-KV geometry errors, and the engine's layer scan
+over whole pools."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.core.flags import FLAGS, set_flags
-from paddle_tpu.ops.decode_block import (DecodeBlockSpec,
-                                         DecodeBlockUnsupportedError,
-                                         decode_block, decode_block_spec,
-                                         decode_block_unsupported_reason,
-                                         make_norm_ffn)
+from paddle_tpu.ops.decode_block import (DecodeBlockSpec, decode_block,
+                                         decode_block_spec, make_norm_ffn,
+                                         prefill_block, serving_layout)
 from paddle_tpu.ops.paged_kv import (PagedKVGeometryError, paged_append,
                                      paged_decode_attention)
 
@@ -144,114 +141,177 @@ DTYPES = (np.float32, jnp.bfloat16)
 
 
 # ---------------------------------------------------------------------------
-# tier parity
+# the layer bodies against the per-op composition
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("kind", VARIANTS)
 @pytest.mark.parametrize("dtype", DTYPES, ids=("fp32", "bf16"))
-def test_xla_tier_bit_identical_to_per_op(kind, dtype):
+def test_decode_block_bit_identical_to_per_op(kind, dtype):
     spec, lp, x, pk, pv, bt, ln, cos, sin = _variant(kind, dtype)
     ref = _per_op_reference(x, lp, pk, pv, bt, ln, cos, sin, spec)
-    got = decode_block(x, lp, pk, pv, bt, ln, cos, sin, spec=spec,
-                       backend="xla")
+    got = decode_block(x, lp, pk, pv, bt, ln, cos, sin, spec=spec)
     for r, g in zip(ref, got):
         np.testing.assert_array_equal(np.asarray(r, np.float32),
                                       np.asarray(g, np.float32))
 
 
-@pytest.mark.parametrize("kind", VARIANTS)
+def _prefill_case(kind, dtype, Ts=7, start=5, MB=6, NB=16):
+    """One sequence's chunk fill: ``Ts`` prompt tokens at absolute
+    positions ``start + [0, Ts)`` against a pool holding ``start``
+    committed tokens in the sequence's block-table row (plus unrelated
+    junk everywhere else, which the fill must ignore)."""
+    spec, lp, *_ = _variant(kind, dtype)
+    H, Hkv, D, BS = spec.hidden, spec.kv_heads, spec.head_dim, \
+        spec.block_size
+    pool_k = _w(NB, BS, Hkv, D, dtype=dtype)
+    pool_v = _w(NB, BS, Hkv, D, dtype=dtype)
+    bt_row = np.full((MB,), -1, np.int32)
+    nb = -(-(start + Ts) // BS)
+    bt_row[:nb] = [2, 5, 7, 9, 11, 13][:nb]
+    x = _w(1, Ts, H, dtype=dtype, scale=0.5)
+    cos = _w(Ts, D, dtype=dtype, scale=1.0) if spec.rope else None
+    sin = _w(Ts, D, dtype=dtype, scale=1.0) if spec.rope else None
+    return (spec, lp, x, pool_k, pool_v,
+            *_fill_targets(jnp.asarray(bt_row), start, Ts, BS), cos, sin)
+
+
+def _fill_targets(bt_row, start, Ts, BS):
+    """``(blk, off, bt_row, mask)`` of a chunk fill, as the engine's
+    ``_build_chunk_fill`` derives them from the table row."""
+    pos = start + jnp.arange(Ts)
+    blk = jnp.take(jnp.maximum(bt_row, 0), pos // BS)
+    jpos = jnp.arange(bt_row.shape[0] * BS)[None, None, None, :]
+    return blk, pos % BS, bt_row, jpos <= pos[None, None, :, None]
+
+
+def _prefill_per_op_reference(x, lp, pool_k, pool_v, blk, off, bt_row, mask, cos,
+                              sin, spec):
+    """The per-op chunk-fill chain, written out independently of the op
+    module — what prefill_block must reproduce bit-for-bit."""
+    _, Ts, _ = x.shape
+    Hq, Hkv, D = spec.num_heads, spec.kv_heads, spec.head_dim
+
+    def norm(x_, w, b=None):
+        if spec.norm == "rms":
+            ms = jnp.mean(jnp.square(x_.astype(jnp.float32)), -1,
+                          keepdims=True)
+            return (x_ * jax.lax.rsqrt(ms + spec.eps).astype(x_.dtype)) * w
+        x32 = x_.astype(jnp.float32)
+        mu = jnp.mean(x32, -1, keepdims=True)
+        var = jnp.var(x32, -1, keepdims=True)
+        return ((x32 - mu) * jax.lax.rsqrt(var + spec.eps)
+                ).astype(x_.dtype) * w + b
+
+    y = norm(x, lp["ln1_w"], lp.get("ln1_b"))
+    if spec.fused_qkv:
+        qkv = (y @ lp["qkv_w"] + lp["qkv_b"]).reshape(1, Ts, Hq, 3 * D)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+    else:
+        q = (y @ lp["q_w"]).reshape(1, Ts, Hq, D)
+        k = (y @ lp["k_w"]).reshape(1, Ts, Hkv, D)
+        v = (y @ lp["v_w"]).reshape(1, Ts, Hkv, D)
+    if spec.rope:
+        def rot(t):
+            d2 = t.shape[-1] // 2
+            return jnp.concatenate([-t[..., d2:], t[..., :d2]], -1)
+
+        q = q * cos[None, :, None, :] + rot(q) * sin[None, :, None, :]
+        k = k * cos[None, :, None, :] + rot(k) * sin[None, :, None, :]
+    pool_k = pool_k.at[blk, off].set(k[0])
+    pool_v = pool_v.at[blk, off].set(v[0])
+    k_all = jnp.take(pool_k, jnp.maximum(bt_row, 0),
+                     axis=0).reshape(1, -1, Hkv, D)
+    v_all = jnp.take(pool_v, jnp.maximum(bt_row, 0),
+                     axis=0).reshape(1, -1, Hkv, D)
+    rep = Hq // Hkv
+    if rep > 1:
+        k_all = jnp.repeat(k_all, rep, axis=2)
+        v_all = jnp.repeat(v_all, rep, axis=2)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_all) * (1.0 / D ** 0.5)
+    logits = jnp.where(mask, logits.astype(jnp.float32), -1e30)
+    p = jax.nn.softmax(logits, -1).astype(q.dtype)
+    attn = jnp.einsum("bhqk,bkhd->bqhd", p, v_all).reshape(1, Ts, -1)
+    proj = attn @ (lp["proj_w"] if spec.fused_qkv else lp["o_w"])
+    x = x + (proj + lp["proj_b"] if spec.bias else proj)
+    y2 = norm(x, lp["ln2_w"], lp.get("ln2_b"))
+    if spec.activation == "swiglu":
+        f = (jax.nn.silu(y2 @ lp["gate_w"]) * (y2 @ lp["up_w"])) \
+            @ lp["down_w"]
+    else:
+        f = jax.nn.gelu(y2 @ lp["fc1_w"] + lp["fc1_b"],
+                        approximate=True) @ lp["fc2_w"] + lp["fc2_b"]
+    return x + f, pool_k, pool_v
+
+
+@pytest.mark.parametrize("kind", ("llama_gqa", "gpt"))
 @pytest.mark.parametrize("dtype", DTYPES, ids=("fp32", "bf16"))
-def test_pallas_tier_value_parity(kind, dtype):
-    spec, lp, x, pk, pv, bt, ln, cos, sin = _variant(kind, dtype)
-    ref = _per_op_reference(x, lp, pk, pv, bt, ln, cos, sin, spec)
-    old = FLAGS.pallas_interpret
-    set_flags({"pallas_interpret": True})
-    try:
-        got = decode_block(x, lp, pk, pv, bt, ln, cos, sin, spec=spec,
-                           backend="pallas")
-        # the traced path the engine's scan takes
-        jit_got = jax.jit(lambda *a: decode_block(
-            *a, spec=spec, backend="pallas"))(x, lp, pk, pv, bt, ln,
-                                              cos, sin)
-    finally:
-        set_flags({"pallas_interpret": old})
-    tol = dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 \
-        else dict(rtol=1e-5, atol=1e-5)
-    for r, g, jg in zip(ref, got, jit_got):
-        np.testing.assert_allclose(np.asarray(g, np.float32),
-                                   np.asarray(r, np.float32), **tol)
-        np.testing.assert_allclose(np.asarray(jg, np.float32),
-                                   np.asarray(r, np.float32), **tol)
-
-
-def test_auto_dispatch_off_tpu_is_reference_tier():
-    """With no TPU and no interpret flag, auto dispatch must take the
-    per-op tier — the CPU tier-1 bit-identity story."""
-    spec, lp, x, pk, pv, bt, ln, cos, sin = _variant("llama_gqa",
-                                                     np.float32)
-    ref = decode_block(x, lp, pk, pv, bt, ln, cos, sin, spec=spec,
-                       backend="xla")
-    got = decode_block(x, lp, pk, pv, bt, ln, cos, sin, spec=spec)
+def test_prefill_block_bit_identical_to_per_op(kind, dtype):
+    spec, lp, x, pk, pv, blk, off, bt, mask, cos, sin = _prefill_case(
+        kind, dtype)
+    ref = _prefill_per_op_reference(x, lp, pk, pv, blk, off, bt, mask,
+                                    cos, sin, spec)
+    got = prefill_block(x, lp, pk, pv, blk, off, bt, mask, cos, sin,
+                        spec=spec)
     for r, g in zip(ref, got):
-        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
+        np.testing.assert_array_equal(np.asarray(r, np.float32),
+                                      np.asarray(g, np.float32))
 
 
 # ---------------------------------------------------------------------------
-# geometry limits / typed fallback
+# the chain against the train step's block
 # ---------------------------------------------------------------------------
-def test_unsupported_head_dim_reason_and_raise():
-    H, Hq, Hkv, D, F = 16, 2, 2, 512, 24     # D past the kernel cap
-    spec = DecodeBlockSpec(hidden=H, num_heads=Hq, kv_heads=Hkv,
-                           head_dim=D, block_size=4, norm="rms",
-                           activation="swiglu", eps=1e-5, rope=True)
-    lp = _llama_layer(H, Hq, Hkv, D, F, np.float32)
-    pk, pv, bt, lengths = _geometry(Hkv=Hkv, D=D)
-    x = _w(3, H)
-    cos, sin = _w(3, D), _w(3, D)
-    reason = decode_block_unsupported_reason(spec, lp, pk)
-    assert reason is not None and "head_dim" in reason
-    with pytest.raises(DecodeBlockUnsupportedError, match="head_dim"):
-        decode_block(x, lp, pk, pv, bt, lengths, cos, sin, spec=spec,
-                     backend="pallas")
-    # auto dispatch silently takes the reference tier instead
-    ref = decode_block(x, lp, pk, pv, bt, lengths, cos, sin, spec=spec,
-                       backend="xla")
-    old = FLAGS.pallas_interpret
-    set_flags({"pallas_interpret": True})
-    try:
-        got = decode_block(x, lp, pk, pv, bt, lengths, cos, sin,
-                           spec=spec)
-    finally:
-        set_flags({"pallas_interpret": old})
-    np.testing.assert_array_equal(np.asarray(ref[0]), np.asarray(got[0]))
+# (kind, q/k/v layout, (start, valid)): a prompt's first ``start`` tokens
+# committed, a chunk of ``valid`` filled after them (a cold fill, a
+# single-token tail, a chunk across several pages), one token decoded
+_CHAIN_CASES = [("llama_gqa", "kn", g) for g in ((0, 8), (3, 1), (11, 9))] \
+    + [("llama_mha_tied", "kn", (11, 9)), ("llama_gqa", "nk", (11, 9)),
+       ("llama_mha_tied", "nk", (11, 9))]
 
 
-def test_unsupported_vmem_budget(monkeypatch):
-    from paddle_tpu.ops.pallas import decode_block as pdb
-    spec, lp, x, pk, pv, bt, ln, cos, sin = _variant("llama_gqa",
-                                                     np.float32)
-    assert decode_block_unsupported_reason(spec, lp, pk) is None
-    monkeypatch.setattr(pdb, "VMEM_BUDGET_BYTES", 128)
-    reason = decode_block_unsupported_reason(spec, lp, pk)
-    assert reason is not None and "VMEM" in reason
-    # auto dispatch silently falls back to the reference tier
-    old = FLAGS.pallas_interpret
-    set_flags({"pallas_interpret": True})
-    try:
-        got = decode_block(x, lp, pk, pv, bt, ln, cos, sin, spec=spec)
-    finally:
-        set_flags({"pallas_interpret": old})
-    ref = decode_block(x, lp, pk, pv, bt, ln, cos, sin, spec=spec,
-                       backend="xla")
-    for r, g in zip(ref, got):
-        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
+@pytest.mark.parametrize("dtype,tol", ((np.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)),
+                         ids=("fp32", "bf16"))
+@pytest.mark.parametrize(
+    "kind,layout,geometry", _CHAIN_CASES,
+    ids=[f"{k}-{lay}-{s}+{v}" for k, lay, (s, v) in _CHAIN_CASES])
+def test_chain_matches_train_block(kind, layout, geometry, dtype, tol):
+    """What serving computes for a sequence, position by position
+    through the paged pool, is what the train step's ``block_apply``
+    computes for it at once: the same weights (in the tree's ``[K, N]``
+    q/k/v or the engine's ``[N, K]``), the same tokens, the same RoPE
+    tables."""
+    from paddle_tpu.models.llama import (LlamaConfig, _rope_cos_sin,
+                                         block_apply)
+    start, valid = geometry
+    S, BS = start + valid + 1, 4
+    spec, lp, *_ = _variant(kind, dtype)
+    cfg = LlamaConfig(hidden_size=spec.hidden, num_heads=spec.num_heads,
+                      num_kv_heads=spec.kv_heads, intermediate_size=48,
+                      rms_norm_eps=spec.eps)
+    cos, sin = _rope_cos_sin(S, spec.head_dim, cfg.rope_theta,
+                             jnp.dtype(dtype))
+    x = _w(1, S, spec.hidden, dtype=dtype, scale=0.5)
+    want = np.asarray(block_apply(lp, x, cfg, cos, sin), np.float32)
 
-
-def test_moe_ffn_override_forces_reference_tier():
-    spec, lp, x, pk, pv, bt, ln, cos, sin = _variant("llama_gqa",
-                                                     np.float32)
-    with pytest.raises(DecodeBlockUnsupportedError, match="FFN"):
-        decode_block(x, lp, pk, pv, bt, ln, cos, sin, spec=spec,
-                     ffn=lambda lp_, y: y, backend="pallas")
+    served = serving_layout(lp) if layout == "nk" else lp
+    assert ("q_wt" in served) == (layout == "nk")
+    pk = _w(16, BS, spec.kv_heads, spec.head_dim, dtype=dtype)
+    pv = _w(16, BS, spec.kv_heads, spec.head_dim, dtype=dtype)
+    bt_row = jnp.asarray([2, 5, 7, 9, 11, 13], jnp.int32)
+    got = []
+    for lo, n in ((0, start), (start, valid)):
+        if n:
+            out, pk, pv = prefill_block(
+                x[:, lo:lo + n], served, pk, pv,
+                *_fill_targets(bt_row, lo, n, BS), cos[lo:lo + n],
+                sin[lo:lo + n], spec=spec)
+            got.append(out)
+    out, pk, pv = decode_block(
+        x[:, S - 1], served, pk, pv, bt_row[None],
+        jnp.asarray([S - 1], jnp.int32), cos[S - 1:], sin[S - 1:],
+        spec=spec)
+    got = np.concatenate([np.asarray(g, np.float32) for g in got]
+                         + [np.asarray(out, np.float32)[None]], axis=1)
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
 
 
 def test_paged_geometry_typed_errors():
@@ -274,46 +334,7 @@ def test_paged_geometry_typed_errors():
 
 
 # ---------------------------------------------------------------------------
-# autotune
-# ---------------------------------------------------------------------------
-def test_autotune_cache_roundtrip(tmp_path):
-    from paddle_tpu.ops.pallas import autotune
-    from paddle_tpu.ops.pallas.decode_block import tune_decode_block
-    spec, lp, x, pk, pv, bt, ln, cos, sin = _variant("llama_gqa",
-                                                     np.float32)
-    path = tmp_path / "at.json"
-    old = FLAGS.pallas_interpret
-    set_flags({"use_autotune": True, "autotune_cache_file": str(path),
-               "pallas_interpret": True})
-    try:
-        autotune.clear_cache()
-        out = tune_decode_block(x, lp, pk, pv, bt, ln, cos, sin,
-                                spec=spec)
-        key = (spec.hidden, spec.num_heads, spec.kv_heads, spec.head_dim,
-               spec.block_size, bt.shape[1], spec.activation,
-               str(pk.dtype), None, -1)   # unquantized: weight_dtype/group
-        won = autotune.lookup("decode_block", key, None)
-        assert won is not None and int(won) >= 1
-        # the winner persisted to disk for later processes
-        import json
-        with open(path) as f:
-            on_disk = json.load(f)
-        assert any(k.startswith("decode_block|") for k in on_disk), on_disk
-        assert int(won) in [int(v) for k, v in on_disk.items()
-                            if k.startswith("decode_block|")]
-        ref = decode_block(x, lp, pk, pv, bt, ln, cos, sin, spec=spec,
-                           backend="xla")
-        np.testing.assert_allclose(np.asarray(out[0]),
-                                   np.asarray(ref[0]), rtol=1e-5,
-                                   atol=1e-5)
-    finally:
-        set_flags({"use_autotune": False, "autotune_cache_file": "",
-                   "pallas_interpret": old})
-        autotune.clear_cache()
-
-
-# ---------------------------------------------------------------------------
-# engine / serve-path bit-identity (the acceptance pins)
+# engine / serve-path bit-identity
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
 def tiny_serving():
@@ -330,7 +351,7 @@ def tiny_serving():
     return cfg, params, prompts
 
 
-def _engine(cfg, params, fused, spec=False, **kw):
+def _engine(cfg, params, spec=False, **kw):
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
     spec_config = None
     if spec:
@@ -339,74 +360,24 @@ def _engine(cfg, params, fused, spec=False, **kw):
                                        k=2, window=8)
     return ContinuousBatchingEngine(
         cfg, params, max_batch=2, block_size=8, num_blocks=64,
-        fused_decode_block=fused, spec_config=spec_config, **kw)
+        spec_config=spec_config, **kw)
 
 
-def _drain(eng, prompts, sampled=False):
+def _drain(eng, prompts):
     for i, p in enumerate(prompts):
-        eng.add_request(p, 6,
-                        temperature=0.7 if (sampled and i == 1) else 0.0,
-                        top_k=8 if (sampled and i == 1) else None,
-                        seed=i)
+        eng.add_request(p, 6, seed=i)
     return eng.run_to_completion()
 
 
-def test_engine_greedy_bit_identity_fused_on_off(tiny_serving):
+def test_spec_decode_verify_bit_identity(tiny_serving):
+    """The verify program wraps the engine's step closure; greedy
+    speculative output must stay bit-identical to baseline decode."""
     cfg, params, prompts = tiny_serving
-    a = _drain(_engine(cfg, params, fused=True), prompts, sampled=True)
-    b = _drain(_engine(cfg, params, fused=False), prompts, sampled=True)
-    assert set(a) == set(b)
-    for k in a:
-        np.testing.assert_array_equal(a[k], b[k])
-
-
-def test_frontend_stream_bit_identity_fused_on_off(tiny_serving):
-    from paddle_tpu.serving import ServingFrontend
-    cfg, params, prompts = tiny_serving
-
-    def stream(fused):
-        fe = ServingFrontend(_engine(cfg, params, fused=fused))
-        handles = [fe.submit(p, max_new_tokens=6) for p in prompts]
-        return [list(h) for h in handles]
-
-    assert stream(True) == stream(False)
-
-
-def test_spec_decode_verify_bit_identity_on_fused_path(tiny_serving):
-    """The verify program wraps the engine's (now fused) step closure;
-    greedy speculative output must stay bit-identical to baseline
-    decode — fused on and off, spec on and off: all four agree."""
-    cfg, params, prompts = tiny_serving
-    runs = {(fused, spec): _drain(_engine(cfg, params, fused=fused,
-                                          spec=spec), prompts)
-            for fused in (True, False) for spec in (True, False)}
-    base = runs[(False, False)]
-    for key, out in runs.items():
-        assert set(out) == set(base), key
-        for k in base:
-            np.testing.assert_array_equal(out[k], base[k], err_msg=str(key))
-
-
-def test_aot_warm_start_covers_fusion_knob(tiny_serving, tmp_path):
-    """The artifact config hash covers the knob: a fused export warm
-    starts a fused engine bit-identically, and an UNFUSED engine
-    pointed at the fused artifact falls back cleanly (no half-warm)."""
-    from paddle_tpu.aot.serve import export_engine
-    cfg, params, prompts = tiny_serving
-    eng = _engine(cfg, params, fused=True, prefill_buckets=(8,))
-    export_engine(eng, str(tmp_path))
-    warm = _engine(cfg, params, fused=True, prefill_buckets=(8,),
-                   aot_dir=str(tmp_path))
-    assert warm.aot_loaded
-    a = _drain(warm, prompts)
-    b = _drain(_engine(cfg, params, fused=True, prefill_buckets=(8,)),
-               prompts)
-    for k in a:
-        np.testing.assert_array_equal(a[k], b[k])
-    cold = _engine(cfg, params, fused=False, prefill_buckets=(8,),
-                   aot_dir=str(tmp_path))
-    assert not cold.aot_loaded
-    assert cold.aot_error is not None
+    base = _drain(_engine(cfg, params), prompts)
+    out = _drain(_engine(cfg, params, spec=True), prompts)
+    assert set(out) == set(base)
+    for k in base:
+        np.testing.assert_array_equal(out[k], base[k])
 
 
 def test_make_norm_ffn_matches_legacy_alias():
@@ -520,7 +491,6 @@ def _step_as_it_was(eng, params):
 
 
 def _fill_as_it_was(eng, params, Ts):
-    from paddle_tpu.ops.decode_block import prefill_block
     cfg, BS = eng.cfg, eng.BS
     cos_full, sin_full = _rope_tables(cfg)
     spec = decode_block_spec(cfg, BS)
@@ -538,8 +508,7 @@ def _fill_as_it_was(eng, params, Ts):
         x, (pk, pv) = _scan_as_it_was(
             lambda x, lp, pk, pv: prefill_block(
                 x, lp, pk, pv, blk, pos % BS, bt_row, mask, cos, sin,
-                spec=spec, start=start,
-                scale=1.0 / (cfg.head_dim ** 0.5)),
+                spec=spec, scale=1.0 / (cfg.head_dim ** 0.5)),
             params, pool_k, pool_v)(
                 jnp.take(params["wte"], toks, axis=0)[None])
         last = x[:, -1] if valid is None \
@@ -650,7 +619,7 @@ def test_matmul_stored_contracts_either_layout(lead, dtype):
     layouts in different orders at some shapes; eager and compiled.
     The layout is made once: a tree that has it, or holds codes, comes
     back as it is."""
-    from paddle_tpu.ops.decode_block import matmul_stored, serving_layout
+    from paddle_tpu.ops.decode_block import matmul_stored
     own = np.random.default_rng(31)
     w = _w(2, 64, 24, dtype=dtype)
     laid = serving_layout({"q_w": w, "o_w": w, "k_w__q": w})
@@ -674,39 +643,3 @@ def test_matmul_stored_contracts_either_layout(lead, dtype):
                     np.testing.assert_array_equal(got, want)
                 else:
                     np.testing.assert_allclose(got, want, **tol)
-
-
-@pytest.mark.parametrize("program", ("decode", "prefill"))
-def test_pallas_tiers_read_qkv_as_stored(program):
-    """Both megakernels stream ``q_wt``/``k_wt``/``v_wt`` and contract
-    them as they lie: the same values as from the tree's layout."""
-    from paddle_tpu.ops.decode_block import prefill_block, serving_layout
-    spec, lp, x, pk, pv, bt, ln, cos, sin = _variant("llama_gqa",
-                                                     np.float32)
-    laid = serving_layout(lp)
-    assert decode_block_unsupported_reason(spec, laid, pk) is None
-    old = FLAGS.pallas_interpret
-    set_flags({"pallas_interpret": True})
-    try:
-        if program == "decode":
-            def run(w):
-                return decode_block(x, w, pk, pv, bt, ln, cos, sin,
-                                    spec=spec, backend="pallas")
-        else:
-            Ts, start = 4, 4
-            pos = start + jnp.arange(Ts)
-            xt = _w(1, Ts, spec.hidden, scale=0.5)
-            c, s = _w(Ts, spec.head_dim, scale=1.0), \
-                _w(Ts, spec.head_dim, scale=1.0)
-
-            def run(w):
-                return prefill_block(
-                    xt, w, pk, pv, jnp.take(bt[0], pos // 4), pos % 4,
-                    bt[0], None, c, s, spec=spec, start=jnp.int32(start),
-                    backend="pallas")
-        got, want = run(laid), run(lp)
-    finally:
-        set_flags({"pallas_interpret": old})
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   rtol=1e-6, atol=1e-6)

@@ -18,7 +18,6 @@ def test_on_tpu_reads_the_backend():
 def test_a_failed_backend_is_not_a_quiet_answer(monkeypatch):
     """A backend that cannot initialise raises through every kernel
     dispatch — it never silently means 'interpret mode' or 'no TPU'."""
-    from paddle_tpu.ops.decode_block import _pallas_platform
     from paddle_tpu.ops.fused_cross_entropy import _pallas_auto
     from paddle_tpu.ops.pallas.common import use_interpret
 
@@ -26,7 +25,7 @@ def test_a_failed_backend_is_not_a_quiet_answer(monkeypatch):
         raise RuntimeError("Unable to initialize backend 'tpu'")
 
     monkeypatch.setattr(jax, "devices", dead_backend)
-    for ask in (on_tpu, use_interpret, _pallas_platform, _pallas_auto):
+    for ask in (on_tpu, use_interpret, _pallas_auto):
         with pytest.raises(RuntimeError, match="initialize backend"):
             ask()
 

@@ -149,6 +149,10 @@ def test_state_update_kernel_equals_the_per_op_update(nh, P, N, G):
         np.testing.assert_array_equal(np.asarray(s1)[keep],
                                       np.asarray(states)[keep])
     assert ssm.ssm_state_update_tier(states.shape, G)[0] == "xla"  # a CPU
+    # the compiled kernel's own limit (the interpreter has none): lanes
+    from paddle_tpu.ops.pallas.ssm import unsupported_reason
+    reason = unsupported_reason(states.shape, G)
+    assert reason is None if N % 128 == 0 else "128 lanes" in reason
 
 
 # ---------------------------------------------------------------------
@@ -385,7 +389,8 @@ def test_http_cli_builds_the_tiny_hybrid():
     h = fe.submit(_prompts((6,))[0], 5)
     fe.run_until_drained(timeout_s=120)
     assert h.state.name == "FINISHED" and len(h.tokens()) == 5
-    assert fe.engine.kernel_tiers()["decode_block"]["tier"] == "xla"
+    assert fe.engine.kernel_tiers() == {
+        "ssm_state_update": {"tier": "xla", "reason": "not on a TPU"}}
 
 
 # ---------------------------------------------------------------------

@@ -60,25 +60,10 @@ def test_itemsize_accepts_strings_and_reprs():
 
 
 def test_budget_reproduces_hand_constant():
-    # 0.75 * 16 MB == the pre-ISSUE-10 VMEM_BUDGET_BYTES
+    # 0.75 * 16 MB
     assert cost.budget_bytes() == 12 * 2 ** 20
     assert cost.fits(12 * 2 ** 20)
     assert not cost.fits(12 * 2 ** 20 + 1)
-
-
-def test_decode_block_vmem_breakdown_adds_up():
-    est = cost.decode_block_vmem(
-        hidden=64, num_heads=4, kv_heads=2, head_dim=16, block_size=8,
-        pages=2, weight_bytes=1000, pool_itemsize=2, x_itemsize=4)
-    assert est["total"] == (est["weights"] + est["staging"]
-                            + est["scratch"] + est["io"])
-    # double-buffered: DMA_STAGING_SLOTS revolving copies of k+v pages
-    assert est["staging"] == cost.DMA_STAGING_SLOTS * 2 * 2 * 8 * 2 * 16 * 2
-    # doubling pages doubles ONLY staging
-    est2 = cost.decode_block_vmem(
-        hidden=64, num_heads=4, kv_heads=2, head_dim=16, block_size=8,
-        pages=4, weight_bytes=1000, pool_itemsize=2, x_itemsize=4)
-    assert est2["total"] - est["total"] == est["staging"]
 
 
 def test_linear_ce_vmem_scales_with_blocks():
@@ -107,9 +92,42 @@ def test_extractor_models_real_kernels():
                for s in fwd.scratch)
 
 
-def test_extractor_handles_decode_block_megakernel():
-    mod = core.load_module(os.path.join(
-        REPO, "paddle_tpu", "ops", "pallas", "decode_block.py"))
+def test_extractor_handles_paged_gather_kernels():
+    """What a kernel that gathers pages itself declares: tables in
+    SMEM, pools left in ANY space, a splat of per-weight specs, DMA
+    semaphores beside the VMEM scratch, a ceil-divided grid axis in the
+    ``-(-a // b)`` idiom.  The serving path's own attention kernel
+    (``ops/pallas/decode_attention.py``) reads lengths from SMEM."""
+    import ast
+    src = textwrap.dedent("""
+        def _call(x, ws, pool_k, pool_v, table, lengths, pages):
+            B, H = x.shape
+            nt = -(-table.shape[1] // pages)
+
+            def wspec(w):
+                return pl.BlockSpec(w.shape, lambda b, j: (0, 0))
+
+            in_specs = [
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((None, 1, H), lambda b, j: (b, 0, 0)),
+                *[wspec(w) for w in ws],
+                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pltpu.ANY),
+            ]
+            return pl.pallas_call(
+                _kernel,
+                grid=(B, nt),
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec((None, 1, H),
+                                       lambda b, j: (b, 0, 0)),
+                out_shape=jax.ShapeDtypeStruct((B, 1, H), x.dtype),
+                scratch_shapes=[pltpu.VMEM((2, pages, 16, 128),
+                                           jnp.float32),
+                                pltpu.SemaphoreType.DMA((2, pages))],
+            )(table, lengths, x[:, None], *ws, pool_k, pool_v)
+    """)
+    mod = core.Module("paged.py", "paged.py", src, ast.parse(src))
     sites = extract_sites(mod)
     assert len(sites) == 1
     site = sites[0]
@@ -121,6 +139,14 @@ def test_extractor_handles_decode_block_megakernel():
     anys = [s for s in site.in_specs if s.memory_space == "any"]
     assert len(smem) == 2 and len(anys) == 2    # tables + pools
     assert any(s.kind == "sem" for s in site.scratch)
+
+    real = extract_sites(core.load_module(os.path.join(
+        REPO, "paddle_tpu", "ops", "pallas", "decode_attention.py")))
+    assert len(real) == 1 and real[0].kernel_name == "_decode_kernel"
+    assert real[0].grid_rank == 3 and real[0].in_specs_complete
+    assert [s.memory_space for s in real[0].in_specs] \
+        == ["smem", "vmem", "vmem", "vmem"]
+    assert [s.kind for s in real[0].scratch] == ["vmem"] * 3
 
 
 def test_const_env_folds_module_and_local_names():

@@ -299,85 +299,6 @@ class TestQuantLinearHW:
         assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
 
 
-class TestBlockMegakernelsHW:
-    """decode_block / prefill_block Pallas tiers against their XLA
-    tiers on the chip, at the widest bf16 geometry the cost model
-    admits (the one tests/test_chip_compile.py compiles)."""
-
-    H, Hq, D, F, BS, NB, MB = 512, 4, 128, 1408, 16, 64, 8
-
-    def _case(self):
-        import jax.numpy as jnp
-        from paddle_tpu.ops.decode_block import DecodeBlockSpec
-        rng = np.random.default_rng(3)
-
-        def w(*shape, scale=0.05):
-            return jnp.asarray(rng.standard_normal(shape) * scale,
-                               jnp.bfloat16)
-
-        H, Hq, D, F = self.H, self.Hq, self.D, self.F
-        lp = {"ln1_w": w(H) + 1, "ln2_w": w(H) + 1,
-              "q_w": w(H, Hq * D), "k_w": w(H, Hq * D),
-              "v_w": w(H, Hq * D), "o_w": w(Hq * D, H),
-              "gate_w": w(H, F), "up_w": w(H, F), "down_w": w(F, H)}
-        spec = DecodeBlockSpec(hidden=H, num_heads=Hq, kv_heads=Hq,
-                               head_dim=D, block_size=self.BS, norm="rms",
-                               activation="swiglu", eps=1e-5, rope=True)
-        pool = lambda: w(self.NB, self.BS, Hq, D, scale=0.5)
-        return spec, lp, pool(), pool(), w
-
-    @staticmethod
-    def _close(got, ref, what):
-        got, ref = (np.asarray(a.astype("float32")) for a in (got, ref))
-        err = np.abs(got - ref).max() / max(np.abs(ref).max(), 1e-6)
-        assert np.isfinite(got).all() and err < 3e-2, (what, err)
-
-    def test_decode_block_matches_xla_tier(self):
-        import jax.numpy as jnp
-        from paddle_tpu.ops.decode_block import (
-            decode_block, decode_block_unsupported_reason)
-        spec, lp, pk, pv, w = self._case()
-        assert decode_block_unsupported_reason(spec, lp, pk) is None
-        B = 4
-        bt = np.full((B, self.MB), -1, np.int32)
-        lengths = np.array([37, 16, 5, 100], np.int32)
-        for b, n in enumerate(lengths):       # map pages through the
-            need = n // self.BS + 1           # CURRENT one (the contract)
-            bt[b, :need] = 1 + b * self.MB + np.arange(need)
-        args = (w(B, self.H, scale=0.5), lp, pk, pv, jnp.asarray(bt),
-                jnp.asarray(lengths), w(B, self.D, scale=1.0),
-                w(B, self.D, scale=1.0))
-        ref = decode_block(*args, spec=spec, backend="xla")
-        got = decode_block(*args, spec=spec, backend="pallas")
-        for g, r, what in zip(got, ref, ("x", "pool_k", "pool_v")):
-            self._close(g, r, what)
-
-    def test_prefill_block_matches_xla_tier(self):
-        import jax.numpy as jnp
-        from paddle_tpu.ops.decode_block import (
-            prefill_block, prefill_block_unsupported_reason)
-        spec, lp, pk, pv, w = self._case()
-        Ts, start = 64, 32
-        assert prefill_block_unsupported_reason(spec, lp, pk, Ts) is None
-        bt_row = np.full((self.MB,), -1, np.int32)
-        bt_row[:(start + Ts) // self.BS] = 3 + np.arange(
-            (start + Ts) // self.BS)
-        pos = start + np.arange(Ts)
-        blk = jnp.asarray(bt_row[pos // self.BS])
-        off = jnp.asarray(pos % self.BS, jnp.int32)
-        mask = jnp.asarray(np.arange(self.MB * self.BS)[None, None, None]
-                           <= pos[None, None, :, None])
-        args = (w(1, Ts, self.H, scale=0.5), lp, pk, pv, blk, off,
-                jnp.asarray(bt_row), mask, w(Ts, self.D, scale=1.0),
-                w(Ts, self.D, scale=1.0))
-        kw = dict(spec=spec, start=jnp.int32(start),
-                  scale=1.0 / np.sqrt(self.D))
-        ref = prefill_block(*args, backend="xla", **kw)
-        got = prefill_block(*args, backend="pallas", **kw)
-        for g, r, what in zip(got, ref, ("x", "pool_k", "pool_v")):
-            self._close(g, r, what)
-
-
 class TestEngineHW:
     def test_serving_engine_smoke(self):
         """One continuous-batching scheduler pass on the chip: paged-KV

@@ -1,10 +1,8 @@
 """Quantized serving end-to-end (ISSUE 16): PTQ export round-trip,
-parity tiers for the int8/int4 weight-only decode path, greedy
-bit-identity WITHIN a quant config across every serve surface (engine,
-frontend stream, HTTP wire, spec-decode, prefix-cache hit), the
-fusion-envelope widening (a layer too wide for VMEM at bf16 runs FUSED
-under int8 — static cost model AND interpret-tier execution), the
-int8-KV capacity win at fixed pool bytes, quantized spill round-trips
+the int8/int4 weight-only decode path against its dequantized-weight
+reference, greedy bit-identity WITHIN a quant config across every serve
+surface (engine, frontend stream, HTTP wire, spec-decode, prefix-cache
+hit), the int8-KV capacity win at fixed pool bytes, quantized spill round-trips
 (preempt/restore, prefix offload, CRC bit-rot typed fallback,
 cross-config mismatch guards), and the AOT config hash covering the
 quant config.
@@ -26,13 +24,9 @@ import numpy as np
 import pytest
 
 from paddle_tpu import parallel as dist
-from paddle_tpu.analysis.kernel import cost
-from paddle_tpu.core.flags import FLAGS, set_flags
 from paddle_tpu.inference.serving import ContinuousBatchingEngine
 from paddle_tpu.models.llama import build_llama_train_step, llama_tiny
-from paddle_tpu.ops.decode_block import (DecodeBlockSpec,
-                                         DecodeBlockUnsupportedError,
-                                         decode_block)
+from paddle_tpu.ops.decode_block import DecodeBlockSpec, decode_block
 from paddle_tpu.ops.paged_kv import (QuantizedKVPool, dequantize_kv,
                                      is_quantized_pool, kv_page_bytes,
                                      quantize_kv, zeros_kv_pool)
@@ -46,8 +40,6 @@ from paddle_tpu.serving.prefix_cache import PrefixCacheConfig
 from paddle_tpu.serving.resilience import (SpillCorruptError,
                                            restore_into_slot,
                                            snapshot_slot)
-
-pytestmark = pytest.mark.slow
 
 rng = np.random.default_rng(16)
 
@@ -158,10 +150,14 @@ def test_ptq_calibrated_thresholds_become_scales(model):
 
 
 # ---------------------------------------------------------------------
-# parity tiers for the quantized decode path
+# the quantized decode path
 # ---------------------------------------------------------------------
+# the leaves the PTQ export replaces with ``__q``/``__s`` pairs (norm
+# gains stay full width)
+_MATMUL_NAMES = ("q_w", "k_w", "v_w", "o_w", "gate_w", "up_w", "down_w")
+
+
 def _quant_layer(lp, qc):
-    from paddle_tpu.ops.pallas.decode_block import _MATMUL_NAMES
     out = {}
     for n, v in lp.items():
         if n in _MATMUL_NAMES:
@@ -173,15 +169,15 @@ def _quant_layer(lp, qc):
     return out
 
 
-def _decode_case(dtype, qc, kv_quant=False, H=32, Hq=4, Hkv=2, D=8, F=48,
-                 w_scale=0.1):
+def _decode_case(dtype, qc, kv_quant=False):
+    H, Hq, Hkv, D, F = 32, 4, 2, 8, 48
     spec = DecodeBlockSpec(
         hidden=H, num_heads=Hq, kv_heads=Hkv, head_dim=D, block_size=4,
         norm="rms", activation="swiglu", eps=1e-5, rope=True,
         weight_dtype=qc.weight_dtype if qc else None,
         group_size=qc.group_size if qc else -1)
 
-    def w(*shape, scale=w_scale):
+    def w(*shape, scale=0.1):
         return jnp.asarray(rng.standard_normal(shape).astype(np.float32)
                            * scale, dtype)
 
@@ -203,8 +199,8 @@ def _decode_case(dtype, qc, kv_quant=False, H=32, Hq=4, Hkv=2, D=8, F=48,
 
 @pytest.mark.parametrize("qc", [c for c in CONFIGS if c.quantized_weights],
                          ids=lambda c: f"{c.weight_dtype}/g{c.group_size}")
-def test_quant_xla_tier_matches_dequantized_reference(qc):
-    """The quantized XLA tier computes exactly what its stored codes
+def test_quant_chain_matches_dequantized_reference(qc):
+    """The quantized chain computes exactly what its stored codes
     say: output == the UNQUANTIZED op run on dequantized weights, at
     the fp32 tier (1e-5) — and stays within QUANT_TOL of the original
     weights."""
@@ -212,9 +208,8 @@ def test_quant_xla_tier_matches_dequantized_reference(qc):
         np.float32, qc, kv_quant=qc.quantized_kv)
     qlp = _quant_layer(lp, qc)
     got, _, _ = decode_block(x, qlp, pk, pv, bt, ln, cos, sin,
-                             spec=spec, backend="xla")
+                             spec=spec)
     deq = dict(lp)
-    from paddle_tpu.ops.pallas.decode_block import _MATMUL_NAMES
     for n in lp:
         if n in _MATMUL_NAMES:
             deq[n] = dequantize_block_weight(
@@ -225,60 +220,13 @@ def test_quant_xla_tier_matches_dequantized_reference(qc):
         block_size=spec.block_size, norm="rms", activation="swiglu",
         eps=1e-5, rope=True)
     ref, _, _ = decode_block(x, deq, pk, pv, bt, ln, cos, sin,
-                             spec=fp_spec, backend="xla")
+                             spec=fp_spec)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     orig, _, _ = decode_block(x, lp, pk, pv, bt, ln, cos, sin,
-                              spec=fp_spec, backend="xla")
+                              spec=fp_spec)
     np.testing.assert_allclose(np.asarray(got), np.asarray(orig),
                                **QUANT_TOL[qc.weight_dtype])
-
-
-@pytest.mark.parametrize("dtype,tol", [(np.float32, 1e-5),
-                                       (jnp.bfloat16, 2e-2)],
-                         ids=["fp32", "bf16"])
-@pytest.mark.parametrize("qc", [c for c in CONFIGS if c.quantized_weights],
-                         ids=lambda c: f"{c.weight_dtype}/g{c.group_size}")
-def test_quant_pallas_tier_matches_xla_tier(qc, dtype, tol):
-    """Dequant-in-kernel == dequant-in-XLA at the activation dtype's
-    tier: the Pallas megakernel's fused (y @ wq) * s must agree with
-    the reference tier for every storage layout (int8 per-channel,
-    grouped, int4 nibbles) and for int8 KV pages."""
-    old = FLAGS.pallas_interpret
-    set_flags({"pallas_interpret": True})
-    try:
-        spec, lp, x, pk, pv, bt, ln, cos, sin = _decode_case(
-            dtype, qc, kv_quant=qc.quantized_kv)
-        qlp = _quant_layer(lp, qc)
-        a, ak, av = decode_block(x, qlp, pk, pv, bt, ln, cos, sin,
-                                 spec=spec, backend="pallas")
-        b, bk, bv = decode_block(x, qlp, pk, pv, bt, ln, cos, sin,
-                                 spec=spec, backend="xla")
-        np.testing.assert_allclose(
-            np.asarray(a, np.float32), np.asarray(b, np.float32),
-            rtol=tol, atol=tol)
-        # appended KV pages agree too: exact codes at fp32; at bf16 the
-        # pre-quantization k differs by one ulp between tiers, so a
-        # boundary value may round to an adjacent code — compare the
-        # DEQUANTIZED page values at the tier tolerance instead
-        if is_quantized_pool(ak):
-            if dtype == np.float32:
-                np.testing.assert_array_equal(np.asarray(ak.data),
-                                              np.asarray(bk.data))
-                np.testing.assert_allclose(np.asarray(ak.scale),
-                                           np.asarray(bk.scale),
-                                           rtol=1e-6)
-            else:
-                np.testing.assert_allclose(
-                    np.asarray(dequantize_kv(ak.data, ak.scale)),
-                    np.asarray(dequantize_kv(bk.data, bk.scale)),
-                    rtol=tol, atol=tol)
-        else:
-            np.testing.assert_allclose(
-                np.asarray(ak, np.float32), np.asarray(bk, np.float32),
-                rtol=tol, atol=tol)
-    finally:
-        set_flags({"pallas_interpret": old})
 
 
 # ---------------------------------------------------------------------
@@ -360,77 +308,12 @@ def test_bit_identity_across_serve_surfaces(model):
 
 
 # ---------------------------------------------------------------------
-# fusion envelope: int8 admits a width that falls back at bf16
-# ---------------------------------------------------------------------
-# llama-7B-ish slice: one layer's bf16 weights (~16.7 MB) overflow the
-# decode-block VMEM budget; the same layer at int8 (~8.4 MB) fits
-_WIDE = dict(H=896, Hq=14, Hkv=2, D=64, F=2432)
-
-
-def _wide_case(qc):
-    # 1/sqrt(K)-ish weights keep activations O(1) so the bf16 tier
-    # tolerance is meaningful at this width
-    return _decode_case(jnp.bfloat16, qc, w_scale=0.02, **_WIDE)
-
-
-def test_fusion_envelope_static_cost_model():
-    W = _WIDE
-    common = dict(hidden=W["H"], num_heads=W["Hq"], kv_heads=W["Hkv"],
-                  head_dim=W["D"], block_size=4, rope=True,
-                  pool_itemsize=2, x_itemsize=2)
-    wb_bf16 = cost.decode_block_weight_bytes(
-        hidden=W["H"], num_heads=W["Hq"], kv_heads=W["Hkv"],
-        head_dim=W["D"], ffn_hidden=W["F"], itemsize_=2)
-    wb_int8 = cost.decode_block_weight_bytes(
-        hidden=W["H"], num_heads=W["Hq"], kv_heads=W["Hkv"],
-        head_dim=W["D"], ffn_hidden=W["F"], weight_dtype="int8",
-        itemsize_=2)
-    assert wb_int8 < wb_bf16 * 0.55
-    reason = cost.decode_block_unsupported_reason(
-        weight_bytes=wb_bf16, **common)
-    assert reason is not None and "VMEM" in reason
-    assert cost.decode_block_unsupported_reason(
-        weight_bytes=wb_int8, **common) is None
-
-
-def test_fusion_envelope_execution(model):
-    """The same wide layer: forcing the Pallas tier at bf16 raises the
-    typed fallback, and at int8 it RUNS (interpret mode) and matches
-    its own XLA tier."""
-    old = FLAGS.pallas_interpret
-    set_flags({"pallas_interpret": True})
-    try:
-        qc = ServeQuantConfig(weight_dtype="int8")
-        spec, lp, x, pk, pv, bt, ln, cos, sin = _wide_case(qc)
-        bf16_spec = DecodeBlockSpec(
-            hidden=spec.hidden, num_heads=spec.num_heads,
-            kv_heads=spec.kv_heads, head_dim=spec.head_dim,
-            block_size=spec.block_size, norm="rms",
-            activation="swiglu", eps=1e-5, rope=True)
-        with pytest.raises(DecodeBlockUnsupportedError,
-                           match="VMEM"):
-            decode_block(x, lp, pk, pv, bt, ln, cos, sin,
-                         spec=bf16_spec, backend="pallas")
-        qlp = _quant_layer(lp, qc)
-        a, _, _ = decode_block(x, qlp, pk, pv, bt, ln, cos, sin,
-                               spec=spec, backend="pallas")
-        b, _, _ = decode_block(x, qlp, pk, pv, bt, ln, cos, sin,
-                               spec=spec, backend="xla")
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   rtol=2e-2, atol=2e-2)
-    finally:
-        set_flags({"pallas_interpret": old})
-
-
-# ---------------------------------------------------------------------
 # int8 KV capacity at fixed pool bytes
 # ---------------------------------------------------------------------
 def test_int8_kv_capacity_at_fixed_pool_bytes():
     """At an identical pool byte budget and head_dim 64, int8 KV pages
     admit >= 1.8x the concurrent sequences of bf16 pages, draining at
-    zero leaked blocks (the ISSUE 16 acceptance row, also surfaced in
-    bench.py extra.quant)."""
+    zero leaked blocks (the ISSUE 16 acceptance row)."""
     ccfg = llama_tiny(hidden_size=128, num_heads=2, num_kv_heads=2,
                       num_layers=2, dtype="bfloat16")
     topo = dist.init_topology(devices=jax.devices()[:1])

@@ -12,8 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.slow
-
 from paddle_tpu import parallel as dist
 from paddle_tpu.inference.serving import ContinuousBatchingEngine
 from paddle_tpu.models.llama import llama_tiny, build_llama_train_step

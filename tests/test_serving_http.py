@@ -929,12 +929,8 @@ def test_cli_builds_the_model_its_flags_name():
             np.testing.assert_array_equal(
                 np.asarray(got.astype("float32")),
                 np.asarray(want.astype("float32")))
-        # the engine names the tier each program runs on, and why
-        tiers = eng.kernel_tiers()
-        assert set(tiers) == {"decode_block", "prefill_block[8]",
-                              "prefill_block[32]"}
-        assert all(t["tier"] == "xla" and "not on a TPU" in t["reason"]
-                   for t in tiers.values())
+        # a Llama-family engine has no kernel choice left to name
+        assert eng.kernel_tiers() == {}
     finally:
         fe.close()
 

@@ -181,30 +181,11 @@ def test_quantization_serve_has_zero_tl001_tl006():
 
 
 def test_decode_block_has_zero_tl001_tl006():
-    """ISSUE 9 contract: the fused decode-block op (dispatch module AND
-    Pallas kernel) sits on the hottest serve path — no host-sync in
-    traced code (TL001; one ``.item()`` in the layer body would sync
-    every layer of every decode step) and no silent broad excepts
-    (TL006; a swallowed dispatch error would silently serve the wrong
-    tier) — live scan AND committed ledger."""
-    files = ("paddle_tpu/ops/decode_block.py",
-             "paddle_tpu/ops/pallas/decode_block.py")
-    live = [f for f in _current_findings()
-            if f.rule in ("TL001", "TL006") and f.path.endswith(files)]
-    assert live == [], [f.format() for f in live]
-    for (rule, path), n in baseline_mod.load().items():
-        if rule in ("TL001", "TL006") and path.endswith(files):
-            assert n == 0, f"baseline carries {rule} debt in {path}"
-
-
-def test_prefill_block_has_zero_tl001_tl006():
-    """ISSUE 18 contract: the fused chunked-prefill kernel shares the
-    decode megakernel's bar — no host-sync in traced code (TL001; a
-    ``.item()`` in the chunk-fill body would sync every layer of every
-    prefill chunk) and no silent broad excepts (TL006; a swallowed
-    dispatch error would silently serve the wrong tier) — live scan
-    AND committed ledger."""
-    files = ("paddle_tpu/ops/pallas/prefill_block.py",)
+    """The serving layer bodies sit on the hottest serve path — no
+    host-sync in traced code (TL001; one ``.item()`` in a layer body
+    would sync every layer of every decode step and chunk fill) and no
+    silent broad excepts (TL006) — live scan AND committed ledger."""
+    files = ("paddle_tpu/ops/decode_block.py",)
     live = [f for f in _current_findings()
             if f.rule in ("TL001", "TL006") and f.path.endswith(files)]
     assert live == [], [f.format() for f in live]

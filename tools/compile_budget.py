@@ -12,8 +12,8 @@
   synthetic compiles to every measured count (proves the ratchet trips —
   used by the tier-1 test and for CI smoke).
 
-Each scenario mirrors a bench.py config at CPU liveness shapes and
-counts ``backend_compile`` events (observability.CompileMonitor) over
+Each scenario runs one serving or training flow at CPU liveness shapes
+and counts ``backend_compile`` events (observability.CompileMonitor) over
 its WORKLOAD phase only — setup (weight init, AOT export) is excluded.
 ``serve_aot_warm`` is the acceptance scenario: an engine warm-started
 from an AOT artifact directory must record ZERO backend compiles.
@@ -83,7 +83,7 @@ def _engine(cfg, params, aot_dir=None, spec=False):
 
 
 def gpt_train() -> Callable[[], None]:
-    """The flagship bench (no --config): GPT train step, steady loop."""
+    """GPT train step, steady loop."""
     import jax
     import numpy as np
     from paddle_tpu import parallel as dist
@@ -108,7 +108,7 @@ def gpt_train() -> Callable[[], None]:
 
 
 def serve_fresh() -> Callable[[], None]:
-    """bench.py --config serve at liveness shapes: cold engine start
+    """Serving at liveness shapes: cold engine start
     (the one-time q/k/v relayout, 13 -> 14 with ISSUE 31; decode step +
     one declared-bucket fill compile) + full drain."""
     cfg, params, prompts = _tiny_llama()
@@ -423,14 +423,11 @@ def serve_prefix_warm() -> Callable[[], None]:
 
 
 def serve_prefill_warm() -> Callable[[], None]:
-    """Fused chunked prefill on a warm engine (ISSUE 18): the
-    fused_prefill=True export (the engine's default chunk-fill path)
+    """Chunked prefill on a warm engine (ISSUE 18): an export
     warm-starts an engine that serves bucketed fills at several prompt
     lengths (greedy AND sampled), a prefix-cache hit running ONLY the
     suffix through the chunk fill, and one explicit preempt/restore —
-    ZERO backend compiles.  The knob is covered by the engine_config
-    hash: a flipped-knob engine REFUSES the artifact instead of
-    half-warming (checked in setup)."""
+    ZERO backend compiles."""
     import tempfile
 
     import numpy as np
@@ -441,12 +438,6 @@ def serve_prefill_warm() -> Callable[[], None]:
     cfg, params, prompts = _tiny_llama()
     aot_dir = tempfile.mkdtemp(prefix="aot_budget_prefill_")
     export_engine(_engine(cfg, params), aot_dir)
-    flipped = ContinuousBatchingEngine(
-        cfg, params, max_batch=2, block_size=8, num_blocks=64,
-        prefill_buckets=(8,), aot_dir=aot_dir, fused_prefill=False)
-    if flipped.aot_loaded or flipped.aot_error is None:
-        raise RuntimeError(
-            "a flipped fused_prefill knob accepted the fused artifact")
 
     def workload():
         from paddle_tpu.serving.prefix_cache import PrefixCacheConfig
@@ -745,7 +736,7 @@ def render_md(counts: Dict[str, int]) -> str:
     lines = [
         "# compile budget",
         "",
-        "Per-bench-config backend-compile budgets "
+        "Per-scenario backend-compile budgets "
         "(`tools/compile_budget.py`); the ratchet "
         "(`tests/test_compile_budget.py`, or `python "
         "tools/compile_budget.py --check`) fails when any scenario "
@@ -769,9 +760,8 @@ def render_md(counts: Dict[str, int]) -> str:
         "with hits, an eviction-to-offload, and an offload restore, "
         "serving int8-quantized weights and KV pages end-to-end with a "
         "preempt/restore through the codes+scales spill format, or — "
-        "`serve_prefill_warm`, the ISSUE 18 row — serving the fused "
-        "chunked-prefill path (the `fused_prefill` knob, covered by "
-        "the artifact config hash) through bucketed fills, a "
+        "`serve_prefill_warm`, the ISSUE 18 row — serving the "
+        "chunked-prefill path through bucketed fills, a "
         "prefix-cache suffix fill, and a preempt/restore.  "
         "`serve_trace_warm` is the ISSUE 20 row: the request span "
         "tracer enabled around greedy, sampled, prefix-hit and "

@@ -9,9 +9,8 @@
   tests/test_kernellint_ratchet.py runs under pytest.
 
 Mirrors ``tools/tracelint_baseline.py`` (the TL ledger) on the same
-lint surface — ``paddle_tpu/``, ``bench.py``, ``tools/`` — restricted
-to the KL (Pallas kernel safety) rules from
-``paddle_tpu/analysis/kernel/``.  As of ISSUE 10 the ledger is EMPTY:
+lint surface — ``paddle_tpu/``, ``tools/`` — restricted to the KL
+(Pallas kernel safety) rules from ``paddle_tpu/analysis/kernel/``.  As of ISSUE 10 the ledger is EMPTY:
 every pre-existing finding was fixed (the six KL006 interpret-parity
 gaps got tests) — any new finding is above baseline by construction.
 """

@@ -9,8 +9,8 @@
   tests/test_locklint_ratchet.py runs under pytest.
 
 Mirrors ``tools/tracelint_baseline.py`` / ``kernellint_baseline.py``
-on the same lint surface — ``paddle_tpu/``, ``bench.py``, ``tools/``
-— restricted to the LK (concurrency safety) rules from
+on the same lint surface — ``paddle_tpu/``, ``tools/`` — restricted
+to the LK (concurrency safety) rules from
 ``paddle_tpu/analysis/threads/``.  The ledger starts EMPTY: every
 finding of the initial project-wide triage was either fixed (the
 prefetcher lost-exception races, the unjoined serving/RPC/KV threads,

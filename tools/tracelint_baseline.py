@@ -8,8 +8,8 @@
   one-liner for the same ratchet tests/test_tracelint_ratchet.py runs
   under pytest.
 
-The lint surface is the repo default: ``paddle_tpu/``, ``bench.py``,
-``tools/`` (including this file).  This ledger carries the TL (trace
+The lint surface is the repo default: ``paddle_tpu/``, ``tools/``
+(including this file).  This ledger carries the TL (trace
 safety) rules only; the KL (Pallas kernel) rules ratchet through
 ``tools/kernellint_baseline.py`` → ``KERNELLINT.md``.
 """
