@@ -6,21 +6,29 @@ flash_attn_kernel.cu:536 via backends/dynload/flashattn.h:19) and keeps a
 per-shape dispatch layer in front of it.  The TPU analog of that tuned
 library is the Pallas kernel suite that ships inside JAX itself
 (``jax.experimental.pallas.ops.tpu.flash_attention`` and
-``splash_attention`` — Mosaic kernels tuned by the platform vendor).  This
-module is the dispatch layer: it exposes :func:`tuned_flash` which picks,
-per shape signature, the fastest of
+``splash_attention``).  Those kernels ship UNTUNED: the splash kernel's
+default tiles are a 128 x 128 placeholder under the library's own
+"TODO: Select better parameters based on a heuristic", and at that
+default the train cell's attention ran at 4% of its roofline until PR 33.
+The tiles it runs at here are this module's (:func:`splash_block_sizes`),
+chosen from the call's shapes at caps read on a TPU v5e with JAX 0.9.0
+(2026-10-03; the table is in PERF.md, section 6, PR 33).  This module is
+also the dispatch layer: it exposes :func:`tuned_flash` which picks, per
+shape signature, one of
 
 * ``ours``      — the first-party kernel (flash_attention.py): full feature
                   set (GQA-native, segment ids, bias, lse out) and the only
                   backend that runs in interpret mode on CPU;
 * ``jax_flash`` — the platform flash kernel (equal-head MHA; GQA served by
-                  repeating KV heads);
+                  repeating KV heads), at its library defaults;
 * ``splash``    — the platform splash kernel (causal/full masks, segment
-                  ids, native grouped-KV via its MQA form).
+                  ids, native grouped-KV via its MQA form), at this
+                  module's tiles.
 
-Selection is autotuned (ops/pallas/autotune.py: timed fwd+bwd once per
-unseen shape, winners persisted) with a static heuristic fallback, mirroring
-the reference's per-shape flash/mem-efficient/math dispatch
+Selection is by a static order (``available_backends``) unless
+``FLAGS.use_autotune`` is on (ops/pallas/autotune.py: timed fwd+bwd once
+per unseen shape, winners persisted; its table holds no TPU entry),
+mirroring the reference's per-shape flash/mem-efficient/math dispatch
 (python/paddle/nn/functional/flash_attention.py:976).
 """
 
@@ -72,6 +80,72 @@ def _jax_flash(q, k, v, scale, causal, seg_q=None, seg_k=None, bias=None):
     return jnp.swapaxes(out, 1, 2)
 
 
+# The splash kernel's tiles.  The library ships none: without
+# ``block_sizes`` it runs ``BlockSizes.get_default()``, 128 for all eight
+# under its own "TODO: Select better parameters", and at 128 x 128 the
+# kernel is bound by stepping its grid (~0.75 us a step), not by the MXU.
+# The caps below were read on one TPU v5e with JAX 0.9.0 (PR 33,
+# 2026-10-03; the table is in PERF.md, section 6): a kernel's ms a call
+# in the profiler's trace of ``jax.grad`` of ``run_backend("splash", ...)``,
+# bf16, causal, at the train cell's [4, 2048, 32/8, 128]; the same caps
+# were best, or within 2.5% of the best call, at [2, 4096, 32/8, 128],
+# [8, 1024, 32/8, 128], [16, 512, 32/8, 128], [2, 2048, 16/16, 64] and
+# [4, 1024, 16/16, 64].  A tile is the largest power of two up to its cap
+# that divides its length.
+_SPLASH_BLOCK_Q = 1024           # fwd 11.42 ms at 128 -> 1.56 at 1024 x 1024
+_SPLASH_BLOCK_KV = 1024          # (512: 1.84, 2048: 1.85; q 2048: 1.92, and
+#                                  the compiler refuses 2048 x 2048 for VMEM)
+_SPLASH_BLOCK_KV_COMPUTE = 512   # 256: 1.563, 512: 1.557, 1024: 1.720
+_SPLASH_BLOCK_Q_DKV = 1024       # fused dkv+dq 2.98 ms at 1024 x 1024 / 512
+_SPLASH_BLOCK_KV_DKV = 1024      # (512 x 512: 3.19, 1024 x 2048: 3.70)
+_SPLASH_BLOCK_KV_DKV_COMPUTE = 512   # 256: 3.07, 512: 2.98, 1024: 2.95
+_SPLASH_BLOCK_Q_DQ = 1024        # the dq kernel alone: 11.24 ms at 128 ->
+_SPLASH_BLOCK_KV_DQ = 1024       # 2.00 at 1024 x 1024 (512: 2.24, 2048: 2.20)
+# The fused backward (dq from the dkv kernel: 2.98 ms against 2.41 + 2.00
+# at the same tiles) writes one partial dq per kv tile, Sk / block_kv_dkv
+# times q's bytes, and sums them outside: time it gains at every length,
+# memory it takes in proportion to Sk.  Past this many partials the two
+# kernels run apart and dq stays one array.
+_SPLASH_FUSED_BWD_MAX_PARTIALS = 4
+# A tile holds at most the bytes of the 1024 x 128 bf16 rows the caps were
+# read at, so wider rows get shorter tiles: at D = 256 (bf16) 512 x 512
+# reads 2.59 ms a call against 2.71 at 1024 x 1024, and 1024 float32 rows
+# of 256 the compiler refuses outright (scoped VMEM, the dkv and dq kernels).
+_SPLASH_TILE_BYTES = 1024 * 128 * 2
+
+
+def splash_block_sizes(Sq: int, Sk: int, D: int, group: int,
+                       itemsize: int = 2):
+    """The ``BlockSizes`` the splash kernel is built with, from what the
+    call can see: the two lengths and a row's bytes.  ``group`` (1: the
+    MHA form, more: the MQA form over a group of q heads) wanted no other
+    caps on the v5e."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as _sa
+    del group
+    rows = _SPLASH_TILE_BYTES // (D * itemsize)
+
+    def tile(length, cap):
+        # ``length`` is a multiple of 128 (``available_backends``)
+        t = 128
+        while t * 2 <= min(cap, rows) and length % (t * 2) == 0:
+            t *= 2
+        return t
+
+    kv = tile(Sk, _SPLASH_BLOCK_KV)
+    kv_dkv = tile(Sk, _SPLASH_BLOCK_KV_DKV)
+    fused = Sk // kv_dkv <= _SPLASH_FUSED_BWD_MAX_PARTIALS
+    dq = {} if fused else dict(block_q_dq=tile(Sq, _SPLASH_BLOCK_Q_DQ),
+                               block_kv_dq=tile(Sk, _SPLASH_BLOCK_KV_DQ))
+    return _sa.BlockSizes(
+        block_q=tile(Sq, _SPLASH_BLOCK_Q),
+        block_kv=kv,
+        block_kv_compute=min(kv, _SPLASH_BLOCK_KV_COMPUTE),
+        block_q_dkv=tile(Sq, _SPLASH_BLOCK_Q_DKV),
+        block_kv_dkv=kv_dkv,
+        block_kv_dkv_compute=min(kv_dkv, _SPLASH_BLOCK_KV_DKV_COMPUTE),
+        use_fused_bwd_kernel=fused, **dq)
+
+
 def _splash(q, k, v, scale, causal, seg_q=None, seg_k=None, bias=None):
     from jax.experimental.pallas.ops.tpu import splash_attention as _sa
     if bias is not None:
@@ -87,10 +161,14 @@ def _splash(q, k, v, scale, causal, seg_q=None, seg_k=None, bias=None):
     if seg_q is not None:
         seg = _sa.SegmentIds(q=seg_q.astype(jnp.int32),
                              kv=seg_k.astype(jnp.int32))
-    interp = use_interpret()
-    if Hq == Hkv:
-        kernel = _sa.make_splash_mha_single_device(
-            _sa.MultiHeadMask([mk] * Hq), interpret=interp)
+    g = Hq // Hkv
+    make = (_sa.make_splash_mha_single_device if g == 1
+            else _sa.make_splash_mqa_single_device)
+    kernel = make(_sa.MultiHeadMask([mk] * (Hq if g == 1 else g)),
+                  block_sizes=splash_block_sizes(Sq, Sk, D, g,
+                                                 q.dtype.itemsize),
+                  interpret=use_interpret())
+    if g == 1:
         if seg is None:
             out = jax.vmap(lambda qq, kk, vv: kernel(qq, kk, vv))(qt, kt, vt)
         else:
@@ -98,9 +176,6 @@ def _splash(q, k, v, scale, causal, seg_q=None, seg_k=None, bias=None):
     else:
         # GQA via the MQA form: group q heads per KV head and vmap the
         # (batch, kv-head) axes; the mask covers one group
-        g = Hq // Hkv
-        kernel = _sa.make_splash_mqa_single_device(
-            _sa.MultiHeadMask([mk] * g), interpret=interp)
         qg = qt.reshape(B, Hkv, g, Sq, D)
         if seg is None:
             out = jax.vmap(jax.vmap(lambda qq, kk, vv: kernel(qq, kk, vv)))(
@@ -121,9 +196,10 @@ def available_backends(q_shape, k_shape, causal, has_seg, has_bias,
                        interpret: bool) -> tuple:
     """Statically-valid backends for this signature, best-guess first.
 
-    The ordering IS the no-autotune heuristic: the platform kernels are
-    vendor-tuned, so they lead whenever their constraints hold; ``ours``
-    is always last-resort-valid (full feature set + interpret mode)."""
+    The ordering IS the no-autotune heuristic: the platform kernels lead
+    whenever their constraints hold (splash at this module's tiles, the
+    one backend timed on the chip since PR 29); ``ours`` is always
+    last-resort-valid (full feature set + interpret mode)."""
     B, Sq, Hq, D = q_shape
     Sk, Hkv = k_shape[1], k_shape[2]
     if interpret:

@@ -86,6 +86,24 @@ def test_flash_attention_fwd_bwd(chip):
                             argnums=(0, 1, 2)), q, q, q, kernels=2)
 
 
+def test_splash_attention_fwd_bwd(chip):
+    """The kernel the train cell runs (``tuned_flash`` leads with it on
+    a TPU), at the cell's shape and at the tiles ``splash_block_sizes``
+    gives there: held to the compiler's scoped-VMEM limit here."""
+    from paddle_tpu.ops.pallas.flash_backends import (run_backend,
+                                                      splash_block_sizes)
+    q, kv = chip((4, 2048, 32, 128)), chip((4, 2048, 8, 128))
+
+    def attn(a, b, c):
+        return run_backend("splash", a, b, c, 1.0 / math.sqrt(128), True)
+
+    fused = splash_block_sizes(2048, 2048, 128, 4).use_fused_bwd_kernel
+    compile_kernel(attn, q, kv, kv)
+    compile_kernel(jax.grad(lambda a, b, c: fsum(attn(a, b, c)),
+                            argnums=(0, 1, 2)), q, kv, kv,
+                   kernels=2 if fused else 3)
+
+
 def test_linear_cross_entropy_fwd_bwd(chip):
     """The llama_7b-width head (b4 x s2048 tokens) on the tier the
     dispatch picks on a TPU.  At the forward's (256, 512) tile the

@@ -65,13 +65,70 @@ def test_tuned_flash_dispatches_ours_on_cpu():
                                atol=2e-3, rtol=2e-3)
 
 
+# (Sq, Sk, D, group): the train cell's shape first, then the interpret
+# test's, a GPT-like MHA, a long row, Sq != Sk, the smallest legal, and a
+# row long enough that the backward's two kernels run apart
+@pytest.mark.parametrize("sq,sk,d,group", [
+    (2048, 2048, 128, 4), (256, 256, 128, 1), (1024, 1024, 64, 1),
+    (4096, 4096, 128, 4), (384, 2048, 128, 4), (128, 128, 64, 1),
+    (8192, 8192, 128, 4)])
+def test_splash_block_sizes_fit_the_shape(sq, sk, d, group):
+    """No kernel runs: every tile divides its length and is a multiple
+    of the 128 lanes, the compute tile divides the memory tile, and the
+    backward has its tiles (the library raises without them)."""
+    bs = fb.splash_block_sizes(sq, sk, d, group)
+    q_tiles = [bs.block_q, bs.block_q_dkv]
+    kv_tiles = [bs.block_kv, bs.block_kv_compute, bs.block_kv_dkv,
+                bs.block_kv_dkv_compute]
+    if not bs.use_fused_bwd_kernel:
+        q_tiles.append(bs.block_q_dq)
+        kv_tiles.append(bs.block_kv_dq)
+    assert all(t % 128 == 0 and sq % t == 0 for t in q_tiles), bs
+    assert all(t % 128 == 0 and sk % t == 0 for t in kv_tiles), bs
+    assert bs.block_kv % bs.block_kv_compute == 0
+    assert bs.block_kv_dkv % bs.block_kv_dkv_compute == 0
+    assert bs.has_backward_blocks
+    if (sq, sk) == (2048, 2048):
+        # the cell's shape runs at none of the library's placeholders
+        assert 128 not in q_tiles + kv_tiles, bs
+    # one partial dq per kv tile: fused only while they are few
+    assert bs.use_fused_bwd_kernel == (
+        sk // bs.block_kv_dkv <= fb._SPLASH_FUSED_BWD_MAX_PARTIALS)
+
+
+@pytest.mark.parametrize("d,itemsize,rows", [
+    (64, 2, 1024), (128, 2, 1024), (256, 2, 512), (128, 4, 512),
+    (256, 4, 256)])
+def test_splash_tiles_shrink_with_row_bytes(d, itemsize, rows):
+    """Wider rows get shorter tiles (VMEM is what a tile costs): 1024
+    rows of 256 float32 are what the v5e compiler refuses."""
+    bs = fb.splash_block_sizes(2048, 2048, d, 1, itemsize)
+    assert (bs.block_q, bs.block_kv, bs.block_q_dkv, bs.block_kv_dkv) \
+        == (rows,) * 4
+    assert bs.block_kv_compute == bs.block_kv_dkv_compute == min(rows, 512)
+
+
 @pytest.mark.slow
-def test_splash_backend_interpret_mha():
-    q, k, v = _qkv(1, 256, 256, 2, 2, 128)
-    out = fb.run_backend("splash", q, k, v, 1.0 / math.sqrt(128), True)
-    ref = _dense_ref(q, k, v, 1.0 / math.sqrt(128), True)
+@pytest.mark.parametrize("hq,hkv", [(2, 2), (4, 2)])
+def test_splash_backend_interpret_mha(hq, hkv):
+    """Equal heads (the MHA form) and grouped heads (the MQA form over
+    groups of 2), forward and ``jax.grad``, against dense attention."""
+    q, k, v = _qkv(1, 256, 256, hq, hkv, 128)
+    scale = 1.0 / math.sqrt(128)
+    out = fb.run_backend("splash", q, k, v, scale, True)
+    ref = _dense_ref(q, k, v, scale, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-2, rtol=2e-2)
+    w = jnp.asarray(np.random.default_rng(1).standard_normal(ref.shape),
+                    jnp.float32)
+    grads = [jax.grad(lambda a, b, c: jnp.sum(f(a, b, c) * w),
+                      argnums=(0, 1, 2))(q, k, v)
+             for f in (lambda a, b, c: fb.run_backend("splash", a, b, c,
+                                                      scale, True),
+                       lambda a, b, c: _dense_ref(a, b, c, scale, True))]
+    for got, want in zip(*grads):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=5e-2, rtol=5e-2)
 
 
 @pytest.mark.slow
