@@ -166,6 +166,10 @@ def export_engine(engine, directory: str, *,
             "recurrent state: the manifest hashes neither the state's "
             "geometry nor the layer pattern, so a warm start could load "
             "programs for another model")
+    if getattr(engine, "pool_v", True) is None:
+        raise NotImplementedError(
+            "AOT export is not supported for a model with a latent "
+            "cache: the manifest does not hash a latent pool's geometry")
     breg = buckets or getattr(engine, "_buckets", None) or \
         ShapeBucketRegistry(DEFAULT_CHUNK_BUCKETS)
     if breg.max_batch is None:

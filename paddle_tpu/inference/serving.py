@@ -193,6 +193,16 @@ def sched_ratios(s: Dict[str, object]) -> Dict[str, object]:
     return s
 
 
+def _refuse(model: str, mechanisms) -> None:
+    """Raise for the first ``(name, given, why)`` whose ``given`` is set:
+    what the engine cannot do for ``model`` yet is refused by name."""
+    for what, given, why in mechanisms:
+        if given is not None:
+            raise NotImplementedError(
+                f"{what}= is not supported for a model with {model} "
+                f"({why})")
+
+
 class ContinuousBatchingEngine:
     """Continuous-batching engine (greedy by default, per-request
     sampling via temperature/top_k/top_p on add_request).  The kind of
@@ -201,14 +211,18 @@ class ContinuousBatchingEngine:
     attention layers (``cfg.layer_types``,
     ``models/granite_hybrid.py``), for which the engine keeps K/V pages
     for the attention layers only and, beside the page table, a
-    recurrent state and a conv tail per decode slot (below).
+    recurrent state and a conv tail per decode slot (below), or a
+    decoder with latent attention (``cfg.kv_lora_rank``,
+    ``models/glm_moe_lite.py``), whose cache is ONE pool of a vector a
+    token and no value pool (below).
 
     Args:
-      cfg: LlamaConfig (dense or MoE — the FFN follows the config), or
-        a GraniteHybridConfig.
+      cfg: LlamaConfig (dense or MoE — the FFN follows the config), a
+        GraniteHybridConfig, or a GlmMoeLiteConfig.
       params: the family's param pytree (Llama: the train-step tree,
-        wte/head/lnf_w + stacked blocks; hybrid: wte/lnf_w + one
-        stacked tree a run of layers of one kind).
+        wte/head/lnf_w + stacked blocks; the other two: wte/lnf_w (and
+        an untied head) + one stacked tree a run of layers of one
+        kind).
       max_batch: decode-batch slots (static jit shape).
       block_size / num_blocks: shared KV page pool geometry.
       max_blocks_per_seq: page-table width per slot (caps per-sequence
@@ -300,6 +314,22 @@ class ContinuousBatchingEngine:
     (CRC-checked; a snapshot the bounded tier dropped is replayed from
     the committed tokens), and ``spec_config`` / ``quant_config`` /
     ``aot_dir`` raise ``NotImplementedError``.
+
+    Models with latent attention (``cfg.kv_lora_rank``): ``pool_k`` is
+    the latent pool ``[L, NB, BS, W]`` (a token's normed latent and
+    rotated shared key, ``r_kv + d_r`` values in whole lanes of 128:
+    its key, whose first ``r_kv`` columns are also its value) and
+    ``pool_v`` is None; the pool is made on the
+    device (no host staging: at 4.7 GB beside 9 GB of weights a staged
+    copy would not fit) and rides through every program whole, donated.
+    The decode step walks it ``ops.mla.WALK_POSITIONS`` positions a
+    trip, counted like the other walk.  Pages are position-absolute as
+    K/V pages are, so the prefix cache's resident tier and the page
+    accounting work unchanged; a preempted slot's pages leave and come
+    back through two fixed-width page programs
+    (``serving/resilience.py``), not through a host copy of the pool.
+    ``spec_config`` / ``quant_config`` / ``aot_dir`` and a prefix
+    cache's host offload tier raise ``NotImplementedError``.
     """
 
     def __init__(self, cfg, params, *, max_batch: int = 4,
@@ -311,22 +341,38 @@ class ContinuousBatchingEngine:
                  spill_tier=None, prefix_cache_config=None,
                  quant_config=None):
         self._hybrid = getattr(cfg, "layer_types", None) is not None
+        self._latent = getattr(cfg, "kv_lora_rank", None) is not None
+        if self._latent:
+            offload = prefix_cache_config is not None and getattr(
+                prefix_cache_config, "offload_capacity_bytes", 0)
+            _refuse("a latent cache", (
+                ("spec_config", spec_config,
+                 "the draft and verify programs know per-head K/V pools "
+                 "only, and the model's own multi-token-prediction layer "
+                 "is not held"),
+                ("quant_config", quant_config,
+                 "the PTQ export knows neither expert banks nor the "
+                 "latent projections, and the latent pool has no "
+                 "quantised form"),
+                ("aot_dir", aot_dir,
+                 "the AOT manifest does not hash a latent pool's "
+                 "geometry"),
+                ("prefix_cache_config.offload_capacity_bytes",
+                 offload or None,
+                 "the host offload tier copies a K and a V pool through "
+                 "the host whole")))
         if self._hybrid:
-            for what, given, why in (
-                    ("spec_config", spec_config,
-                     "speculative decoding would have to roll a slot's "
-                     "recurrent state back over rejected tokens"),
-                    ("quant_config", quant_config,
-                     "the PTQ export knows neither expert banks nor "
-                     "state-space projections, and the recurrent state "
-                     "has no quantised form"),
-                    ("aot_dir", aot_dir,
-                     "the AOT manifest does not hash the per-slot state's "
-                     "geometry")):
-                if given is not None:
-                    raise NotImplementedError(
-                        f"{what}= is not supported for a model with "
-                        f"per-slot recurrent state ({why})")
+            _refuse("per-slot recurrent state", (
+                ("spec_config", spec_config,
+                 "speculative decoding would have to roll a slot's "
+                 "recurrent state back over rejected tokens"),
+                ("quant_config", quant_config,
+                 "the PTQ export knows neither expert banks nor "
+                 "state-space projections, and the recurrent state has "
+                 "no quantised form"),
+                ("aot_dir", aot_dir,
+                 "the AOT manifest does not hash the per-slot state's "
+                 "geometry")))
         if getattr(cfg, "moe_num_experts", 0) and \
                 getattr(cfg, "moe_router", "topk") != "topk":
             raise NotImplementedError("decode serves token-choice only")
@@ -360,7 +406,6 @@ class ContinuousBatchingEngine:
         # K/V pages exist for the layers that attend: all of them, or a
         # hybrid's attention layers only
         L = cfg.num_attention_layers if self._hybrid else cfg.num_layers
-        kvh, hd = cfg.kv_heads, cfg.head_dim
         dt = jnp.dtype(cfg.dtype)
         # pools are built from HOST zeros through the same pool-shaped
         # copy op the preemption restore path uses (jnp.array of a
@@ -372,13 +417,16 @@ class ContinuousBatchingEngine:
         from ..ops.paged_kv import zeros_kv_pool
         self._kv_quant = quant_config is not None \
             and quant_config.quantized_kv
-        self.pool_k = zeros_kv_pool(
-            (L, num_blocks, block_size, kvh, hd), dt,
-            kv_quant=self._kv_quant)
-        self.pool_v = zeros_kv_pool(
-            (L, num_blocks, block_size, kvh, hd), dt,
-            kv_quant=self._kv_quant)
-        if not self._hybrid:
+        if self._latent:
+            # one vector a token, made where it lives
+            self.pool_k = jnp.zeros(
+                (L, num_blocks, block_size, cfg.pool_width), dt)
+            self.pool_v = None
+        else:
+            shape = (L, num_blocks, block_size, cfg.kv_heads, cfg.head_dim)
+            self.pool_k = zeros_kv_pool(shape, dt, kv_quant=self._kv_quant)
+            self.pool_v = zeros_kv_pool(shape, dt, kv_quant=self._kv_quant)
+        if not (self._hybrid or self._latent):
             # q, k and v laid out ONCE as the compiled programs read
             # them (a tree that already is comes back as it is); the
             # caller's tree, which training and checkpoints share, is
@@ -399,8 +447,9 @@ class ContinuousBatchingEngine:
         #: what every compiled program is given after the params,
         #: donated, and hands back first: the pools, and a hybrid's two
         #: state arrays (``_carried`` / ``_keep``)
-        self._carry = ("pool_k", "pool_v") + (
-            ("ssm_state", "conv_state") if self._hybrid else ())
+        self._carry = ("pool_k",) if self._latent else (
+            ("pool_k", "pool_v") + (
+                ("ssm_state", "conv_state") if self._hybrid else ()))
         self._donate = tuple(range(1, 1 + len(self._carry)))
         self.block_table = np.full((max_batch, self.MB), -1, np.int32)
         self.lengths = np.zeros((max_batch,), np.int32)
@@ -488,6 +537,17 @@ class ContinuousBatchingEngine:
         # of live slots x k x layers and held experts x layers
         self.moe = {"moe_assignments_local": 0, "moe_experts_hit": 0,
                     "moe_assignments_total": 0, "moe_expert_slots": 0}
+        if self._latent:
+            # the most pairs on ONE expert of a layer, summed over the
+            # expert layers and the steps: against the pairs, how
+            # uneven the routing is
+            self.moe["moe_peak_load"] = 0
+        # positions a trip of the decode program's page walk covers
+        if self._latent:
+            from ..ops.mla import WALK_POSITIONS
+        else:
+            from ..ops.paged_kv import WALK_POSITIONS
+        self._walk_positions = WALK_POSITIONS
         # the engine timeline while the tracer is on, else None: set
         # once per step(), read by the phases underneath it
         self._tl = None
@@ -546,6 +606,9 @@ class ContinuousBatchingEngine:
         if self._hybrid:
             from ..models.granite_hybrid import build_hybrid_step
             return build_hybrid_step(cfg, self.BS)
+        if self._latent:
+            from ..models.glm_moe_lite import build_latent_step
+            return build_latent_step(cfg, self.BS)
         from ..models.llama import _rope_cos_sin
         from ..models.generation import _collapse_blocks
         from ..ops.decode_block import decode_block, decode_block_spec
@@ -599,6 +662,9 @@ class ContinuousBatchingEngine:
         if self._hybrid:
             from ..models.granite_hybrid import build_hybrid_chunk_fill
             return build_hybrid_chunk_fill(cfg, self.BS, Ts)
+        if self._latent:
+            from ..models.glm_moe_lite import build_latent_chunk_fill
+            return build_latent_chunk_fill(cfg, self.BS, Ts)
         from ..models.llama import _rope_cos_sin
         from ..models.generation import _collapse_blocks
         from ..ops.decode_block import decode_block_spec, prefill_block
@@ -1076,6 +1142,15 @@ class ContinuousBatchingEngine:
             if idx is None:
                 return
             cand = self.queue[idx]
+            # nobody to evict (every class equal: the usual case) ends
+            # the matter before the waiter's prefix is hashed and the
+            # prefix index walked, which cost per cached page, every
+            # iteration
+            victims = [s for s in range(self.B)
+                       if self.slots[s] is not None
+                       and self.slots[s].priority < cand.priority]
+            if not victims:
+                return
             snap = self._spill.get(cand.req_id)
             if snap is not None:
                 need, shared = snap.num_blocks, ()
@@ -1100,11 +1175,6 @@ class ContinuousBatchingEngine:
             have_slot = any(s is None for s in self.slots)
             if have_slot and self.alloc.free_blocks + evictable >= need:
                 return                 # admissible without eviction
-            victims = [s for s in range(self.B)
-                       if self.slots[s] is not None
-                       and self.slots[s].priority < cand.priority]
-            if not victims:
-                return
             releasable = sum(self._releasable_pages(s) for s in victims)
             if (self.alloc.free_blocks + evictable + releasable) < need:
                 return                 # eviction could never admit cand
@@ -1112,7 +1182,12 @@ class ContinuousBatchingEngine:
             # committed KV positions, then slot index (deterministic)
             victims.sort(key=lambda s: (self.slots[s].priority,
                                         int(self.lengths[s]), s))
+            # the pages' way out, on the timeline under ``admit``
+            tl = self._tl
+            sp = tl and tl.enter("kv_snapshot", slot=victims[0])
             self.preempt(victims[0])
+            if tl:
+                tl.leave(sp)
 
     def preempt(self, slot: int) -> int:
         """Evict the RUNNING request in ``slot`` for later resumption:
@@ -1194,6 +1269,8 @@ class ContinuousBatchingEngine:
                                        self.conv_state)))):
             return False
         ref = self.pool_k.data if self._kv_quant else self.pool_k
+        if (snap.v_pages.shape[-1] == 0) != self._latent:
+            return False           # a latent snapshot has no value pages
         return (snap.k_pages.shape[0] == ref.shape[0]
                 and snap.k_pages.shape[2:] == ref.shape[2:]
                 and snap.k_pages.dtype == ref.dtype
@@ -1238,8 +1315,12 @@ class ContinuousBatchingEngine:
         self.block_table[slot, :snap.num_blocks] = priv
         self.slot_pages[slot] = priv
         t0 = time.perf_counter()
+        tl = self._tl
+        sp = tl and tl.enter("kv_restore", blocks=snap.num_blocks)
         try:
             restore_into_slot(self, slot, snap)
+            if tl:
+                tl.leave(sp)
         except BaseException:
             # exactly-once release; the snapshot is unusable, so the
             # request is DROPPED from this engine (a supervising
@@ -1353,10 +1434,12 @@ class ContinuousBatchingEngine:
             # declared-bucket prefill (cold prompts AND cache-hit
             # suffixes): fixed chunk programs, no per-length jit
             return self._fill_prompt_bucketed(slot, req, L * self.BS)
-        if L or self.quant_config is not None or self._hybrid:
+        if L or self.quant_config is not None or self._hybrid \
+                or self._latent:
             # suffix-only prefill against the cached pages (a hybrid's
-            # whole prompt, start=0: the chunk fill is its one prefill
-            # program, the state's hand-over lives there).  Quantized
+            # or a latent model's whole prompt, start=0: the chunk fill
+            # is its one prefill program; a hybrid's hand-over of the
+            # state lives there).  Quantized
             # engines route COLD prompts here too (start=0): the dense
             # tier below computes full-width KV and scatters it into
             # the pool raw, which would skip both the quantized matmul
@@ -1660,14 +1743,15 @@ class ContinuousBatchingEngine:
         # the attention of this dispatch sees each row's stored tokens
         # plus the one it appends
         seen = self.lengths + 1
-        trips, chunk_pages = decode_walk(seen, self.MB, self.BS)
+        trips, chunk_pages = decode_walk(seen, self.MB, self.BS,
+                                         self._walk_positions)
         self.decode_pages_walked += trips * chunk_pages * self.B
         self.decode_pages_live += int(
             np.sum(-(-seen[active] // self.BS)))
         m0 = time.monotonic() if tl else 0.0
         sp = tl and tl.enter("decode_dispatch", batch=len(active))
-        # a hybrid's step also returns its expert layers' two counts
-        # and every row's first choice
+        # a hybrid's or a latent model's step also returns its expert
+        # layers' counts and every row's first choice
         logits, *extra = self._keep(self._step(
             self.params, *self._carried(), jnp.asarray(self.block_table),
             jnp.asarray(self.lengths), jnp.asarray(self.tokens)))
@@ -1682,13 +1766,16 @@ class ContinuousBatchingEngine:
             counts, greedy = (np.asarray(a) for a in extra)
             self.last_logits = logits
             fetched = counts.nbytes + greedy.nbytes
-            local, hit = (int(c) for c in counts)
+            local, hit, *peak = (int(c) for c in counts)
             cfg, m = self.cfg, self.moe
+            layers = getattr(cfg, "num_expert_layers", cfg.num_layers)
             m["moe_assignments_local"] += local
             m["moe_experts_hit"] += hit
             m["moe_assignments_total"] += \
-                len(active) * cfg.num_experts_per_tok * cfg.num_layers
-            m["moe_expert_slots"] += cfg.experts_held * cfg.num_layers
+                len(active) * cfg.num_experts_per_tok * layers
+            m["moe_expert_slots"] += cfg.experts_held * layers
+            if peak:
+                m["moe_peak_load"] += peak[0]
         else:
             self.last_logits = np.asarray(logits)
             fetched = self.last_logits.nbytes
@@ -1850,7 +1937,7 @@ class ContinuousBatchingEngine:
                 self.stats["prefill_tokens_computed"],
             "stalled_slot_iterations": self.stalled_slot_iterations,
             "decode_slot_steps": self.decode_slot_steps}
-        if self._hybrid:
+        if self._hybrid or self._latent:
             s.update(self.moe)
         return sched_ratios(s)
 

@@ -316,9 +316,12 @@ def paged_append(pool_k, pool_v, k_new, v_new, block_table, lengths,
     return pool_k, pool_v
 
 
-def decode_walk(lengths, max_blocks: int, block_size: int):
+def decode_walk(lengths, max_blocks: int, block_size: int,
+                positions: int = WALK_POSITIONS):
     """``(trips, chunk_pages)`` of :func:`paged_decode_attention`'s page
-    walk over a ``[B, max_blocks]`` table: each trip gathers
+    walk (and, at its own ``positions`` a trip, of
+    ``ops.mla.paged_latent_attention``'s) over a ``[B, max_blocks]``
+    table: each trip gathers
     ``chunk_pages`` columns of every row, and ``trips`` covers the
     longest of ``lengths`` (tokens valid per row, capped at the table's
     width).  ``chunk_pages`` is static: about :data:`WALK_POSITIONS`
@@ -328,7 +331,7 @@ def decode_walk(lengths, max_blocks: int, block_size: int):
     ``int`` for the numpy array the engine keeps on the host — ONE
     arithmetic, so the program's walk and the engine's
     ``decode_pages_walked`` cannot drift."""
-    per_chunk = max(1, WALK_POSITIONS // block_size)
+    per_chunk = max(1, positions // block_size)
     chunk_pages = -(-max_blocks // -(-max_blocks // per_chunk))
     xp = np if isinstance(lengths, np.ndarray) else jnp
     longest = xp.minimum(xp.max(lengths), max_blocks * block_size)

@@ -45,7 +45,9 @@ __all__ = ["inject_aux_grad", "topk_scatter_routing", "moe_ffn_ep",
            "moe_swiglu_ffn_ep", "moe_dispatch_combine", "compute_capacity",
            "schedule_aux_coef", "expert_choice_routing",
            "moe_expert_choice_ffn", "moe_swiglu_ffn_grouped",
-           "moe_swiglu_ffn_masked", "route_held", "moe_gelu_ffn_grouped"]
+           "moe_swiglu_ffn_masked", "route_held", "moe_gelu_ffn_grouped",
+           "route_sigmoid", "moe_swiglu_ffn_routed", "expert_counts",
+           "dispatch_capacity"]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -252,6 +254,158 @@ def _held_counts(held, local, n_held: int, mask=None):
                       jnp.sum(hit, dtype=jnp.int32)])
 
 
+def _experts_masked(tokens, w, local, wg, wu, wd):
+    """``sum_j w[t, j] E_local[t, j](tokens[t])`` as float32 ``[T, h]``,
+    every token through every expert of the bank as one batched matmul
+    and the gates (``local == E``: no expert of this bank) weighing the
+    sum."""
+    E = wg.shape[0]
+    # [T, E] gates: a token's gate for each held expert, else 0
+    dense = jnp.zeros((tokens.shape[0], E + 1), jnp.float32).at[
+        jnp.arange(tokens.shape[0])[:, None], local].add(w)[:, :E]
+    g = jnp.einsum("th,ehf->etf", tokens, wg)
+    u = jnp.einsum("th,ehf->etf", tokens, wu)
+    act = (jax.nn.silu(g) * u) * dense.T[..., None].astype(g.dtype)
+    return jnp.einsum("etf,efh->th", act, wd,
+                      preferred_element_type=jnp.float32)
+
+
+def route_sigmoid(logits: jax.Array, bias: jax.Array, top_k: int, *,
+                  n_group: int = 1, topk_group: int = 1,
+                  normalize: bool = True, scale: float = 1.0):
+    """The DeepSeek-V3 / GLM-4.x gate (``topk_method`` ``noaux_tc``) on
+    ``logits [T, E]`` float32: scores ``s = sigmoid(logits)``; the
+    CHOICE is the ``top_k`` largest of ``s + bias``
+    (``e_score_correction_bias``, a load-balancing offset), taken among
+    the ``topk_group`` groups of experts (of ``n_group``) whose two best
+    ``s + bias`` sum highest — the others' ``s + bias`` count as 0, as
+    in the public implementation; the WEIGHTS are ``s`` of the chosen,
+    without the bias, over their sum (``normalize``), times ``scale``
+    (``routed_scaling_factor``).  Returns ``(w [T, k] float32, idx [T,
+    k] int32)``."""
+    T, E = logits.shape
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    choice = s + bias.astype(jnp.float32)
+    if n_group > 1:
+        per = E // n_group
+        best2 = jnp.sum(lax.top_k(choice.reshape(T, n_group, per), 2)[0],
+                        axis=-1)
+        _, gi = lax.top_k(best2, topk_group)
+        keep = jnp.zeros((T, n_group), bool).at[
+            jnp.arange(T)[:, None], gi].set(True)
+        choice = jnp.where(jnp.repeat(keep, per, axis=1), choice, 0.0)
+    _, idx = lax.top_k(choice, top_k)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * scale, idx.astype(jnp.int32)
+
+
+def expert_counts(idx: jax.Array, n_experts: int, mask=None):
+    """Of the choices ``idx [T, k]`` of the tokens ``mask [T]`` names
+    (all when None), int32 ``[token-expert pairs, distinct experts hit,
+    the most pairs on ONE expert]``: what a serving step reports of an
+    expert layer that holds every expert (the last is the straggler an
+    expert-parallel deployment would wait for)."""
+    if mask is not None:
+        idx = jnp.where(mask[:, None], idx, n_experts)
+    load = jnp.zeros((n_experts + 1,), jnp.int32).at[
+        idx.reshape(-1)].add(1)[:n_experts]
+    return jnp.stack([jnp.sum(load), jnp.sum(load > 0, dtype=jnp.int32),
+                      jnp.max(load)])
+
+
+def dispatch_capacity(n_tokens: int, top_k: int, n_experts: int) -> int:
+    """Rows an expert's buffer holds in :func:`moe_swiglu_ffn_routed`'s
+    dispatched form: twice an even share, in whole sublanes of 8, and
+    never more than the tokens there are."""
+    even = -(-n_tokens * top_k // n_experts)
+    return min(n_tokens, -(-2 * even // 8) * 8)
+
+
+def moe_swiglu_ffn_routed(x: jax.Array, w: jax.Array, idx: jax.Array,
+                          wg: jax.Array, wu: jax.Array, wd: jax.Array, *,
+                          capacity: Optional[int] = None,
+                          layer=None) -> jax.Array:
+    """Exact SwiGLU MoE for routing done by the caller: ``sum_j w[t, j]
+    E_idx[t, j](x[t])`` over the bank ``wg/wu [E, h, f]``, ``wd [E, f,
+    h]``, every ``idx`` in ``[0, E)``.  With ``layer`` (a traced index)
+    the three are STACKS ``[n, E, ...]`` and that layer's bank is cut out
+    where it is multiplied, inside the branch that runs: a branch handed
+    a layer's bank as an operand is handed a copy of it (600 MB a layer
+    at GLM-4.7-Flash's sizes), where a matmul reads its slice of the
+    stack in place.
+
+    ``capacity=None``: the masked form (every token through every
+    expert, :func:`moe_swiglu_ffn_masked`'s): ``E / k`` times the FLOPs
+    the choices require and the weights read once — right for a decode
+    step's few rows, which the weights' bytes bind either way.
+
+    ``capacity=C``: the dispatched form, for a chunk fill: each expert
+    gathers the rows routed to it into ``C`` slots (assignments sorted
+    by expert; a gather each way, no scatter) and multiplies only
+    those, ``E x C`` rows where the masked form has ``E x T``
+    (:func:`dispatch_capacity`: 2 x the required FLOPs against 16 x at
+    4 of 64).  Static shapes, dense batched matmuls that read the bank
+    in place — ``lax.ragged_dot`` has no slack but copies a layer's bank
+    out of the stacked weights first (PERF.md, PR 30).  No token is
+    ever dropped: the slots are filled in ROUNDS, round ``r`` taking
+    each expert's assignments ``[r C, (r + 1) C)``, as many rounds as
+    the busiest expert needs (one, unless the routing is that uneven;
+    the count is computed where the program runs).  A loop and not a
+    ``lax.cond`` onto the masked form: the compiler gives a
+    conditional's operands ONE layout for both branches and copies the
+    whole stack of banks into it on every call (2 x 2.4 GB at
+    GLM-4.7-Flash's sizes)."""
+    shape = x.shape
+    tokens = x.reshape(-1, shape[-1])
+
+    def bank(a):
+        return a if layer is None else lax.dynamic_index_in_dim(
+            a, layer, 0, keepdims=False)
+
+    if capacity is None:
+        res = _experts_masked(tokens, w, idx, bank(wg), bank(wu), bank(wd))
+        return res.astype(x.dtype).reshape(shape)
+    T, k = idx.shape
+    E, C = wg.shape[-3], capacity
+    flat = idx.reshape(-1)                               # [T*k]
+    order = jnp.argsort(flat)                            # stable
+    load = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+    first = jnp.cumsum(load) - load                      # [E]
+    slot = jnp.argsort(order) - first[flat]              # [T*k]
+
+    def one_round(r, acc):
+        # the bank is cut out of the stack HERE, round by round: tied to
+        # the round, or the compiler moves the cut out of the loop and
+        # with it a copy of the layer's three banks (1.2 GB a layer)
+        at = layer if layer is None else lax.optimization_barrier(
+            (layer, r))[0]
+
+        def bank(a):
+            return a if at is None else lax.dynamic_index_in_dim(
+                a, at, 0, keepdims=False)
+
+        # slot c of expert e holds its (r C + c)-th assignment
+        src = order[jnp.minimum(
+            first[:, None] + r * C + jnp.arange(C)[None],
+            T * k - 1)] // k                             # [E, C] tokens
+        buf = tokens[src]                                # [E, C, h]
+        act = jax.nn.silu(jnp.einsum("ech,ehf->ecf", buf, bank(wg))) \
+            * jnp.einsum("ech,ehf->ecf", buf, bank(wu))
+        out = jnp.einsum("ecf,efh->ech", act, bank(wd))
+        c = slot - r * C
+        got = jnp.where(((c >= 0) & (c < C))[:, None],
+                        out[flat, jnp.clip(c, 0, C - 1)], 0)
+        return acc + jnp.sum(
+            w[..., None] * got.reshape(T, k, -1).astype(jnp.float32),
+            axis=1)
+
+    res = lax.fori_loop(0, -(-jnp.max(load) // C), one_round,
+                        jnp.zeros(tokens.shape, jnp.float32))
+    return res.astype(x.dtype).reshape(shape)
+
+
 def moe_swiglu_ffn_masked(x: jax.Array, router_w: jax.Array,
                           wg: jax.Array, wu: jax.Array, wd: jax.Array, *,
                           top_k: int = 2, normalize: bool = True,
@@ -282,14 +436,7 @@ def moe_swiglu_ffn_masked(x: jax.Array, router_w: jax.Array,
     logits = tokens.astype(jnp.float32) @ router_w.astype(jnp.float32)
     w, local, held = route_held(logits, top_k, E, normalize=normalize,
                                 gate=gate, expert_offset=expert_offset)
-    # [T, E] gates: a token's gate for each held expert, else 0
-    dense = jnp.zeros((tokens.shape[0], E + 1), jnp.float32).at[
-        jnp.arange(tokens.shape[0])[:, None], local].add(w)[:, :E]
-    g = jnp.einsum("th,ehf->etf", tokens, wg)
-    u = jnp.einsum("th,ehf->etf", tokens, wu)
-    act = (jax.nn.silu(g) * u) * dense.T[..., None].astype(g.dtype)
-    res = jnp.einsum("etf,efh->th", act, wd,
-                     preferred_element_type=jnp.float32)
+    res = _experts_masked(tokens, w, local, wg, wu, wd)
     res = res.astype(x.dtype).reshape(shape)
     if with_counts:
         return res, _held_counts(held, local, E, count_mask)

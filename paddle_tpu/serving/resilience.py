@@ -194,7 +194,15 @@ def snapshot_slot(engine, slot: int) -> KVSnapshot:
     pages = engine.slot_pages[slot]
     idx = np.asarray(pages[:used], np.int64)
     ks = vs = None
-    if is_quantized_pool(engine.pool_k):
+    if engine.pool_v is None:
+        # a latent pool: one vector a token, no value pages.  The pages
+        # come off the device through ONE fixed-width program (the
+        # table's width; unused columns read page 0 and are cut here):
+        # the pool itself, gigabytes of it, never visits the host
+        k = np.asarray(_page_programs()[0](
+            engine.pool_k, _table_width(idx, engine.MB, 0)))[:, :used]
+        v = k[..., :0]
+    elif is_quantized_pool(engine.pool_k):
         k = np.asarray(engine.pool_k.data)[:, idx].copy()
         v = np.asarray(engine.pool_v.data)[:, idx].copy()
         ks = np.asarray(engine.pool_k.scale)[:, idx].copy()
@@ -211,6 +219,27 @@ def snapshot_slot(engine, slot: int) -> KVSnapshot:
                       num_blocks=len(pages), k_pages=k, v_pages=v,
                       k_scale=ks, v_scale=vs, ssm_state=ssm,
                       conv_state=conv)
+
+
+def _table_width(pages: np.ndarray, width: int, fill: int) -> np.ndarray:
+    """``pages`` padded with ``fill`` to a table row's ``width``."""
+    out = np.full((width,), fill, np.int32)
+    out[:len(pages)] = pages
+    return out
+
+
+@functools.lru_cache(maxsize=1)
+def _page_programs():
+    """``(read, write)`` of a latent pool ``[L, NB, BS, W]``: the pages
+    a table row names, ``[L, MB, BS, W]``, out of the pool / into it
+    (an entry past the pool's last page writes nowhere).  One compiled
+    program each for an engine, whatever the slot and its length."""
+    import jax
+    read = jax.jit(lambda pool, idx: pool[:, idx])
+    write = jax.jit(
+        lambda pool, idx, pages: pool.at[:, idx].set(pages, mode="drop"),
+        donate_argnums=(0,))
+    return read, write
 
 
 @functools.lru_cache(maxsize=1)
@@ -262,6 +291,21 @@ def restore_into_slot(engine, slot: int, snap: KVSnapshot) -> None:
             snap.conv_state, np.int32(slot))
     used = snap.k_pages.shape[1]
     pages = np.asarray(engine.slot_pages[slot][:used], np.int64)
+    if engine.pool_v is None:
+        # a latent pool (see snapshot_slot): the rows past the
+        # snapshot's pages are aimed past the pool and dropped
+        if snap.v_pages.shape[-1]:
+            raise SpillCorruptError(
+                f"KV snapshot for request {snap.req_id} carries value "
+                "pages but the engine's cache is one latent pool — "
+                "replay from the committed token prefix")
+        rows = np.zeros((snap.k_pages.shape[0], engine.MB)
+                        + snap.k_pages.shape[2:], snap.k_pages.dtype)
+        rows[:, :used] = snap.k_pages
+        engine.pool_k = _page_programs()[1](
+            engine.pool_k,
+            _table_width(pages, engine.MB, engine.pool_k.shape[1]), rows)
+        return
     # jnp.array (owned copy), NOT jax.device_put/jnp.asarray: both can
     # zero-copy ALIAS the numpy buffer on CPU, and the decode step
     # DONATES the pools — XLA reusing memory numpy still owns is a

@@ -206,8 +206,8 @@ def test_engine_programs_move_no_byte_twice(chip, program, temp_mib):
     # the builders read these of an engine and nothing else: no pools,
     # no weights
     eng = object.__new__(ContinuousBatchingEngine)
-    eng.cfg, eng.BS, eng._hybrid, eng.quant_config = \
-        cfg, z["page"], False, None
+    eng.cfg, eng.BS, eng._hybrid, eng._latent, eng.quant_config = \
+        cfg, z["page"], False, False, None
     pool = chip((z["layers"], z["pages"], z["page"], cfg.kv_heads,
                  cfg.head_dim))
     i32 = jnp.int32
@@ -232,6 +232,70 @@ def test_engine_programs_move_no_byte_twice(chip, program, temp_mib):
     # no layer's pool is sliced out of the stack (or put back into it)
     assert "bf16[1024,16,8,128]" not in text
     assert "bf16[16384,16,8,128]" in text
+
+
+# the GLM-4.7-Flash serve cell's engine (benchmark/configs: layer 0 and
+# six expert layers at the published widths, 64 slots, 32768 pages of
+# 16, a table of 512)
+_LATENT = dict(layers=7, slots=64, pages=32768, page=16, table=512)
+
+
+@pytest.mark.parametrize("program, temp_mib", [
+    ("step", 256), ("fill512", 256), ("fill2048", 512)])
+def test_latent_engine_programs_fit_and_copy_no_pool(chip, program,
+                                                     temp_mib):
+    """The latent-attention engine's decode step and its 512 and 2048
+    chunk fills at the benchmark cell's sizes, from abstract arguments:
+    9.06 GB of weights and a 4.70 GB latent pool ride through whole, so
+    a program's temporaries stay in the megabytes, the whole fits the
+    chip's 15.75 GiB, and nothing pool- or bank-sized is copied.
+
+    What this holds (ISSUE 34's compiles): a pool row of 576 made every
+    program copy the pool into the padded layout and back (4.4 GB each
+    way: ``ops/mla.py``, ``pool_width``); an expert layer under
+    ``lax.cond``, or with its bank cut outside the rounds' loop, copied
+    a layer's three banks (1.2 GB) or the whole stacks (2 x 2.4 GB)
+    every call (``parallel/moe.py:moe_swiglu_ffn_routed``).  Now 86 MB /
+    12 MB / 180 MB of temporaries."""
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models.glm_moe_lite import (glm_4_7_flash,
+                                                init_glm_moe_lite_params)
+    z = _LATENT
+    cfg = glm_4_7_flash(num_hidden_layers=z["layers"])
+    params = on(chip, jax.eval_shape(
+        lambda: init_glm_moe_lite_params(cfg, 0)))
+    held = sum(math.prod(a.shape) for a in jax.tree.leaves(params))
+    assert held == 4530936960
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.cfg, eng.BS, eng._hybrid, eng._latent, eng.quant_config = \
+        cfg, z["page"], False, True, None
+    pool = chip((z["layers"], z["pages"], z["page"], cfg.pool_width))
+    i32 = jnp.int32
+    if program == "step":
+        fn, args = eng._build_step(), (
+            chip((z["slots"], z["table"]), i32), chip((z["slots"],), i32),
+            chip((z["slots"],), i32))
+    else:
+        Ts = int(program[4:])
+        fn, args = eng._build_chunk_fill(Ts), (
+            chip((z["table"],), i32), chip((), i32), chip((Ts,), i32),
+            chip((), i32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < temp_mib * _MIB
+    total = (m.temp_size_in_bytes + m.argument_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes
+             + m.generated_code_size_in_bytes)
+    assert total < 14.2e9, total            # of the chip's 16.9e9
+    text = compiled.as_text()
+    copied = [(dt, dims) for dt, dims in _COPY.findall(text)
+              if _BYTES.get(dt, 4) * math.prod(int(d) for d in dims.split(",")
+                                        if d) >= 64 * _MIB]
+    assert not copied, f"large arrays copied: {copied}"
+    # the pool rides as ONE pool of all layers' pages, in place
+    assert "bf16[229376,16,640]" in text
+    assert "bf16[32768,16,640]" not in text
 
 
 def test_decode_attention(chip):
