@@ -12,7 +12,8 @@ reads THIS module:
 * ``ops/pallas/linear_ce._tuned_blocks``' autotune candidate filter
   drops configs :func:`linear_ce_fits` rejects before ever timing them;
 * ``ops/pallas/ssm.py`` sizes its state block from
-  :func:`budget_bytes`.
+  :func:`budget_bytes`, ``ops/pallas/moe_grouped_matmul.py`` its weight
+  blocks.
 
 There is one table and one estimator, so the number the lint proves
 things about is the number the dispatches enforce.

@@ -542,6 +542,14 @@ class ContinuousBatchingEngine:
             # expert layers and the steps: against the pairs, how
             # uneven the routing is
             self.moe["moe_peak_load"] = 0
+            # the chunk fills' expert layers: token-expert pairs routed
+            # (a bucket's padded rows too) and the rows the experts'
+            # matmuls multiplied for them, summed on the device through
+            # a prompt's chunks (models/glm_moe_lite.py) and fetched
+            # where the engine waits for the prompt's first token
+            self.moe.update(moe_fill_pairs=0, moe_fill_rows=0)
+            self._moe_fill_zero = jnp.zeros((2,), jnp.int32)
+            self._moe_fill = self._moe_fill_zero
         # positions a trip of the decode program's page walk covers
         if self._latent:
             from ..ops.mla import WALK_POSITIONS
@@ -754,13 +762,26 @@ class ContinuousBatchingEngine:
 
     def _run_fill(self, fill, slot: int, bt_row, start, toks, *valid):
         """Call a compiled chunk fill for ``slot`` (a hybrid's fill is
-        told the slot: its state rows are that slot's); returns the
-        logits."""
-        where = (jnp.int32(slot),) if self._hybrid else ()
-        (logits,) = self._keep(fill(
-            self.params, *self._carried(), bt_row, start, toks, *where,
+        told the slot: its state rows are that slot's; a latent model's
+        is handed its expert layers' running counts and hands them
+        back); returns the logits."""
+        more = (jnp.int32(slot),) if self._hybrid else \
+            (self._moe_fill,) if self._latent else ()
+        logits, *counts = self._keep(fill(
+            self.params, *self._carried(), bt_row, start, toks, *more,
             *valid))
+        if counts:
+            (self._moe_fill,) = counts
         return logits
+
+    def _take_fill_counts(self) -> None:
+        """Bring a latent model's fill counts to the host (the caller is
+        about to wait for the same fills' logits) and start anew."""
+        if self._latent and self._moe_fill is not self._moe_fill_zero:
+            pairs, rows = (int(c) for c in np.asarray(self._moe_fill))
+            self.moe["moe_fill_pairs"] += pairs
+            self.moe["moe_fill_rows"] += rows
+            self._moe_fill = self._moe_fill_zero
 
     def _bucket_fill(self, size: int):
         """Compiled bucketed fill for a DECLARED chunk size: AOT-loaded
@@ -1576,6 +1597,7 @@ class ContinuousBatchingEngine:
                 sp = tl and tl.enter("first_token_fetch")
                 first = self._pick_token(req, np.asarray(logits)[0],
                                          position=T0)
+                self._take_fill_counts()
                 if tl:
                     tl.leave(sp)
             except BaseException:

@@ -25,9 +25,13 @@ prefix and the fresh chunk alike: at 512 queries a cached token costs
 of the others ``sum_j g_j E_j(x) + shared(x)`` with
 ``parallel/moe.py:route_sigmoid``'s gate (sigmoid scores in float32,
 the choice by ``score + bias``, the weights from the scores alone,
-renormalised, times ``routed_scaling_factor``), every expert held: a
-decode step's few rows through the masked form, a chunk fill's through
-the dispatched form (``moe_swiglu_ffn_routed``).
+renormalised, times ``routed_scaling_factor``), every expert held.
+``moe_swiglu_ffn_routed`` picks the form from the rows it is given: a
+decode step's few rows (and a chunk under the chip's FLOP-per-byte
+ridge) go through every expert, the masked form; a chunk fill's rows
+are sorted by expert and multiplied once each by a grouped matmul that
+reads every expert's weights once, in the stacked leaves
+(``ops/pallas/moe_grouped_matmul.py``).
 
 Parameter tree: ``{"wte" [V, H], "head" [H, V], "lnf_w" [H], "runs":
 (run, ...)}``, one ``run`` the layers of one kind (``cfg.runs()``:
@@ -225,13 +229,13 @@ _BANKS = ("e_gate", "e_up", "e_down")
 
 
 def _make_ffn_half(cfg: GlmMoeLiteConfig):
-    """``ffn_half(x [T, H], lp, kind, capacity=None, count_mask=None) ->
-    (x, counts)``: the second half of a layer; ``counts`` an expert
-    layer's ``parallel.moe.expert_counts`` over the tokens ``count_mask``
-    names (zeros for a dense layer).  ``capacity``: the dispatched form
-    at that many rows an expert (a chunk fill), else the masked form.
-    An expert layer's ``lp`` holds its run's three expert banks STACKED
-    and ``"bank"``, its place in them (``_scan_runs``)."""
+    """``ffn_half(x [T, H], lp, kind, count_mask=None) -> (x, counts,
+    rows)``: the second half of a layer; ``counts`` an expert layer's
+    ``parallel.moe.expert_counts`` over the tokens ``count_mask`` names,
+    ``rows`` the rows its experts' matmuls multiplied for its ``T k``
+    pairs (zeros for a dense layer).  An expert layer's ``lp`` holds its
+    run's three expert banks STACKED and ``"bank"``, its place in them
+    (``_scan_runs``)."""
     from ..ops.mla import rms_norm
     from ..parallel import moe
     E = cfg.n_routed_experts
@@ -239,12 +243,12 @@ def _make_ffn_half(cfg: GlmMoeLiteConfig):
     def swiglu(y, g, u, d):
         return ((jax.nn.silu(y @ g) * (y @ u)) @ d).astype(jnp.float32)
 
-    def ffn_half(x, lp, kind, capacity=None, count_mask=None):
+    def ffn_half(x, lp, kind, count_mask=None):
         y32 = rms_norm(x, lp["ln2_w"], cfg.rms_norm_eps)
         y = y32.astype(lp["ln2_w"].dtype)
         if kind == "dense":
             return x + swiglu(y, lp["gate_w"], lp["up_w"], lp["down_w"]), \
-                jnp.zeros((3,), jnp.int32)
+                jnp.zeros((3,), jnp.int32), jnp.int32(0)
         # the router reads the float32 rows, at full precision: a choice
         # among 64 scores a hundredth apart does not survive rounding
         # its 2048 inputs to bfloat16 (a few tokens in a hundred would
@@ -257,12 +261,12 @@ def _make_ffn_half(cfg: GlmMoeLiteConfig):
             logits, lp["router_b"], cfg.num_experts_per_tok,
             n_group=cfg.n_group, topk_group=cfg.topk_group,
             normalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor)
-        out = moe.moe_swiglu_ffn_routed(
+        out, rows = moe.moe_swiglu_ffn_routed(
             y, w, idx, lp["e_gate"], lp["e_up"], lp["e_down"],
-            capacity=capacity, layer=lp["bank"])
+            layer=lp["bank"])
         out = out.astype(jnp.float32) \
             + swiglu(y, lp["s_gate"], lp["s_up"], lp["s_down"])
-        return x + out, moe.expert_counts(idx, E, count_mask)
+        return x + out, moe.expert_counts(idx, E, count_mask), rows
 
     return ffn_half
 
@@ -287,8 +291,8 @@ def _scan_runs(cfg: GlmMoeLiteConfig, params, layer, carry):
     i) -> carry`` with ``i`` the layer's index among all layers.  The
     expert banks are not scanned: ``lp`` holds the run's stacks and
     ``lp["bank"]``, the layer's place in them, so that the expert layer
-    cuts its bank out where it multiplies it
-    (``moe_swiglu_ffn_routed``)."""
+    reads its bank where it lies (``moe_swiglu_ffn_routed``: a scanned
+    bank is a copy of it, 1.2 GB a layer at GLM-4.7-Flash's sizes)."""
     for (kind, n, first), run in zip(cfg.runs(), params["runs"]):
         banks = {k: run[k] for k in _BANKS if k in run}
         rest = {k: v for k, v in run.items() if k not in banks}
@@ -336,7 +340,7 @@ def build_latent_step(cfg: GlmMoeLiteConfig, block_size: int):
                 lengths + 1, spec.kv_lora_rank, spec.scale)
             x = x + (mla.lift_output(o, lp["uv_w"])
                      @ lp["o_w"]).astype(jnp.float32)
-            x, c = ffn_half(x, lp, kind, count_mask=live)
+            x, c, _ = ffn_half(x, lp, kind, count_mask=live)
             return x, pc, cnt + c
 
         x, pc, cnt = _scan_runs(
@@ -352,24 +356,25 @@ def build_latent_step(cfg: GlmMoeLiteConfig, block_size: int):
 def build_latent_chunk_fill(cfg: GlmMoeLiteConfig, block_size: int,
                             Ts: int):
     """The chunk fill of ONE sequence: ``fill(params, pool, bt_row,
-    start, toks [Ts], valid=None) -> (pool, logits [1, V])``: ``Ts``
-    prompt tokens from position ``start`` on, their latents written into
-    the row's pages, each attending over everything cached before it and
-    the chunk up to itself (``ops.mla.paged_expanded_attention``: the
-    walk ends at the chunk's last real token, whatever the table's
-    width).  With ``valid`` only the first ``valid`` tokens are real:
-    the padded rows write no page, and the logits come from row ``valid
-    - 1``."""
+    start, toks [Ts], moe_rows, valid=None) -> (pool, logits [1, V],
+    moe_rows)``: ``Ts`` prompt tokens from position ``start`` on, their
+    latents written into the row's pages, each attending over everything
+    cached before it and the chunk up to itself
+    (``ops.mla.paged_expanded_attention``: the walk ends at the chunk's
+    last real token, whatever the table's width).  With ``valid`` only
+    the first ``valid`` tokens are real: the padded rows write no page,
+    and the logits come from row ``valid - 1``.  ``moe_rows`` (int32
+    ``[2]``) rides through a prompt's chunks: each adds the token-expert
+    pairs its expert layers routed (padded rows are routed too) and the
+    rows their matmuls multiplied for them."""
     from ..ops import mla
     from ..ops.paged_kv import layer_pages, layers_as_one_pool
-    from ..parallel.moe import dispatch_capacity
     spec = mla_spec(cfg, block_size)
     ffn_half = _make_ffn_half(cfg)
     BS = block_size
-    capacity = dispatch_capacity(Ts, cfg.num_experts_per_tok,
-                                 cfg.n_routed_experts)
+    pairs = Ts * cfg.num_experts_per_tok * cfg.num_expert_layers
 
-    def fill(params, pool, bt_row, start, toks, valid=None):
+    def fill(params, pool, bt_row, start, toks, moe_rows, valid=None):
         pos = start + jnp.arange(Ts)
         real = jnp.arange(Ts) < (Ts if valid is None else valid)
         last = start + (Ts if valid is None else valid) - 1
@@ -379,7 +384,7 @@ def build_latent_chunk_fill(cfg: GlmMoeLiteConfig, block_size: int,
         L, NB = pool.shape[:2]
 
         def layer(carry, lp, kind, i):
-            x, pc = carry
+            x, pc, rows = carry
             y = mla.rms_norm(x, lp["ln1_w"], cfg.rms_norm_eps).astype(
                 pc.dtype)
             q_n, q_r, latent = mla.project(y, lp, pos, spec)
@@ -390,13 +395,15 @@ def build_latent_chunk_fill(cfg: GlmMoeLiteConfig, block_size: int,
                 q_n, q_r, pc, layer_pages(bt_row, i, NB), pos, last,
                 lp["uk_w"], lp["uv_w"], spec.scale)
             x = x + (o @ lp["o_w"]).astype(jnp.float32)
-            x, _ = ffn_half(x, lp, kind, capacity=capacity)
-            return x, pc
+            x, _, r = ffn_half(x, lp, kind)
+            return x, pc, rows + r
 
-        x, pc = _scan_runs(cfg, params, layer,
-                           (x, layers_as_one_pool(pool)))
+        x, pc, rows = _scan_runs(
+            cfg, params, layer,
+            (x, layers_as_one_pool(pool), jnp.int32(0)))
         row = x[-1:] if valid is None \
             else jax.lax.dynamic_slice_in_dim(x, valid - 1, 1)
-        return layers_as_one_pool(pc, like=pool), _head(cfg, params, row)
+        return (layers_as_one_pool(pc, like=pool), _head(cfg, params, row),
+                moe_rows + jnp.stack([jnp.int32(pairs), rows]))
 
     return fill

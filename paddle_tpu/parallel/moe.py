@@ -46,8 +46,7 @@ __all__ = ["inject_aux_grad", "topk_scatter_routing", "moe_ffn_ep",
            "schedule_aux_coef", "expert_choice_routing",
            "moe_expert_choice_ffn", "moe_swiglu_ffn_grouped",
            "moe_swiglu_ffn_masked", "route_held", "moe_gelu_ffn_grouped",
-           "route_sigmoid", "moe_swiglu_ffn_routed", "expert_counts",
-           "dispatch_capacity"]
+           "route_sigmoid", "moe_swiglu_ffn_routed", "expert_counts"]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -315,95 +314,105 @@ def expert_counts(idx: jax.Array, n_experts: int, mask=None):
                       jnp.max(load)])
 
 
-def dispatch_capacity(n_tokens: int, top_k: int, n_experts: int) -> int:
-    """Rows an expert's buffer holds in :func:`moe_swiglu_ffn_routed`'s
-    dispatched form: twice an even share, in whole sublanes of 8, and
-    never more than the tokens there are."""
-    even = -(-n_tokens * top_k // n_experts)
-    return min(n_tokens, -(-2 * even // 8) * 8)
+#: rows under which :func:`moe_swiglu_ffn_routed` multiplies every row
+#: by every expert: that form's FLOPs per byte of bank ARE its row
+#: count, so under the chip's ridge (197e12 FLOP/s over 819e9 B/s on a
+#: TPU v5e) the bank's bytes bind it whichever rows are multiplied
+RIDGE_ROWS = 240
+
+
+def _experts_grouped(tokens, w, idx, wg, wu, wd, E, base):
+    """``sum_j w[t, j] E_idx[t, j](tokens[t])`` as float32 ``[T, h]``
+    and the rows multiplied for it (int32).  ``wg/wu [G, h, f]``, ``wd
+    [G, f, h]``: EVERY layer's experts, this layer's ``E`` from ``base``
+    on.  The pairs are sorted by expert and laid out in tiles
+    of ``tm`` rows, an expert's rows padded up to whole tiles (gathers
+    only, no scatter); gate and up with the SwiGLU are one grouped
+    matmul over the tiles that hold a row, down another
+    (``ops/pallas/moe_grouped_matmul.py``); each pair's row is gathered
+    back and the gates weigh the float32 sum."""
+    from ..ops.pallas.moe_grouped_matmul import (grouped_tiles,
+                                                 moe_grouped_matmul)
+    T, k = idx.shape
+    h, f = wg.shape[-2:]
+    item = tokens.dtype.itemsize
+    tm, tn_up = grouped_tiles(T * k, E, h, f, 2, item)
+    _, tn_down = grouped_tiles(T * k, E, f, h, 1, item)
+    flat = idx.reshape(-1)                               # [T k] pairs
+    order = jnp.argsort(flat)                            # stable
+    load = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+    first = jnp.cumsum(load) - load                      # in sorted order
+    tiles = -(-load // tm)                               # [E] tiles each
+    tile_end = jnp.cumsum(tiles)
+    tile0 = tile_end - tiles
+    visited = tile_end[-1]
+    # the static bound: no routing needs more tiles than this
+    n_tiles = (T * k + E * (tm - 1)) // tm
+    # tile t is expert e's: the steps past the last real tile name it again
+    t = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32), visited - 1)
+    e = jnp.searchsorted(tile_end, t, side="right").astype(jnp.int32)
+    # row r of tile t is the expert's ((t - tile0) tm + r)-th pair; the
+    # padding rows read some token and are multiplied for nothing
+    at = first[e][:, None] + (t - tile0[e])[:, None] * tm \
+        + jnp.arange(tm, dtype=jnp.int32)[None]
+    src = order[jnp.minimum(at, T * k - 1)] // k         # [tiles, tm]
+    x = tokens[src.reshape(-1)]                          # [tiles tm, h]
+    act = moe_grouped_matmul(x, (wg, wu), base + e, t, visited, tm, tn_up)
+    out = moe_grouped_matmul(act, (wd,), base + e, t, visited, tm, tn_down)
+    # a pair's row: its expert's first tile, then its place among the
+    # expert's pairs
+    row = tile0[flat] * tm + jnp.argsort(order) - first[flat]
+    got = out[row].reshape(T, k, h).astype(jnp.float32)
+    return jnp.sum(w[..., None] * got, axis=1), visited * tm
 
 
 def moe_swiglu_ffn_routed(x: jax.Array, w: jax.Array, idx: jax.Array,
                           wg: jax.Array, wu: jax.Array, wd: jax.Array, *,
-                          capacity: Optional[int] = None,
-                          layer=None) -> jax.Array:
+                          layer=None):
     """Exact SwiGLU MoE for routing done by the caller: ``sum_j w[t, j]
     E_idx[t, j](x[t])`` over the bank ``wg/wu [E, h, f]``, ``wd [E, f,
-    h]``, every ``idx`` in ``[0, E)``.  With ``layer`` (a traced index)
-    the three are STACKS ``[n, E, ...]`` and that layer's bank is cut out
-    where it is multiplied, inside the branch that runs: a branch handed
-    a layer's bank as an operand is handed a copy of it (600 MB a layer
-    at GLM-4.7-Flash's sizes), where a matmul reads its slice of the
-    stack in place.
+    h]``, every ``idx`` in ``[0, E)``; no token is dropped for any
+    routing.  With ``layer`` (a traced index) the three are STACKS ``[n,
+    E, ...]`` of which that layer's bank is meant.  Returns ``(out, rows)``,
+    ``rows`` (int32) the rows the experts' matmuls multiplied for the
+    ``T k`` token-expert pairs.
 
-    ``capacity=None``: the masked form (every token through every
-    expert, :func:`moe_swiglu_ffn_masked`'s): ``E / k`` times the FLOPs
-    the choices require and the weights read once — right for a decode
-    step's few rows, which the weights' bytes bind either way.
+    The form follows from the rows there are, a static shape:
 
-    ``capacity=C``: the dispatched form, for a chunk fill: each expert
-    gathers the rows routed to it into ``C`` slots (assignments sorted
-    by expert; a gather each way, no scatter) and multiplies only
-    those, ``E x C`` rows where the masked form has ``E x T``
-    (:func:`dispatch_capacity`: 2 x the required FLOPs against 16 x at
-    4 of 64).  Static shapes, dense batched matmuls that read the bank
-    in place — ``lax.ragged_dot`` has no slack but copies a layer's bank
-    out of the stacked weights first (PERF.md, PR 30).  No token is
-    ever dropped: the slots are filled in ROUNDS, round ``r`` taking
-    each expert's assignments ``[r C, (r + 1) C)``, as many rounds as
-    the busiest expert needs (one, unless the routing is that uneven;
-    the count is computed where the program runs).  A loop and not a
-    ``lax.cond`` onto the masked form: the compiler gives a
-    conditional's operands ONE layout for both branches and copies the
-    whole stack of banks into it on every call (2 x 2.4 GB at
-    GLM-4.7-Flash's sizes)."""
+    * under ``RIDGE_ROWS`` (a decode step's few rows, a short chunk)
+      the masked form, :func:`moe_swiglu_ffn_masked`'s: every token
+      through every expert, ``E / k`` times the FLOPs the choices
+      require and the weights read once, which is what binds it
+      (``rows = T E``);
+    * from there on (a chunk fill) the grouped form,
+      :func:`_experts_grouped`: each routed row is multiplied once, but
+      for the padding of each expert's last tile, and each expert's
+      weights are read once, where they lie: the kernel is handed the
+      WHOLE stacks (viewed ``[n E, ...]``, a bitcast) and finds the
+      layer's experts by index, so nothing is cut out of them.
+      ``lax.ragged_dot`` is handed one layer's bank, which the compiler
+      first copies out of the stack (1.2 GB a layer at GLM-4.7-Flash's
+      sizes; PERF.md, PR 30), and a fixed capacity an expert multiplies
+      its padding and needs rounds where the routing is uneven (PERF.md,
+      PR 35)."""
     shape = x.shape
     tokens = x.reshape(-1, shape[-1])
-
-    def bank(a):
-        return a if layer is None else lax.dynamic_index_in_dim(
-            a, layer, 0, keepdims=False)
-
-    if capacity is None:
-        res = _experts_masked(tokens, w, idx, bank(wg), bank(wu), bank(wd))
-        return res.astype(x.dtype).reshape(shape)
-    T, k = idx.shape
-    E, C = wg.shape[-3], capacity
-    flat = idx.reshape(-1)                               # [T*k]
-    order = jnp.argsort(flat)                            # stable
-    load = jnp.zeros((E,), jnp.int32).at[flat].add(1)
-    first = jnp.cumsum(load) - load                      # [E]
-    slot = jnp.argsort(order) - first[flat]              # [T*k]
-
-    def one_round(r, acc):
-        # the bank is cut out of the stack HERE, round by round: tied to
-        # the round, or the compiler moves the cut out of the loop and
-        # with it a copy of the layer's three banks (1.2 GB a layer)
-        at = layer if layer is None else lax.optimization_barrier(
-            (layer, r))[0]
-
+    T, E = tokens.shape[0], wg.shape[-3]
+    if T < RIDGE_ROWS:
         def bank(a):
-            return a if at is None else lax.dynamic_index_in_dim(
-                a, at, 0, keepdims=False)
+            return a if layer is None else lax.dynamic_index_in_dim(
+                a, layer, 0, keepdims=False)
 
-        # slot c of expert e holds its (r C + c)-th assignment
-        src = order[jnp.minimum(
-            first[:, None] + r * C + jnp.arange(C)[None],
-            T * k - 1)] // k                             # [E, C] tokens
-        buf = tokens[src]                                # [E, C, h]
-        act = jax.nn.silu(jnp.einsum("ech,ehf->ecf", buf, bank(wg))) \
-            * jnp.einsum("ech,ehf->ecf", buf, bank(wu))
-        out = jnp.einsum("ecf,efh->ech", act, bank(wd))
-        c = slot - r * C
-        got = jnp.where(((c >= 0) & (c < C))[:, None],
-                        out[flat, jnp.clip(c, 0, C - 1)], 0)
-        return acc + jnp.sum(
-            w[..., None] * got.reshape(T, k, -1).astype(jnp.float32),
-            axis=1)
+        res = _experts_masked(tokens, w, idx, bank(wg), bank(wu), bank(wd))
+        rows = jnp.int32(T * E)
+    else:
+        def whole(a):
+            return a.reshape((-1,) + a.shape[-2:])
 
-    res = lax.fori_loop(0, -(-jnp.max(load) // C), one_round,
-                        jnp.zeros(tokens.shape, jnp.float32))
-    return res.astype(x.dtype).reshape(shape)
+        res, rows = _experts_grouped(
+            tokens, w, idx, whole(wg), whole(wu), whole(wd), E,
+            0 if layer is None else layer * E)
+    return res.astype(x.dtype).reshape(shape), rows
 
 
 def moe_swiglu_ffn_masked(x: jax.Array, router_w: jax.Array,

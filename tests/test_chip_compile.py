@@ -167,6 +167,9 @@ def test_rope_fwd_bwd(chip):
 _SERVE = dict(layers=16, slots=32, pages=1024, page=16, table=256)
 _MIB = 1 << 20
 _COPY = re.compile(r"= (\w+)\[([\d,]*)\]\S* copy(?:-start)?\(")
+# one layer's bank of GLM-4.7-Flash's experts as the result of a slice
+_BANK_CUT = re.compile(r"= bf16\[(?:1,)?64,(?:2048,1536|1536,2048)\]\S* "
+                       r"(?:dynamic-)?slice\(")
 _BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
           "u32": 4, "f32": 4}
 
@@ -253,10 +256,12 @@ def test_latent_engine_programs_fit_and_copy_no_pool(chip, program,
     What this holds (ISSUE 34's compiles): a pool row of 576 made every
     program copy the pool into the padded layout and back (4.4 GB each
     way: ``ops/mla.py``, ``pool_width``); an expert layer under
-    ``lax.cond``, or with its bank cut outside the rounds' loop, copied
-    a layer's three banks (1.2 GB) or the whole stacks (2 x 2.4 GB)
-    every call (``parallel/moe.py:moe_swiglu_ffn_routed``).  Now 86 MB /
-    12 MB / 180 MB of temporaries."""
+    ``lax.cond``, or handed a layer's bank as an operand, copied a
+    layer's three banks (1.2 GB) or the whole stacks (2 x 2.4 GB) every
+    call.  The fills' grouped matmuls (ISSUE 35) are handed the stacks
+    whole and find the layer's experts by index: no bank is the result
+    of a slice in a fill's program.  Now 86 MB / 9 MB / 188 MB of
+    temporaries."""
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
     from paddle_tpu.models.glm_moe_lite import (glm_4_7_flash,
                                                 init_glm_moe_lite_params)
@@ -279,7 +284,7 @@ def test_latent_engine_programs_fit_and_copy_no_pool(chip, program,
         Ts = int(program[4:])
         fn, args = eng._build_chunk_fill(Ts), (
             chip((z["table"],), i32), chip((), i32), chip((Ts,), i32),
-            chip((), i32))
+            chip((2,), i32), chip((), i32))
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pool, *args).compile()
     m = compiled.memory_analysis()
@@ -296,6 +301,10 @@ def test_latent_engine_programs_fit_and_copy_no_pool(chip, program,
     # the pool rides as ONE pool of all layers' pages, in place
     assert "bf16[229376,16,640]" in text
     assert "bf16[32768,16,640]" not in text
+    if program != "step":
+        # a fill's grouped matmuls find the layer's experts in the stacks
+        assert not _BANK_CUT.search(text), "a bank was cut out"
+        assert text.count("moe_grouped_matmul") >= 2
 
 
 def test_decode_attention(chip):
@@ -317,6 +326,28 @@ def test_ssm_state_update(chip):
         chip((128,), f32), chip((9, 64, 128, 64, 128), f32),
         chip((), jnp.int32))
     assert "ssm_state_update" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tokens", [512, 2048])
+def test_moe_grouped_matmul(chip, tokens):
+    """The chunk fills' expert layer alone (``parallel/moe.py``'s grouped
+    form: the sort, the two ``moe_grouped_matmul`` calls, the gathers)
+    at GLM-4.7-Flash's widths and the cell's two chunk sizes, its bank
+    one layer of a 6-layer stack that the kernels are handed whole: no
+    bank is cut out of it."""
+    from paddle_tpu.parallel.moe import moe_swiglu_ffn_routed
+    E, k, h, f = 64, 4, 2048, 1536
+    up, down = chip((6, E, h, f)), chip((6, E, f, h))
+    compiled = compile_kernel(
+        lambda x, w, idx, g, u, d, i: moe_swiglu_ffn_routed(
+            x, w, idx, g, u, d, layer=i),
+        chip((tokens, h)), chip((tokens, k), jnp.float32),
+        chip((tokens, k), jnp.int32), up, up, down, chip((), jnp.int32),
+        kernels=2)
+    text = compiled.as_text()
+    assert text.count("moe_grouped_matmul") >= 2
+    assert not _BANK_CUT.search(text), "a bank was cut out of the stack"
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * _MIB
 
 
 def test_quant_linear_int8(chip):

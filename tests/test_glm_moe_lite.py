@@ -17,6 +17,7 @@ from benchmark.programs import glm_moe_lite as prog
 from benchmark.reference import glm_moe_lite_ref as ref
 from paddle_tpu.inference.serving import ContinuousBatchingEngine
 from paddle_tpu.ops import mla
+from paddle_tpu.ops.pallas import moe_grouped_matmul
 from paddle_tpu.parallel import moe
 
 SEED = 7
@@ -246,36 +247,86 @@ def test_gate_equals_the_reference(groups, kept):
         assert int((jnp.sort(free, -1) != jnp.sort(idx, -1)).any(-1).sum())
 
 
-def _bank(E=8, H=64, F=32, seed=6):
+def _bank(E=8, H=64, F=32, seed=6, T=40):
     k = jax.random.split(jax.random.key(seed), 4)
-    return (jax.random.normal(k[0], (40, H)),
+    return (jax.random.normal(k[0], (T, H)),
             0.1 * jax.random.normal(k[1], (E, H, F)),
             0.1 * jax.random.normal(k[2], (E, H, F)),
             0.1 * jax.random.normal(k[3], (E, F, H)))
 
 
-@pytest.mark.parametrize("capacity", [None, 24, 40, 3])
-def test_dispatched_form_equals_the_masked_form(capacity):
-    """The chunk fill's form (each expert multiplies only the rows
-    routed to it, in ``capacity`` slots) = the masked form = a loop over
-    experts, on the same tokens; a batch that loads an expert past the
-    capacity (3 slots for 10 rows an expert) takes the masked form
-    inside the same program and drops nothing."""
-    x, wg, wu, wd = _bank()
-    logits, bias = _routing(T=40)
-    w, idx = moe.route_sigmoid(logits, bias, 2, scale=1.8)
+def _stacked(bank, n, layer):
+    """``bank`` as layer ``layer`` of ``n``, the other layers NaN: an
+    index into the wrong layer's experts shows."""
+    return tuple(jnp.full((n,) + a.shape, jnp.nan).at[layer].set(a)
+                 for a in bank)
+
+
+def _even(T, E, k):
+    return (jnp.arange(T)[:, None] * k + jnp.arange(k)[None]) % E
+
+
+@pytest.mark.parametrize("case, T, layers, layer, choose", [
+    ("even", 256, None, None, _even),
+    ("one_expert_takes_every_row", 256, None, None,
+     lambda T, E, k: jnp.full((T, k), 5)),
+    ("experts_with_no_row", 256, None, None,
+     lambda T, E, k: _even(T, 3, k) * 2),
+    ("rows_no_multiple_of_the_tile", 301, None, None, None),
+    ("first_layer_of_a_stack", 256, 3, 0, None),
+    ("last_layer_of_a_stack", 256, 3, 2, None),
+    ("under_the_ridge", 40, 3, 1, None),
+])
+def test_grouped_form_equals_the_loop_over_experts(case, T, layers, layer,
+                                                   choose):
+    """``moe_swiglu_ffn_routed`` = a loop over experts on the same
+    tokens, for any routing (``choose``: the router's own where None):
+    the grouped form (rows sorted by expert, each expert's rows in whole
+    tiles, ``moe_grouped_matmul`` over the tiles that hold a row; the
+    kernel interpreted here) from ``RIDGE_ROWS`` tokens on, the masked
+    form under it, with no kernel in the program.  A bank inside a stack
+    is found by index: the other layers' weights are NaN."""
+    E, k = 8, 2
+    x, *bank = _bank(T=T)
+    logits, bias = _routing(T=T)
+    w, idx = moe.route_sigmoid(logits, bias, k, scale=1.8)
+    if choose is not None:
+        idx = choose(T, E, k).astype(jnp.int32)
+    wg, wu, wd = bank
     want = sum(
         jnp.where(idx == e, w, 0.0).sum(-1)[:, None]
         * ((jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
-        for e in range(8))
-    got = jax.jit(lambda *a: moe.moe_swiglu_ffn_routed(
-        *a, capacity=capacity))(x, w, idx, wg, wu, wd)
+        for e in range(E))
+    if layers is not None:
+        bank = _stacked(bank, layers, layer)
+    fn = lambda *a: moe.moe_swiglu_ffn_routed(
+        *a, layer=None if layer is None else jnp.int32(layer))
+    got, rows = jax.jit(fn)(x, w, idx, *bank)
     np.testing.assert_allclose(got, want,
                                atol=1e-5 * float(jnp.abs(want).max()))
-    assert moe.dispatch_capacity(40, 2, 8) == 24
-    assert moe.dispatch_capacity(512, 4, 64) == 64
-    assert moe.dispatch_capacity(2048, 4, 64) == 256
-    assert moe.dispatch_capacity(4, 2, 8) == 4       # never past T
+    grouped = "pallas_call" in str(jax.make_jaxpr(fn)(x, w, idx, *bank))
+    assert grouped == (T >= moe.RIDGE_ROWS)
+    if grouped:
+        # whole tiles of each expert's rows: never fewer rows than
+        # pairs, never a tile more an expert than its rows need
+        tm = moe_grouped_matmul.grouped_tiles(T * k, E, 64, 32, 2, 4)[0]
+        load = np.bincount(np.asarray(idx).reshape(-1), minlength=E)
+        assert int(rows) == int((-(-load // tm) * tm).sum())
+        assert T * k <= int(rows) < T * k + E * tm
+    else:
+        assert int(rows) == T * E
+
+
+def test_grouped_tiles_follow_the_shape():
+    """The cell's two chunks at GLM-4.7-Flash's widths: a row tile the
+    height of an expert's even share (at most the MXU's 128), column
+    blocks whose double-buffered weights fit the kernel's budget."""
+    tiles = moe_grouped_matmul.grouped_tiles
+    assert tiles(2048 * 4, 64, 2048, 1536, 2, 2) == (128, 512)
+    assert tiles(2048 * 4, 64, 1536, 2048, 1, 2) == (128, 1024)
+    assert tiles(512 * 4, 64, 2048, 1536, 2, 2) == (32, 512)
+    assert tiles(256 * 2, 8, 64, 32, 2, 4) == (64, 32)     # toy widths
+    assert tiles(3, 8, 64, 32, 1, 4) == (8, 32)            # a sublane pack
 
 
 def test_expert_counts():
@@ -388,6 +439,52 @@ def test_counters_and_prefix_cache():
     assert ps["enabled"] is True and ps["hits"] - hits0 == 1
     np.testing.assert_array_equal(outs[0], outs[1])
     assert eng.kv_leak_report()["leaked"] == 0
+
+
+def test_a_wide_bucket_fills_through_the_grouped_form():
+    """The file's other engines fill in buckets of (8, 16), under
+    ``RIDGE_ROWS``: the masked form.  A bucket of 256 takes the grouped
+    form (the kernel interpreted): a prompt of four chunks (256, 16, 16,
+    12 in 16) serves what the float32 reference computes, and the fills'
+    counts say what was multiplied: every dispatched row routed (padded
+    rows too), at least a row a pair."""
+    eng = _engine(prefill_buckets=(16, 256), max_batch=2, num_blocks=96,
+                  max_blocks_per_seq=80)
+    before = _stats(eng)
+    prompt, new = _prompts((300,), seed=4)[0], 4
+    (seq, logits), = _serve(eng, [prompt], [new]).values()
+    want = _ref_logits(seq, pad_to=320)[len(prompt) - 1:len(seq) - 1]
+    np.testing.assert_allclose(np.stack(logits[:new]), want,
+                               atol=2e-4 * np.abs(want).max())
+    assert (want.argmax(-1) == seq[len(prompt):]).all()
+    s = _since(eng, before)
+    k, layers = CONFIG["num_experts_per_tok"], 2       # expert layers
+    assert s["prefill_chunks"] == 4
+    assert s["prefill_tokens_dispatched"] == 256 + 3 * 16
+    assert s["moe_fill_pairs"] == s["prefill_tokens_dispatched"] * k * layers
+    # the 256 chunk: whole tiles of 64 rows, at most one more an expert
+    # than its rows fill; the 16-row chunks: every row by all 8 experts
+    small = 3 * 16 * 8 * layers
+    assert 256 * k * layers <= s["moe_fill_rows"] - small \
+        < (256 * k + 8 * 64) * layers
+    assert eng.kv_leak_report()["leaked"] == 0
+
+
+def test_the_fill_slack_metric_reads_the_two_counters():
+    """``benchmark/metrics/moe_fill_slack.glm47flash.json``: rows over
+    pairs of the window's counters, as ``scheduler_stats()`` names them;
+    a program without them (the parent commit) leaves the metric out of
+    the line."""
+    from benchmark.lib import cell, model
+    (m,) = [m for m in cell.load_metrics("glm47flash-long", model.HERE)
+            if m["name"] == "moe_fill_slack.glm47flash"]
+    assert {m["numerator"], m["denominator"]} <= set(
+        _engine().scheduler_stats())
+    got = cell.reduce_metrics([m], {"counters": {
+        "moe_fill_rows": 6 * (8192 + 4096), "moe_fill_pairs": 6 * 8192}})
+    assert got == {"moe_fill_slack.glm47flash":
+                   {"value": 1.5, "unit": "rows/pair"}}
+    assert cell.reduce_metrics([m], {"counters": {"decode_steps": 3}}) == {}
 
 
 def test_greedy_rows_are_picked_on_the_device():
