@@ -23,6 +23,7 @@ pinned by tests/test_serving_engine.py against a batch-of-one engine.
 from __future__ import annotations
 
 import collections
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
@@ -193,6 +194,19 @@ def sched_ratios(s: Dict[str, object]) -> Dict[str, object]:
     return s
 
 
+def _model_module(cfg):
+    """The module that defines ``cfg``'s class when it is a family's
+    own serving module, else None (the Llama family, whose programs the
+    engine builds itself).  Such a module offers ``build_step(cfg,
+    block_size)`` and ``build_chunk_fill(cfg, block_size, Ts)``, the
+    programs over the engine's ``_carry``; ``init_slot_state(cfg,
+    max_batch)`` where its configuration has ``layer_types``; and may
+    offer ``kernel_tiers(cfg, state_shape)``.  A further family is a
+    further module, and no branch here."""
+    mod = sys.modules.get(type(cfg).__module__)
+    return mod if hasattr(mod, "build_step") else None
+
+
 def _refuse(model: str, mechanisms) -> None:
     """Raise for the first ``(name, given, why)`` whose ``given`` is set:
     what the engine cannot do for ``model`` yet is refused by name."""
@@ -214,11 +228,16 @@ class ContinuousBatchingEngine:
     recurrent state and a conv tail per decode slot (below), or a
     decoder with latent attention (``cfg.kv_lora_rank``,
     ``models/glm_moe_lite.py``), whose cache is ONE pool of a vector a
-    token and no value pool (below).
+    token and no value pool (below).  The two kinds COMPOSE: a model
+    with both (``models/ling_linear.py``: delta-rule linear attention
+    with one latent-attention layer in six) gets the latent pool for its
+    attending layers and state rows for the others.  A family other than
+    Llama's brings its programs in its configuration's own module
+    (:func:`_model_module`).
 
     Args:
       cfg: LlamaConfig (dense or MoE — the FFN follows the config), a
-        GraniteHybridConfig, or a GlmMoeLiteConfig.
+        GraniteHybridConfig, a GlmMoeLiteConfig, or a LingLinearConfig.
       params: the family's param pytree (Llama: the train-step tree,
         wte/head/lnf_w + stacked blocks; the other two: wte/lnf_w (and
         an untied head) + one stacked tree a run of layers of one
@@ -330,6 +349,12 @@ class ContinuousBatchingEngine:
     (``serving/resilience.py``), not through a host copy of the pool.
     ``spec_config`` / ``quant_config`` / ``aot_dir`` and a prefix
     cache's host offload tier raise ``NotImplementedError``.
+
+    Models with both (``layer_types`` AND ``kv_lora_rank``): ``_carry``
+    is ``("pool_k", "ssm_state", "conv_state")``, the pool ``[attending
+    layers, NB, BS, W]``; the prefix cache is off (state); a preempted
+    slot's snapshot carries its latent pages (through the page
+    programs) AND its state rows; what either kind refuses is refused.
     """
 
     def __init__(self, cfg, params, *, max_batch: int = 4,
@@ -441,15 +466,15 @@ class ContinuousBatchingEngine:
         # per-slot recurrent state beside the pages (hybrid models)
         self.ssm_state = self.conv_state = None
         if self._hybrid:
-            from ..models.granite_hybrid import init_slot_state
-            self.ssm_state, self.conv_state = init_slot_state(
-                cfg, max_batch)
+            self.ssm_state, self.conv_state = \
+                _model_module(cfg).init_slot_state(cfg, max_batch)
         #: what every compiled program is given after the params,
-        #: donated, and hands back first: the pools, and a hybrid's two
-        #: state arrays (``_carried`` / ``_keep``)
-        self._carry = ("pool_k",) if self._latent else (
-            ("pool_k", "pool_v") + (
-                ("ssm_state", "conv_state") if self._hybrid else ()))
+        #: donated, and hands back first: the pools (one for a latent
+        #: cache), and the two state arrays of a model with per-slot
+        #: state (``_carried`` / ``_keep``)
+        self._carry = (("pool_k",) if self._latent
+                       else ("pool_k", "pool_v")) + (
+            ("ssm_state", "conv_state") if self._hybrid else ())
         self._donate = tuple(range(1, 1 + len(self._carry)))
         self.block_table = np.full((max_batch, self.MB), -1, np.int32)
         self.lengths = np.zeros((max_batch,), np.int32)
@@ -530,6 +555,9 @@ class ContinuousBatchingEngine:
         # and those of them that hold a live token of an active slot
         self.decode_pages_walked = 0
         self.decode_pages_live = 0
+        # the decode program's state updates (a model with per-slot
+        # state): live slots x recurrent layers, per decode dispatch
+        self.state_slot_steps = 0
         # expert layers that hold a share of the router's experts
         # (hybrid models): per decode dispatch, token-expert pairs that
         # landed on held experts and distinct held experts hit (summed
@@ -611,12 +639,9 @@ class ContinuousBatchingEngine:
 
     def _build_step(self):
         cfg = self.cfg
-        if self._hybrid:
-            from ..models.granite_hybrid import build_hybrid_step
-            return build_hybrid_step(cfg, self.BS)
-        if self._latent:
-            from ..models.glm_moe_lite import build_latent_step
-            return build_latent_step(cfg, self.BS)
+        family = _model_module(cfg)
+        if family is not None:
+            return family.build_step(cfg, self.BS)
         from ..models.llama import _rope_cos_sin
         from ..models.generation import _collapse_blocks
         from ..ops.decode_block import decode_block, decode_block_spec
@@ -667,12 +692,9 @@ class ContinuousBatchingEngine:
         the last row.  With ``valid == Ts`` the computation is
         identical to the unpadded call."""
         cfg = self.cfg
-        if self._hybrid:
-            from ..models.granite_hybrid import build_hybrid_chunk_fill
-            return build_hybrid_chunk_fill(cfg, self.BS, Ts)
-        if self._latent:
-            from ..models.glm_moe_lite import build_latent_chunk_fill
-            return build_latent_chunk_fill(cfg, self.BS, Ts)
+        family = _model_module(cfg)
+        if family is not None:
+            return family.build_chunk_fill(cfg, self.BS, Ts)
         from ..models.llama import _rope_cos_sin
         from ..models.generation import _collapse_blocks
         from ..ops.decode_block import decode_block_spec, prefill_block
@@ -761,12 +783,12 @@ class ContinuousBatchingEngine:
         return out[len(self._carry):]
 
     def _run_fill(self, fill, slot: int, bt_row, start, toks, *valid):
-        """Call a compiled chunk fill for ``slot`` (a hybrid's fill is
-        told the slot: its state rows are that slot's; a latent model's
-        is handed its expert layers' running counts and hands them
-        back); returns the logits."""
-        more = (jnp.int32(slot),) if self._hybrid else \
-            (self._moe_fill,) if self._latent else ()
+        """Call a compiled chunk fill for ``slot`` (a fill of a model
+        with per-slot state is told the slot: its state rows are that
+        slot's; a latent model's is handed its expert layers' running
+        counts and hands them back); returns the logits."""
+        more = ((jnp.int32(slot),) if self._hybrid else ()) + (
+            (self._moe_fill,) if self._latent else ())
         logits, *counts = self._keep(fill(
             self.params, *self._carried(), bt_row, start, toks, *more,
             *valid))
@@ -1206,9 +1228,12 @@ class ContinuousBatchingEngine:
             # the pages' way out, on the timeline under ``admit``
             tl = self._tl
             sp = tl and tl.enter("kv_snapshot", slot=victims[0])
-            self.preempt(victims[0])
+            rid = self.preempt(victims[0])
             if tl:
-                tl.leave(sp)
+                # what went with the pages (a tier at its cap may have
+                # dropped the snapshot again already)
+                snap = self._spill.get(rid)
+                tl.leave(sp, state_bytes=snap.state_nbytes if snap else 0)
 
     def preempt(self, slot: int) -> int:
         """Evict the RUNNING request in ``slot`` for later resumption:
@@ -1341,7 +1366,7 @@ class ContinuousBatchingEngine:
         try:
             restore_into_slot(self, slot, snap)
             if tl:
-                tl.leave(sp)
+                tl.leave(sp, state_bytes=snap.state_nbytes)
         except BaseException:
             # exactly-once release; the snapshot is unusable, so the
             # request is DROPPED from this engine (a supervising
@@ -1770,6 +1795,8 @@ class ContinuousBatchingEngine:
         self.decode_pages_walked += trips * chunk_pages * self.B
         self.decode_pages_live += int(
             np.sum(-(-seen[active] // self.BS)))
+        if self._hybrid:
+            self.state_slot_steps += len(active) * self.ssm_state.shape[0]
         m0 = time.monotonic() if tl else 0.0
         sp = tl and tl.enter("decode_dispatch", batch=len(active))
         # a hybrid's or a latent model's step also returns its expert
@@ -1959,6 +1986,8 @@ class ContinuousBatchingEngine:
                 self.stats["prefill_tokens_computed"],
             "stalled_slot_iterations": self.stalled_slot_iterations,
             "decode_slot_steps": self.decode_slot_steps}
+        if self._hybrid:
+            s["state_slot_steps"] = self.state_slot_steps
         if self._hybrid or self._latent:
             s.update(self.moe)
         return sched_ratios(s)
@@ -1985,14 +2014,14 @@ class ContinuousBatchingEngine:
         the SAME function the dispatch reads, so a printed tier is the
         one that ran: ``{"ssm_state_update": {"tier": "pallas" | "xla",
         "reason": why the kernel stood down, or None}}`` for a model
-        with per-slot state; nothing for the Llama family, whose layers
-        have one implementation (``ops/decode_block.py``)."""
-        if not self._hybrid:
+        with per-slot state (its family's ``kernel_tiers``: a delta-rule
+        model's key is ``kda_state_update``); nothing for the Llama
+        family, whose layers have one implementation
+        (``ops/decode_block.py``)."""
+        tiers = getattr(_model_module(self.cfg), "kernel_tiers", None)
+        if tiers is None or not self._hybrid:
             return {}
-        from ..ops.ssm import ssm_state_update_tier
-        tier, why = ssm_state_update_tier(
-            self.ssm_state.shape, self.cfg.mamba_n_groups)
-        return {"ssm_state_update": {"tier": tier, "reason": why}}
+        return tiers(self.cfg, self.ssm_state.shape)
 
     def aot_stats(self) -> Dict[str, object]:
         """Warm-start observability for bench rows/telemetry: whether

@@ -56,8 +56,8 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["GlmMoeLiteConfig", "PRESETS", "build_latent_chunk_fill",
-           "build_latent_step", "glm_4_7_flash", "glm_moe_lite_tiny",
+__all__ = ["GlmMoeLiteConfig", "PRESETS", "build_chunk_fill",
+           "build_latent_chunk_fill", "build_latent_step", "build_step", "glm_4_7_flash", "glm_moe_lite_tiny",
            "init_glm_moe_lite_params"]
 
 #: the zoo's presets (``serving/http.py --model``)
@@ -407,3 +407,11 @@ def build_latent_chunk_fill(cfg: GlmMoeLiteConfig, block_size: int,
                 moe_rows + jnp.stack([jnp.int32(pairs), rows]))
 
     return fill
+
+
+# the names the serving engine and the zoo's CLI find a family's
+# programs and parameters under (``inference/serving.py:_model_module``,
+# ``serving/http.py:build_frontend``)
+build_step = build_latent_step
+build_chunk_fill = build_latent_chunk_fill
+init_params = init_glm_moe_lite_params

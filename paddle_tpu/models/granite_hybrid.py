@@ -49,10 +49,11 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["GraniteHybridConfig", "PRESETS", "build_hybrid_chunk_fill",
-           "build_hybrid_step", "granite_4_0_h_small",
-           "granite_hybrid_tiny", "init_granite_hybrid_params",
-           "init_slot_state"]
+__all__ = ["GraniteHybridConfig", "PRESETS", "build_chunk_fill",
+           "build_hybrid_chunk_fill", "build_hybrid_step", "build_step",
+           "granite_4_0_h_small", "granite_hybrid_tiny",
+           "init_granite_hybrid_params", "init_slot_state",
+           "kernel_tiers"]
 
 #: the zoo's presets (``serving/http.py --model``)
 PRESETS = ("granite_hybrid_tiny", "granite_4_0_h_small")
@@ -250,6 +251,14 @@ def init_slot_state(cfg: GraniteHybridConfig, max_batch: int):
                        cfg.mamba_d_state), jnp.float32),
             jnp.zeros((Lm, max_batch, cfg.conv_dim, cfg.mamba_d_conv - 1),
                       jnp.dtype(cfg.dtype)))
+
+
+def kernel_tiers(cfg: GraniteHybridConfig, state_shape):
+    """``{"ssm_state_update": {"tier", "reason"}}``: the tier the decode
+    step's state update runs on, from the function its dispatch reads."""
+    from ..ops.ssm import ssm_state_update_tier
+    tier, why = ssm_state_update_tier(state_shape, cfg.mamba_n_groups)
+    return {"ssm_state_update": {"tier": tier, "reason": why}}
 
 
 # ---------------------------------------------------------------------
@@ -473,3 +482,11 @@ def build_hybrid_chunk_fill(cfg: GraniteHybridConfig, block_size: int,
         return pool_k, pool_v, ssm, conv, _head(cfg, norm, params, last)
 
     return fill
+
+
+# the names the serving engine and the zoo's CLI find a family's
+# programs and parameters under (``inference/serving.py:_model_module``,
+# ``serving/http.py:build_frontend``)
+build_step = build_hybrid_step
+build_chunk_fill = build_hybrid_chunk_fill
+init_params = init_granite_hybrid_params

@@ -7,6 +7,7 @@ The equations (``rms`` an RMS norm with a gain; ``nh`` heads; ``d_n`` /
 a value head; ``r_kv`` the latent's width)::
 
     c_q = rms(x W_qa);  q = c_q W_qb -> nh x [q_n (d_n) | q_r (d_r)]
+                        (or q = x W_q: no low-rank step, ``q_lora_rank`` None)
     [c | k_r] = x W_kva;  c = rms(c)               (r_kv | d_r)
     per head  k_n = c W_uk (d_n),  v = c W_uv (d_v)
     score = (q_n . k_n + rope(q_r) . rope(k_r)) * scale
@@ -72,7 +73,7 @@ import jax.numpy as jnp
 from .paged_kv import NEG_INF, decode_walk
 
 __all__ = ["MlaSpec", "rms_norm", "WALK_POSITIONS", "FILL_POSITIONS", "project",
-           "rope_pairs", "rope_angles", "absorb_query", "lift_output",
+           "rope_pairs", "rope_angles", "absorb_query", "lift_output", "gate_heads",
            "expanded_attention", "absorbed_attention", "latent_append",
            "paged_latent_attention", "paged_expanded_attention"]
 
@@ -93,7 +94,9 @@ class MlaSpec:
     """The sizes of one latent-attention layer."""
     hidden: int
     num_heads: int
-    q_lora_rank: int
+    #: None: the query has no low-rank step (``q = x W_q``, the leaf
+    #: ``q_w``; Ling-3.0's ``q_lora_rank: null``)
+    q_lora_rank: Optional[int]
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -164,8 +167,11 @@ def project(y, lp, pos, spec: MlaSpec):
     nh, dn, dr = (spec.num_heads, spec.qk_nope_head_dim,
                   spec.qk_rope_head_dim)
     eps = spec.latent_norm_eps
-    cq = rms_norm(y @ lp["q_a_w"], lp["q_a_ln_w"], eps)
-    q = (cq @ lp["q_b_w"]).reshape(*y.shape[:-1], nh, dn + dr)
+    if spec.q_lora_rank is None:
+        q = y @ lp["q_w"]
+    else:
+        q = rms_norm(y @ lp["q_a_w"], lp["q_a_ln_w"], eps) @ lp["q_b_w"]
+    q = q.reshape(*y.shape[:-1], nh, dn + dr)
     kv = y @ lp["kv_a_w"]
     c = rms_norm(kv[..., :spec.kv_lora_rank], lp["kv_a_ln_w"], eps)
     cos, sin = rope_angles(pos, spec)
@@ -197,6 +203,17 @@ def lift_output(o_lat, uv_w):
     o = jnp.einsum("...hr,hrv->...hv", o_lat, uv_w,
                    preferred_element_type=jnp.float32).astype(o_lat.dtype)
     return o.reshape(*o.shape[:-2], -1)
+
+
+def gate_heads(o, gate):
+    """A mixer's output gated a HEAD: ``o [..., nh x d]`` times
+    ``sigmoid(gate [..., nh])`` (float32 inside), in ``o``'s dtype —
+    Ling-3.0's ``gated_attention_proj_granularity_type: head_wise``, on
+    its latent and its linear-attention layers alike."""
+    nh = gate.shape[-1]
+    g = jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
+    oh = o.reshape(*o.shape[:-1], nh, -1).astype(jnp.float32) * g
+    return oh.reshape(o.shape).astype(o.dtype)
 
 
 def expanded_attention(q_n, q_r, latent, uk_w, uv_w, mask, scale: float):

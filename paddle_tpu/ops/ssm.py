@@ -39,7 +39,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["causal_conv", "ssd_chunk_scan", "ssm_state_update",
+__all__ = ["causal_conv", "causal_conv_rows", "causal_conv_step",
+           "ssd_chunk_scan", "ssm_state_update",
            "ssm_state_update_row", "ssm_state_update_tier",
            "ssm_recurrence"]
 
@@ -51,15 +52,23 @@ def causal_conv(x, tail, w, b, valid=None):
 
     ``x [B, T, C]``: the chunk; ``tail [B, C, W - 1]``: the ``W - 1``
     inputs before it (zeros at a sequence's start); ``w [C, W]`` (``w[:,
-    W - 1]`` multiplies the current position), ``b [C]``.  Returns ``(y
-    [B, T, C], new tail)``.  With ``valid`` (traced scalar or ``[B]``)
+    W - 1]`` multiplies the current position), ``b [C]`` or None (no
+    bias).  Returns ``(y [B, T, C], new tail)``.  With ``valid`` (traced
+    scalar or ``[B]``)
     the new tail holds the last ``W - 1`` inputs before position
     ``valid``; without it, before position ``T``."""
+    y, new = causal_conv_rows(x, jnp.swapaxes(tail, 1, 2), w, b, valid)
+    return y, jnp.swapaxes(new, 1, 2)
+
+
+def causal_conv_rows(x, tail, w, b, valid=None):
+    """:func:`causal_conv` with the tail held as ROWS, ``[B, W - 1,
+    C]``: the channels on the minor axis, as ``x`` has them (a state
+    array ``[.., C, 3]`` has three values on the lanes)."""
     B, T, C = x.shape
     W = w.shape[1]
-    full = jnp.concatenate([jnp.swapaxes(tail, 1, 2).astype(x.dtype), x],
-                           axis=1)                    # [B, W-1+T, C]
-    y = b.astype(F32)
+    full = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    y = 0.0 if b is None else b.astype(F32)           # [B, W-1+T, C]
     for k in range(W):
         y = y + full[:, k:k + T].astype(F32) * w[:, k].astype(F32)
     if valid is None:
@@ -68,7 +77,23 @@ def causal_conv(x, tail, w, b, valid=None):
         v = jnp.broadcast_to(jnp.asarray(valid, jnp.int32), (B,))
         new = jax.vmap(lambda f, s: jax.lax.dynamic_slice_in_dim(
             f, s, W - 1, axis=0))(full, v)
-    return y.astype(x.dtype), jnp.swapaxes(new, 1, 2).astype(tail.dtype)
+    return y.astype(x.dtype), new.astype(tail.dtype)
+
+
+def causal_conv_step(x, tail, w, b):
+    """One position of :func:`causal_conv_rows`, every array 2-D: ``x
+    [B, C]``, ``tail [B, (W - 1) C]`` (the last ``W - 1`` inputs one
+    after the other, the oldest first).  Returns ``(y [B, C], new
+    tail)``.  What a decode step wants: slices and a concatenation along
+    the lanes, no axis of ``W - 1`` for a layout to pad."""
+    C = x.shape[-1]
+    W = w.shape[1]
+    taps = [tail[:, k * C:(k + 1) * C] for k in range(W - 1)] + [x]
+    y = 0.0 if b is None else b.astype(F32)
+    for k, t in enumerate(taps):
+        y = y + t.astype(F32) * w[:, k].astype(F32)
+    return y.astype(x.dtype), jnp.concatenate(taps[1:], axis=1).astype(
+        tail.dtype)
 
 
 def _heads(m, nh: int):

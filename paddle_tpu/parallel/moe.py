@@ -46,7 +46,8 @@ __all__ = ["inject_aux_grad", "topk_scatter_routing", "moe_ffn_ep",
            "schedule_aux_coef", "expert_choice_routing",
            "moe_expert_choice_ffn", "moe_swiglu_ffn_grouped",
            "moe_swiglu_ffn_masked", "route_held", "moe_gelu_ffn_grouped",
-           "route_sigmoid", "moe_swiglu_ffn_routed", "expert_counts"]
+           "route_sigmoid", "moe_swiglu_ffn_routed", "expert_counts",
+           "held_choices"]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -235,9 +236,18 @@ def route_held(logits: jax.Array, top_k: int, n_held: int, *,
         w = jax.nn.softmax(lg, axis=-1)
     else:
         raise ValueError(f"unknown gate {gate!r}")
+    local, held = held_choices(idx, n_held, expert_offset)
+    return w, local, held
+
+
+def held_choices(idx: jax.Array, n_held: int, expert_offset: int = 0):
+    """``(local, held)`` of the router's choices ``idx`` for a rank that
+    holds experts ``[expert_offset, expert_offset + n_held)``: each
+    choice's index into the held bank (``n_held`` where it is not held)
+    and whether it is held."""
     local = idx - expert_offset
     held = (local >= 0) & (local < n_held)
-    return w, jnp.where(held, local, n_held), held
+    return jnp.where(held, local, n_held), held
 
 
 def _held_counts(held, local, n_held: int, mask=None):
@@ -321,11 +331,14 @@ def expert_counts(idx: jax.Array, n_experts: int, mask=None):
 RIDGE_ROWS = 240
 
 
-def _experts_grouped(tokens, w, idx, wg, wu, wd, E, base):
+def _experts_grouped(tokens, w, idx, wg, wu, wd, E, base, share=1.0):
     """``sum_j w[t, j] E_idx[t, j](tokens[t])`` as float32 ``[T, h]``
     and the rows multiplied for it (int32).  ``wg/wu [G, h, f]``, ``wd
     [G, f, h]``: EVERY layer's experts, this layer's ``E`` from ``base``
-    on.  The pairs are sorted by expert and laid out in tiles
+    on; ``idx == E`` names no expert of the bank (a choice another rank
+    holds: it multiplies nothing and counts no row); ``share`` the part
+    of the ``T k`` pairs expected to land here.  The pairs are sorted by
+    expert and laid out in tiles
     of ``tm`` rows, an expert's rows padded up to whole tiles (gathers
     only, no scatter); gate and up with the SwiGLU are one grouped
     matmul over the tiles that hold a row, down another
@@ -336,11 +349,12 @@ def _experts_grouped(tokens, w, idx, wg, wu, wd, E, base):
     T, k = idx.shape
     h, f = wg.shape[-2:]
     item = tokens.dtype.itemsize
-    tm, tn_up = grouped_tiles(T * k, E, h, f, 2, item)
-    _, tn_down = grouped_tiles(T * k, E, f, h, 1, item)
+    expected = max(1, int(T * k * share))
+    tm, tn_up = grouped_tiles(expected, E, h, f, 2, item)
+    _, tn_down = grouped_tiles(expected, E, f, h, 1, item)
     flat = idx.reshape(-1)                               # [T k] pairs
-    order = jnp.argsort(flat)                            # stable
-    load = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+    order = jnp.argsort(flat)                 # stable; the unheld last
+    load = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
     first = jnp.cumsum(load) - load                      # in sorted order
     tiles = -(-load // tm)                               # [E] tiles each
     tile_end = jnp.cumsum(tiles)
@@ -349,8 +363,10 @@ def _experts_grouped(tokens, w, idx, wg, wu, wd, E, base):
     # the static bound: no routing needs more tiles than this
     n_tiles = (T * k + E * (tm - 1)) // tm
     # tile t is expert e's: the steps past the last real tile name it again
-    t = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32), visited - 1)
-    e = jnp.searchsorted(tile_end, t, side="right").astype(jnp.int32)
+    t = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32),
+                    jnp.maximum(visited - 1, 0))
+    e = jnp.minimum(jnp.searchsorted(tile_end, t, side="right"),
+                    E - 1).astype(jnp.int32)
     # row r of tile t is the expert's ((t - tile0) tm + r)-th pair; the
     # padding rows read some token and are multiplied for nothing
     at = first[e][:, None] + (t - tile0[e])[:, None] * tm \
@@ -360,22 +376,33 @@ def _experts_grouped(tokens, w, idx, wg, wu, wd, E, base):
     act = moe_grouped_matmul(x, (wg, wu), base + e, t, visited, tm, tn_up)
     out = moe_grouped_matmul(act, (wd,), base + e, t, visited, tm, tn_down)
     # a pair's row: its expert's first tile, then its place among the
-    # expert's pairs
-    row = tile0[flat] * tm + jnp.argsort(order) - first[flat]
-    got = out[row].reshape(T, k, h).astype(jnp.float32)
+    # expert's pairs; an unheld pair has none (and what lies past the
+    # visited tiles is not even zeros: chosen, not multiplied, away)
+    held = flat < E
+    mine = jnp.minimum(flat, E - 1)
+    row = tile0[mine] * tm + jnp.argsort(order) - first[mine]
+    got = jnp.where(held[:, None], out[jnp.where(held, row, 0)],
+                    0).reshape(T, k, h).astype(jnp.float32)
     return jnp.sum(w[..., None] * got, axis=1), visited * tm
 
 
 def moe_swiglu_ffn_routed(x: jax.Array, w: jax.Array, idx: jax.Array,
                           wg: jax.Array, wu: jax.Array, wd: jax.Array, *,
-                          layer=None):
+                          layer=None, expert_offset: int = 0,
+                          router_experts: Optional[int] = None):
     """Exact SwiGLU MoE for routing done by the caller: ``sum_j w[t, j]
     E_idx[t, j](x[t])`` over the bank ``wg/wu [E, h, f]``, ``wd [E, f,
-    h]``, every ``idx`` in ``[0, E)``; no token is dropped for any
-    routing.  With ``layer`` (a traced index) the three are STACKS ``[n,
+    h]``; no token is dropped for any routing.  The bank holds experts
+    ``[expert_offset, expert_offset + E)`` of the ``router_experts``
+    (``E`` when None) that ``idx`` ranges over: a rank that holds a
+    SHARE computes the terms of the pairs that chose its experts, gates
+    unchanged, and a pair that chose another rank's multiplies nothing
+    and counts no row (expert parallelism's partial sum; with every
+    expert held this is the whole sum, on the same code).  With
+    ``layer`` (a traced index) the three are STACKS ``[n,
     E, ...]`` of which that layer's bank is meant.  Returns ``(out, rows)``,
     ``rows`` (int32) the rows the experts' matmuls multiplied for the
-    ``T k`` token-expert pairs.
+    held token-expert pairs.
 
     The form follows from the rows there are, a static shape:
 
@@ -398,6 +425,7 @@ def moe_swiglu_ffn_routed(x: jax.Array, w: jax.Array, idx: jax.Array,
     shape = x.shape
     tokens = x.reshape(-1, shape[-1])
     T, E = tokens.shape[0], wg.shape[-3]
+    idx, _ = held_choices(idx, E, expert_offset)
     if T < RIDGE_ROWS:
         def bank(a):
             return a if layer is None else lax.dynamic_index_in_dim(
@@ -411,7 +439,8 @@ def moe_swiglu_ffn_routed(x: jax.Array, w: jax.Array, idx: jax.Array,
 
         res, rows = _experts_grouped(
             tokens, w, idx, whole(wg), whole(wu), whole(wd), E,
-            0 if layer is None else layer * E)
+            0 if layer is None else layer * E,
+            share=E / (router_experts or E))
     return res.astype(x.dtype).reshape(shape), rows
 
 

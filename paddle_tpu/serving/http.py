@@ -1148,37 +1148,38 @@ def build_frontend(args) -> ServingFrontend:
     from ..inference.serving import ContinuousBatchingEngine
     from ..models import glm_moe_lite as latent_zoo
     from ..models import granite_hybrid as hybrid_zoo
+    from ..models import ling_linear as linear_zoo
     from ..models import llama as llama_zoo
     from ..parallel.topology import HybridTopology, set_topology
     from .frontend import AdmissionConfig
 
     # the zoo: the Llama family's presets, the hybrid family's
-    # (state-space + attention layers) and the latent-attention
-    # family's; the engine reads the kind of model from the
+    # (state-space + attention layers), the latent-attention family's
+    # and the linear-attention family's (a state beside a latent
+    # cache); the engine reads the kind of model from the
     # configuration object
-    zoo = next((z for z in (hybrid_zoo, latent_zoo)
-                if args.model in z.PRESETS), llama_zoo)
+    families = (hybrid_zoo, latent_zoo, linear_zoo)
+    zoo = next((z for z in families if args.model in z.PRESETS),
+               llama_zoo)
     cfg_fn = getattr(zoo, args.model, None)
     if cfg_fn is None:
         raise SystemExit(f"unknown model {args.model!r} (the zoo has "
                          "llama_tiny / llama_7b / ... and "
-                         + " / ".join(hybrid_zoo.PRESETS
-                                      + latent_zoo.PRESETS) + ")")
+                         + " / ".join(p for z in families
+                                      for p in z.PRESETS) + ")")
     cfg_kw = {k: v for k, v in (("dtype", args.dtype),) if v is not None}
     if args.num_layers is not None:
         if zoo is hybrid_zoo:
             # the depth of a hybrid is its pattern's: its first layers
             cfg_kw["layer_types"] = \
                 cfg_fn().layer_types[:args.num_layers]
-        elif zoo is latent_zoo:
+        elif zoo in (latent_zoo, linear_zoo):
             cfg_kw["num_hidden_layers"] = args.num_layers
         else:
             cfg_kw["num_layers"] = args.num_layers
     cfg = cfg_fn(**cfg_kw)
-    if zoo is hybrid_zoo:
-        params = hybrid_zoo.init_granite_hybrid_params(cfg, args.seed)
-    elif zoo is latent_zoo:
-        params = latent_zoo.init_glm_moe_lite_params(cfg, args.seed)
+    if zoo in families:
+        params = zoo.init_params(cfg, args.seed)
     else:
         topo = dist.init_topology(devices=jax.devices()[:1])
         params = llama_zoo.init_llama_params(cfg, topo, args.seed)

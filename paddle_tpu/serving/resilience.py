@@ -127,8 +127,8 @@ class KVSnapshot:
     # attention layers): the slot's rows of the engine's two state
     # arrays, CRC-stamped like the pages — the pages alone could not
     # resume such a sequence
-    ssm_state: Optional[np.ndarray] = None   # [L_mamba, heads, P, N] f32
-    conv_state: Optional[np.ndarray] = None  # [L_mamba, channels, W-1]
+    ssm_state: Optional[np.ndarray] = None   # [L_state, heads, P, N] f32
+    conv_state: Optional[np.ndarray] = None  # [L_state, channels, W-1]
     crc_k: int = 0
     crc_v: int = 0
     crc_state: int = 0
@@ -149,12 +149,18 @@ class KVSnapshot:
         return crc
 
     @property
+    def state_nbytes(self) -> int:
+        """Bytes of per-slot recurrent state that ride with the pages
+        (0 for a model that keeps none)."""
+        if self.ssm_state is None:
+            return 0
+        return self.ssm_state.nbytes + self.conv_state.nbytes
+
+    @property
     def nbytes(self) -> int:
-        n = self.k_pages.nbytes + self.v_pages.nbytes
+        n = self.k_pages.nbytes + self.v_pages.nbytes + self.state_nbytes
         if self.k_scale is not None:
             n += self.k_scale.nbytes + self.v_scale.nbytes
-        if self.ssm_state is not None:
-            n += self.ssm_state.nbytes + self.conv_state.nbytes
         return n
 
     def verify(self) -> None:

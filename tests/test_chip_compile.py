@@ -307,6 +307,92 @@ def test_latent_engine_programs_fit_and_copy_no_pool(chip, program,
         assert text.count("moe_grouped_matmul") >= 2
 
 
+# the Ling-3.0-flash serve cell's engine (benchmark/configs: layer 0 and
+# six expert layers at the published widths, 128 of each layer's 512
+# experts, a quarter of the vocabulary; 128 slots, 32768 pages of 16 for
+# the ONE latent layer, a table of 256)
+_LINEAR = dict(layers=7, held=128, vocab=39296, slots=128, pages=32768,
+               page=16, table=256)
+# a layer's bank of its held experts as the result of a slice
+_LINEAR_BANK_CUT = re.compile(
+    r"= bf16\[(?:1,)?128,(?:2560,768|768,2560)\]\S* (?:dynamic-)?slice\(")
+
+
+@pytest.mark.parametrize("program, temp_mib", [
+    ("step", 256), ("fill128", 64), ("fill512", 128)])
+def test_linear_engine_programs_fit_and_copy_no_state(chip, program,
+                                                      temp_mib):
+    """The engine's decode step and its 128 and 512 chunk fills for a
+    model with a recurrent state BESIDE a latent pool, at the benchmark
+    cell's sizes, from abstract arguments: 10.34 GB of weights, 1.61 GB
+    of KDA state, 57 MB of conv tails and a 0.67 GB latent pool ride
+    through whole, so the whole fits the chip's 15.75 GiB with room for
+    temporaries and nothing pool-, state- or bank-sized is copied.
+
+    What this holds (ISSUE 36's compiles): conv tails held ``[.., C, 3]``
+    or ``[.., 3, C]`` were copied whole into a padded layout and back by
+    every fill (57 MB each way), and as ``[.., 3 C]`` rows by the step
+    until its conv read them as 2-D slices (``ops.ssm.
+    causal_conv_step``).  Now 175 MB / 16 MB / 31 MB of temporaries (the
+    step's: every row through every held expert, ``[128, 128, 768]``
+    twice a layer)."""
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models import ling_linear as zoo
+    z = _LINEAR
+    cfg = zoo.ling_3_0_flash(
+        num_hidden_layers=z["layers"], first_k_dense_replace=1,
+        experts_held=z["held"], vocab_size=z["vocab"])
+    params = on(chip, jax.eval_shape(
+        lambda: zoo.init_ling_linear_params(cfg, 0)))
+    held = sum(math.prod(a.shape) for a in jax.tree.leaves(params))
+    assert held == 5169390784
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.cfg, eng.BS, eng._hybrid, eng._latent, eng.quant_config = \
+        cfg, z["page"], True, True, None
+    pool = chip((cfg.num_attention_layers, z["pages"], z["page"],
+                 cfg.pool_width))
+    ssm, conv = on(chip, jax.eval_shape(
+        lambda: zoo.init_slot_state(cfg, z["slots"])))
+    assert ssm.shape == (6, 128, 32, 128, 128) and ssm.dtype == jnp.float32
+    i32 = jnp.int32
+    if program == "step":
+        fn, args = eng._build_step(), (
+            chip((z["slots"], z["table"]), i32), chip((z["slots"],), i32),
+            chip((z["slots"],), i32))
+    else:
+        Ts = int(program[4:])
+        fn, args = eng._build_chunk_fill(Ts), (
+            chip((z["table"],), i32), chip((), i32), chip((Ts,), i32),
+            chip((), i32), chip((2,), i32), chip((), i32))
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 3)).lower(
+        params, pool, ssm, conv, *args).compile()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < temp_mib * _MIB
+    total = (m.temp_size_in_bytes + m.argument_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes
+             + m.generated_code_size_in_bytes)
+    assert total < 14.2e9, total            # of the chip's 16.9e9
+    text = compiled.as_text()
+    # the largest leaf that is no pool, state or bank is a dense layer's
+    # [2560, 6144] (31 MB: a run of ONE layer is read out of its stack)
+    copied = [(dt, dims) for dt, dims in _COPY.findall(text)
+              if _BYTES.get(dt, 4) * math.prod(int(d) for d in dims.split(",")
+                                        if d) >= 40 * _MIB]
+    assert not copied, f"large arrays copied: {copied}"
+    # the state rides whole, in place; the pool as ONE pool of pages
+    assert "f32[6,128,32,128,128]" in text
+    assert "bf16[32768,16,640]" in text
+    if program == "step":
+        assert text.count("kda_state_update") >= 3    # a run of KDA layers
+    elif program == "fill512":
+        # its grouped matmuls find the layer's held experts in the stacks
+        # (the masked form of the step and the 128 fill reads a layer's
+        # bank through a slice that its matmul's fusion takes in: the
+        # temporaries above have no room for a 503 MB bank)
+        assert not _LINEAR_BANK_CUT.search(text), "a bank was cut out"
+        assert text.count("moe_grouped_matmul") >= 2
+
+
 def test_decode_attention(chip):
     from paddle_tpu.ops.pallas.decode_attention import decode_attention
     cache = chip((8, 2048, 32, 128))
@@ -326,6 +412,19 @@ def test_ssm_state_update(chip):
         chip((128,), f32), chip((9, 64, 128, 64, 128), f32),
         chip((), jnp.int32))
     assert "ssm_state_update" in compiled.as_text()
+
+
+def test_kda_state_update(chip):
+    """The linear-attention family's decode-step state update at the
+    published KDA widths (32 heads of 128 x 128), 128 slots, 6 layers:
+    in place."""
+    from paddle_tpu.ops.pallas.kda import kda_state_update_rows
+    f32 = jnp.float32
+    side = chip((128, 32, 128), f32)
+    compiled = compile_kernel(
+        kda_state_update_rows, side, side, side, side, chip((128, 32), f32),
+        chip((6, 128, 32, 128, 128), f32), chip((), jnp.int32))
+    assert "kda_state_update" in compiled.as_text()
 
 
 @pytest.mark.parametrize("tokens", [512, 2048])
