@@ -570,6 +570,10 @@ class ContinuousBatchingEngine:
             # expert layers and the steps: against the pairs, how
             # uneven the routing is
             self.moe["moe_peak_load"] = 0
+            # the rows the decode steps' expert matmuls multiplied (row
+            # tiles visited x tile height, summed over the layers):
+            # against the held pairs, the padding of the experts' tiles
+            self.moe["moe_step_rows"] = 0
             # the chunk fills' expert layers: token-expert pairs routed
             # (a bucket's padded rows too) and the rows the experts'
             # matmuls multiplied for them, summed on the device through
@@ -1815,7 +1819,7 @@ class ContinuousBatchingEngine:
             counts, greedy = (np.asarray(a) for a in extra)
             self.last_logits = logits
             fetched = counts.nbytes + greedy.nbytes
-            local, hit, *peak = (int(c) for c in counts)
+            local, hit, *latent = (int(c) for c in counts)
             cfg, m = self.cfg, self.moe
             layers = getattr(cfg, "num_expert_layers", cfg.num_layers)
             m["moe_assignments_local"] += local
@@ -1823,8 +1827,9 @@ class ContinuousBatchingEngine:
             m["moe_assignments_total"] += \
                 len(active) * cfg.num_experts_per_tok * layers
             m["moe_expert_slots"] += cfg.experts_held * layers
-            if peak:
-                m["moe_peak_load"] += peak[0]
+            if latent:
+                m["moe_peak_load"] += latent[0]
+                m["moe_step_rows"] += latent[1]
         else:
             self.last_logits = np.asarray(logits)
             fetched = self.last_logits.nbytes

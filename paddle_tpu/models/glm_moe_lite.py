@@ -26,12 +26,12 @@ of the others ``sum_j g_j E_j(x) + shared(x)`` with
 ``parallel/moe.py:route_sigmoid``'s gate (sigmoid scores in float32,
 the choice by ``score + bias``, the weights from the scores alone,
 renormalised, times ``routed_scaling_factor``), every expert held.
-``moe_swiglu_ffn_routed`` picks the form from the rows it is given: a
-decode step's few rows (and a chunk under the chip's FLOP-per-byte
-ridge) go through every expert, the masked form; a chunk fill's rows
-are sorted by expert and multiplied once each by a grouped matmul that
-reads every expert's weights once, in the stacked leaves
-(``ops/pallas/moe_grouped_matmul.py``).
+``moe_swiglu_ffn_routed`` has one form for a decode step's few rows
+and a chunk fill's many: the token-expert pairs are sorted by expert
+and multiplied once each by a grouped matmul that reads an expert's
+weights once, in the stacked leaves, and only if a row chose it
+(``ops/pallas/moe_grouped_matmul.py``; a step of 64 rows hits 86% of
+the 64 experts, PERF.md PR 37).
 
 Parameter tree: ``{"wte" [V, H], "head" [H, V], "lnf_w" [H], "runs":
 (run, ...)}``, one ``run`` the layers of one kind (``cfg.runs()``:
@@ -309,14 +309,15 @@ def _scan_runs(cfg: GlmMoeLiteConfig, params, layer, carry):
 
 def build_latent_step(cfg: GlmMoeLiteConfig, block_size: int):
     """The decode program: ``step(params, pool, bt, lengths, tokens) ->
-    (pool, logits [B, V], counts [3], greedy [B])``.  The latent pool
+    (pool, logits [B, V], counts [4], greedy [B])``.  The latent pool
     ``[L, NB, BS, W]`` rides through the layer scans WHOLE, in their
     carry, as one pool of ``L x NB`` pages (``layers_as_one_pool``), so
-    a layer's append lands in place.  ``counts`` sums the expert
-    layers' ``[pairs, distinct experts hit, most pairs on one expert]``
-    over the layers and over the rows that run a request (``lengths >
-    0``); ``greedy`` is every row's first choice, so that the engine
-    need not fetch the logits to pick."""
+    a layer's append lands in place.  ``counts`` sums over the expert
+    layers ``[pairs, distinct experts hit, most pairs on one expert]``
+    of the rows that run a request (``lengths > 0``), and the rows the
+    experts' matmuls multiplied (for all ``B`` rows: an idle slot's row
+    is routed too); ``greedy`` is every row's first choice, so that the
+    engine need not fetch the logits to pick."""
     from ..ops import mla
     from ..ops.paged_kv import layer_pages, layers_as_one_pool
     spec = mla_spec(cfg, block_size)
@@ -340,12 +341,12 @@ def build_latent_step(cfg: GlmMoeLiteConfig, block_size: int):
                 lengths + 1, spec.kv_lora_rank, spec.scale)
             x = x + (mla.lift_output(o, lp["uv_w"])
                      @ lp["o_w"]).astype(jnp.float32)
-            x, c, _ = ffn_half(x, lp, kind, count_mask=live)
-            return x, pc, cnt + c
+            x, c, rows = ffn_half(x, lp, kind, count_mask=live)
+            return x, pc, cnt + jnp.concatenate([c, rows[None]])
 
         x, pc, cnt = _scan_runs(
             cfg, params, layer,
-            (x, layers_as_one_pool(pool), jnp.zeros((3,), jnp.int32)))
+            (x, layers_as_one_pool(pool), jnp.zeros((4,), jnp.int32)))
         logits = _head(cfg, params, x)
         return (layers_as_one_pool(pc, like=pool), logits, cnt,
                 jnp.argmax(logits, axis=-1).astype(jnp.int32))

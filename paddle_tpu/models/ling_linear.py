@@ -43,7 +43,11 @@ route_sigmoid``'s gate over ALL ``num_experts`` (8 groups, the 4 whose
 two best scores sum highest, the 8 best in them).  A rank may hold a
 contiguous share of the experts (``experts_held`` from
 ``expert_offset``): it adds only the terms of those it holds, gates
-unchanged (``moe_swiglu_ffn_routed``; expert parallelism's partial sum).
+unchanged (``moe_swiglu_ffn_routed``; expert parallelism's partial sum:
+the pairs on held experts sorted by expert and multiplied once each, in
+a decode step as in a chunk fill, so that an expert's weights are read
+only where a row chose it: a step of 128 rows hits half of 128 held,
+PERF.md PR 37).
 
 Parameter tree: ``{"wte" [V, H], "head" [H, V], "lnf_w" [H], "runs":
 (run, ...)}``, one ``run`` a maximal stretch of layers of one kind
@@ -472,14 +476,16 @@ def _closures(cfg: LingLinearConfig, block_size: int):
 
 def build_step(cfg: LingLinearConfig, block_size: int):
     """The decode program: ``step(params, pool, ssm, conv, bt, lengths,
-    tokens) -> (pool, ssm, conv, logits [B, V], counts [3], greedy
+    tokens) -> (pool, ssm, conv, logits [B, V], counts [4], greedy
     [B])``.  One ``lax.scan`` a run of ``cfg.runs()``; the latent pool
     ``[La, NB, BS, W]`` rides through them WHOLE as one pool of ``La x
     NB`` pages (``layers_as_one_pool``), the state arrays whole beside
-    it, each updated in place, row by layer.  ``counts`` sums the expert
-    layers' ``[pairs on held experts, distinct held experts hit, most
-    pairs on one]`` over the layers and over the rows that run a request
-    (``lengths > 0``); ``greedy`` is every row's first choice."""
+    it, each updated in place, row by layer.  ``counts`` sums over the
+    expert layers ``[pairs on held experts, distinct held experts hit,
+    most pairs on one]`` of the rows that run a request (``lengths >
+    0``), and the rows the experts' matmuls multiplied (for all ``B``
+    rows: an idle slot's row is routed too); ``greedy`` is every row's
+    first choice."""
     from ..ops import mla
     from ..ops.paged_kv import layer_pages, layers_as_one_pool
     spec, ffn_half, kda_mix, rows = _closures(cfg, block_size)
@@ -510,13 +516,13 @@ def build_step(cfg: LingLinearConfig, block_size: int):
                 o = mla.gate_heads(mla.lift_output(o, lp["uv_w"]),
                                    y @ lp["g_w"])
                 x = x + (o @ lp["o_w"]).astype(F32)
-            x, c, _ = ffn_half(x, lp, ffn, count_mask=live)
-            return x, pc, ssm, conv, cnt + c
+            x, c, f = ffn_half(x, lp, ffn, count_mask=live)
+            return x, pc, ssm, conv, cnt + jnp.concatenate([c, f[1:]])
 
         x, pc, ssm, conv, cnt = _scan_runs(
             cfg, params, layer,
             (x, layers_as_one_pool(pool), ssm, conv,
-             jnp.zeros((3,), jnp.int32)))
+             jnp.zeros((4,), jnp.int32)))
         logits = _head(cfg, params, x)
         return (layers_as_one_pool(pc, like=pool), ssm, conv, logits, cnt,
                 jnp.argmax(logits, axis=-1).astype(jnp.int32))

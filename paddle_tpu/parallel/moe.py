@@ -324,13 +324,6 @@ def expert_counts(idx: jax.Array, n_experts: int, mask=None):
                       jnp.max(load)])
 
 
-#: rows under which :func:`moe_swiglu_ffn_routed` multiplies every row
-#: by every expert: that form's FLOPs per byte of bank ARE its row
-#: count, so under the chip's ridge (197e12 FLOP/s over 819e9 B/s on a
-#: TPU v5e) the bank's bytes bind it whichever rows are multiplied
-RIDGE_ROWS = 240
-
-
 def _experts_grouped(tokens, w, idx, wg, wu, wd, E, base, share=1.0):
     """``sum_j w[t, j] E_idx[t, j](tokens[t])`` as float32 ``[T, h]``
     and the rows multiplied for it (int32).  ``wg/wu [G, h, f]``, ``wd
@@ -404,43 +397,36 @@ def moe_swiglu_ffn_routed(x: jax.Array, w: jax.Array, idx: jax.Array,
     ``rows`` (int32) the rows the experts' matmuls multiplied for the
     held token-expert pairs.
 
-    The form follows from the rows there are, a static shape:
-
-    * under ``RIDGE_ROWS`` (a decode step's few rows, a short chunk)
-      the masked form, :func:`moe_swiglu_ffn_masked`'s: every token
-      through every expert, ``E / k`` times the FLOPs the choices
-      require and the weights read once, which is what binds it
-      (``rows = T E``);
-    * from there on (a chunk fill) the grouped form,
-      :func:`_experts_grouped`: each routed row is multiplied once, but
-      for the padding of each expert's last tile, and each expert's
-      weights are read once, where they lie: the kernel is handed the
-      WHOLE stacks (viewed ``[n E, ...]``, a bitcast) and finds the
-      layer's experts by index, so nothing is cut out of them.
-      ``lax.ragged_dot`` is handed one layer's bank, which the compiler
-      first copies out of the stack (1.2 GB a layer at GLM-4.7-Flash's
-      sizes; PERF.md, PR 30), and a fixed capacity an expert multiplies
-      its padding and needs rounds where the routing is uneven (PERF.md,
-      PR 35)."""
+    ONE form whatever the rows, :func:`_experts_grouped`: each routed
+    row is multiplied once, but for the padding of each expert's last
+    tile, and an expert's weights are read once IF a row chose it, where
+    they lie: the kernel is handed the WHOLE stacks (viewed ``[n E,
+    ...]``, a bitcast) and finds the layer's experts by index, so
+    nothing is cut out of them.  A decode step's few rows take it too:
+    every row through every expert as one batched matmul
+    (:func:`moe_swiglu_ffn_masked`'s form) reads the whole bank at the
+    same rate, so it is level only where every expert is hit, which the
+    routers served here are far from (alone on a TPU v5e, a layer of a
+    6-layer stack, PERF.md PR 37: 128 rows choosing 8 of 512 with 128
+    held hit 49% of them, 1.17 ms against 2.03; 64 rows choosing 4 of
+    64, all held, hit 85%, 1.46 against 1.63; with every expert hit the
+    arithmetic says 4% behind).  ``lax.ragged_dot`` is handed one
+    layer's bank, which the compiler first copies out of the stack (1.2
+    GB a layer at GLM-4.7-Flash's sizes; PERF.md, PR 30), and a fixed
+    capacity an expert multiplies its padding and needs rounds where the
+    routing is uneven (PERF.md, PR 35)."""
     shape = x.shape
     tokens = x.reshape(-1, shape[-1])
-    T, E = tokens.shape[0], wg.shape[-3]
+    E = wg.shape[-3]
     idx, _ = held_choices(idx, E, expert_offset)
-    if T < RIDGE_ROWS:
-        def bank(a):
-            return a if layer is None else lax.dynamic_index_in_dim(
-                a, layer, 0, keepdims=False)
 
-        res = _experts_masked(tokens, w, idx, bank(wg), bank(wu), bank(wd))
-        rows = jnp.int32(T * E)
-    else:
-        def whole(a):
-            return a.reshape((-1,) + a.shape[-2:])
+    def whole(a):
+        return a.reshape((-1,) + a.shape[-2:])
 
-        res, rows = _experts_grouped(
-            tokens, w, idx, whole(wg), whole(wu), whole(wd), E,
-            0 if layer is None else layer * E,
-            share=E / (router_experts or E))
+    res, rows = _experts_grouped(
+        tokens, w, idx, whole(wg), whole(wu), whole(wd), E,
+        0 if layer is None else layer * E,
+        share=E / (router_experts or E))
     return res.astype(x.dtype).reshape(shape), rows
 
 

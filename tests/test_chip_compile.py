@@ -167,9 +167,16 @@ def test_rope_fwd_bwd(chip):
 _SERVE = dict(layers=16, slots=32, pages=1024, page=16, table=256)
 _MIB = 1 << 20
 _COPY = re.compile(r"= (\w+)\[([\d,]*)\]\S* copy(?:-start)?\(")
-# one layer's bank of GLM-4.7-Flash's experts as the result of a slice
-_BANK_CUT = re.compile(r"= bf16\[(?:1,)?64,(?:2048,1536|1536,2048)\]\S* "
-                       r"(?:dynamic-)?slice\(")
+
+
+def _bank_cut(E, h, f):
+    """One layer's bank of ``E`` experts ``[h, f]`` (or its transpose)
+    as the result of a slice."""
+    return re.compile(r"= bf16\[(?:1,)?%d,(?:%d,%d|%d,%d)\]\S* "
+                      r"(?:dynamic-)?slice\(" % (E, h, f, f, h))
+
+
+_BANK_CUT = _bank_cut(64, 2048, 1536)           # GLM-4.7-Flash's
 _BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
           "u32": 4, "f32": 4}
 
@@ -260,8 +267,8 @@ def test_latent_engine_programs_fit_and_copy_no_pool(chip, program,
     layer's three banks (1.2 GB) or the whole stacks (2 x 2.4 GB) every
     call.  The fills' grouped matmuls (ISSUE 35) are handed the stacks
     whole and find the layer's experts by index: no bank is the result
-    of a slice in a fill's program.  Now 86 MB / 9 MB / 188 MB of
-    temporaries."""
+    of a slice in a fill's program, nor (ISSUE 37) in the step's.  Now
+    82 MB / 9 MB / 180 MB of temporaries."""
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
     from paddle_tpu.models.glm_moe_lite import (glm_4_7_flash,
                                                 init_glm_moe_lite_params)
@@ -301,10 +308,10 @@ def test_latent_engine_programs_fit_and_copy_no_pool(chip, program,
     # the pool rides as ONE pool of all layers' pages, in place
     assert "bf16[229376,16,640]" in text
     assert "bf16[32768,16,640]" not in text
-    if program != "step":
-        # a fill's grouped matmuls find the layer's experts in the stacks
-        assert not _BANK_CUT.search(text), "a bank was cut out"
-        assert text.count("moe_grouped_matmul") >= 2
+    # the grouped matmuls, the step's too, find the layer's experts in
+    # the stacks
+    assert not _BANK_CUT.search(text), "a bank was cut out"
+    assert text.count("moe_grouped_matmul") >= 2
 
 
 # the Ling-3.0-flash serve cell's engine (benchmark/configs: layer 0 and
@@ -313,9 +320,7 @@ def test_latent_engine_programs_fit_and_copy_no_pool(chip, program,
 # the ONE latent layer, a table of 256)
 _LINEAR = dict(layers=7, held=128, vocab=39296, slots=128, pages=32768,
                page=16, table=256)
-# a layer's bank of its held experts as the result of a slice
-_LINEAR_BANK_CUT = re.compile(
-    r"= bf16\[(?:1,)?128,(?:2560,768|768,2560)\]\S* (?:dynamic-)?slice\(")
+_LINEAR_BANK_CUT = _bank_cut(128, 2560, 768)    # its held experts
 
 
 @pytest.mark.parametrize("program, temp_mib", [
@@ -333,9 +338,8 @@ def test_linear_engine_programs_fit_and_copy_no_state(chip, program,
     or ``[.., 3, C]`` were copied whole into a padded layout and back by
     every fill (57 MB each way), and as ``[.., 3 C]`` rows by the step
     until its conv read them as 2-D slices (``ops.ssm.
-    causal_conv_step``).  Now 175 MB / 16 MB / 31 MB of temporaries (the
-    step's: every row through every held expert, ``[128, 128, 768]``
-    twice a layer)."""
+    causal_conv_step``).  Now 168 MB / 16 MB / 30 MB of temporaries
+    (ISSUE 37: the step and the 128 fill take the grouped form too)."""
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
     from paddle_tpu.models import ling_linear as zoo
     z = _LINEAR
@@ -384,13 +388,10 @@ def test_linear_engine_programs_fit_and_copy_no_state(chip, program,
     assert "bf16[32768,16,640]" in text
     if program == "step":
         assert text.count("kda_state_update") >= 3    # a run of KDA layers
-    elif program == "fill512":
-        # its grouped matmuls find the layer's held experts in the stacks
-        # (the masked form of the step and the 128 fill reads a layer's
-        # bank through a slice that its matmul's fusion takes in: the
-        # temporaries above have no room for a 503 MB bank)
-        assert not _LINEAR_BANK_CUT.search(text), "a bank was cut out"
-        assert text.count("moe_grouped_matmul") >= 2
+    # the grouped matmuls, the step's too, find the layer's held experts
+    # in the stacks
+    assert not _LINEAR_BANK_CUT.search(text), "a bank was cut out"
+    assert text.count("moe_grouped_matmul") >= 2
 
 
 def test_decode_attention(chip):
@@ -427,26 +428,36 @@ def test_kda_state_update(chip):
     assert "kda_state_update" in compiled.as_text()
 
 
-@pytest.mark.parametrize("tokens", [512, 2048])
-def test_moe_grouped_matmul(chip, tokens):
-    """The chunk fills' expert layer alone (``parallel/moe.py``'s grouped
-    form: the sort, the two ``moe_grouped_matmul`` calls, the gathers)
-    at GLM-4.7-Flash's widths and the cell's two chunk sizes, its bank
+@pytest.mark.parametrize("tokens, E, router, k, h, f", [
+    pytest.param(512, 64, 64, 4, 2048, 1536, id="512"),
+    pytest.param(2048, 64, 64, 4, 2048, 1536, id="2048"),
+    pytest.param(64, 64, 64, 4, 2048, 1536, id="step-64-of-64-held"),
+    pytest.param(128, 128, 512, 8, 2560, 768, id="step-128-of-512-held"),
+])
+def test_moe_grouped_matmul(chip, tokens, E, router, k, h, f):
+    """The expert layer alone (``parallel/moe.py``'s grouped form: the
+    sort, the two ``moe_grouped_matmul`` calls, the gathers), its bank
     one layer of a 6-layer stack that the kernels are handed whole: no
-    bank is cut out of it."""
+    bank is cut out of it.  At GLM-4.7-Flash's widths the cell's two
+    chunk sizes and its 64-row decode step; at Ling-3.0-flash's the
+    128-row step (and 128 fill) of a rank that holds 128 of the
+    router's 512 experts."""
     from paddle_tpu.parallel.moe import moe_swiglu_ffn_routed
-    E, k, h, f = 64, 4, 2048, 1536
     up, down = chip((6, E, h, f)), chip((6, E, f, h))
     compiled = compile_kernel(
         lambda x, w, idx, g, u, d, i: moe_swiglu_ffn_routed(
-            x, w, idx, g, u, d, layer=i),
+            x, w, idx, g, u, d, layer=i, router_experts=router),
         chip((tokens, h)), chip((tokens, k), jnp.float32),
         chip((tokens, k), jnp.int32), up, up, down, chip((), jnp.int32),
         kernels=2)
     text = compiled.as_text()
     assert text.count("moe_grouped_matmul") >= 2
-    assert not _BANK_CUT.search(text), "a bank was cut out of the stack"
-    assert compiled.memory_analysis().temp_size_in_bytes < 256 * _MIB
+    assert not _bank_cut(E, h, f).search(text), \
+        "a bank was cut out of the stack"
+    # 0.7 MB of temporaries for a step, 4 for the 512 fill, 48 for the
+    # 2048 fill
+    limit = 256 if tokens > 128 else 16
+    assert compiled.memory_analysis().temp_size_in_bytes < limit * _MIB
 
 
 def test_quant_linear_int8(chip):

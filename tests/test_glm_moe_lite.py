@@ -275,7 +275,8 @@ def _even(T, E, k):
     ("rows_no_multiple_of_the_tile", 301, None, None, None),
     ("first_layer_of_a_stack", 256, 3, 0, None),
     ("last_layer_of_a_stack", 256, 3, 2, None),
-    ("under_the_ridge", 40, 3, 1, None),
+    ("a_steps_few_rows", 40, 3, 1, None),
+    ("fewer_pairs_than_experts", 3, 3, 1, None),
 ])
 def test_grouped_form_equals_the_loop_over_experts(case, T, layers, layer,
                                                    choose):
@@ -283,9 +284,9 @@ def test_grouped_form_equals_the_loop_over_experts(case, T, layers, layer,
     tokens, for any routing (``choose``: the router's own where None):
     the grouped form (rows sorted by expert, each expert's rows in whole
     tiles, ``moe_grouped_matmul`` over the tiles that hold a row; the
-    kernel interpreted here) from ``RIDGE_ROWS`` tokens on, the masked
-    form under it, with no kernel in the program.  A bank inside a stack
-    is found by index: the other layers' weights are NaN."""
+    kernel interpreted here), for a chunk fill's rows and a decode
+    step's few alike.  A bank inside a stack is found by index: the
+    other layers' weights are NaN."""
     E, k = 8, 2
     x, *bank = _bank(T=T)
     logits, bias = _routing(T=T)
@@ -304,17 +305,15 @@ def test_grouped_form_equals_the_loop_over_experts(case, T, layers, layer,
     got, rows = jax.jit(fn)(x, w, idx, *bank)
     np.testing.assert_allclose(got, want,
                                atol=1e-5 * float(jnp.abs(want).max()))
-    grouped = "pallas_call" in str(jax.make_jaxpr(fn)(x, w, idx, *bank))
-    assert grouped == (T >= moe.RIDGE_ROWS)
-    if grouped:
-        # whole tiles of each expert's rows: never fewer rows than
-        # pairs, never a tile more an expert than its rows need
-        tm = moe_grouped_matmul.grouped_tiles(T * k, E, 64, 32, 2, 4)[0]
-        load = np.bincount(np.asarray(idx).reshape(-1), minlength=E)
-        assert int(rows) == int((-(-load // tm) * tm).sum())
-        assert T * k <= int(rows) < T * k + E * tm
-    else:
-        assert int(rows) == T * E
+    assert str(jax.make_jaxpr(fn)(x, w, idx, *bank)).count(
+        "pallas_call") == 2
+    # whole tiles of each expert's rows: never fewer rows than pairs,
+    # never a tile more an expert than its rows need, and none for an
+    # expert no row chose
+    tm = moe_grouped_matmul.grouped_tiles(T * k, E, 64, 32, 2, 4)[0]
+    load = np.bincount(np.asarray(idx).reshape(-1), minlength=E)
+    assert int(rows) == int((-(-load // tm) * tm).sum())
+    assert T * k <= int(rows) < T * k + min(E, T * k) * tm
 
 
 def test_grouped_tiles_follow_the_shape():
@@ -432,6 +431,12 @@ def test_counters_and_prefix_cache():
     # pairs, at most one pair a live row
     assert s["moe_assignments_total"] / 8 <= s["moe_peak_load"] \
         <= s["decode_slot_steps"] * layers
+    # the rows the steps' expert matmuls multiplied: whole tiles of 8 of
+    # all 3 slots' pairs (an idle slot's row is routed too), at most a
+    # tile a pair
+    assert s["moe_assignments_local"] <= s["moe_step_rows"] \
+        <= s["decode_steps"] * layers * 3 * k * 8
+    assert s["moe_step_rows"] % 8 == 0
     assert s["decode_pages_walked"] >= s["decode_pages_live"] > 0
     # a latent page is position-absolute like a K/V page: the second
     # request reuses the first one's full pages and serves the same
@@ -442,12 +447,12 @@ def test_counters_and_prefix_cache():
 
 
 def test_a_wide_bucket_fills_through_the_grouped_form():
-    """The file's other engines fill in buckets of (8, 16), under
-    ``RIDGE_ROWS``: the masked form.  A bucket of 256 takes the grouped
-    form (the kernel interpreted): a prompt of four chunks (256, 16, 16,
-    12 in 16) serves what the float32 reference computes, and the fills'
-    counts say what was multiplied: every dispatched row routed (padded
-    rows too), at least a row a pair."""
+    """The file's other engines fill in buckets of (8, 16).  A bucket
+    of 256 takes the same grouped form at taller tiles (the kernel
+    interpreted): a prompt of four chunks (256, 16, 16, 12 in 16) serves
+    what the float32 reference computes, and the fills' counts say what
+    was multiplied: every dispatched row routed (padded rows too), at
+    least a row a pair."""
     eng = _engine(prefill_buckets=(16, 256), max_batch=2, num_blocks=96,
                   max_blocks_per_seq=80)
     before = _stats(eng)
@@ -462,11 +467,12 @@ def test_a_wide_bucket_fills_through_the_grouped_form():
     assert s["prefill_chunks"] == 4
     assert s["prefill_tokens_dispatched"] == 256 + 3 * 16
     assert s["moe_fill_pairs"] == s["prefill_tokens_dispatched"] * k * layers
-    # the 256 chunk: whole tiles of 64 rows, at most one more an expert
-    # than its rows fill; the 16-row chunks: every row by all 8 experts
-    small = 3 * 16 * 8 * layers
-    assert 256 * k * layers <= s["moe_fill_rows"] - small \
-        < (256 * k + 8 * 64) * layers
+    # whole tiles of an expert's rows, at most one more an expert than
+    # its rows fill: tiles of 64 rows in the 256 chunk, of 8 in the
+    # three 16-row chunks (never the 16 x 8 of every row by every expert)
+    assert s["moe_fill_pairs"] <= s["moe_fill_rows"] \
+        < s["moe_fill_pairs"] + 8 * (64 + 3 * 8) * layers
+    assert s["moe_fill_rows"] < (256 * k + 8 * 64 + 3 * 16 * 8) * layers
     assert eng.kv_leak_report()["leaked"] == 0
 
 

@@ -313,33 +313,37 @@ def _bank(T, E=16, H=32, F=16, k=4, seed=6):
     return x, w, idx, wg, wu, wd
 
 
-@pytest.mark.parametrize("T", [20, 256])
+@pytest.mark.parametrize("T", [20, 128, 256])
 def test_the_shares_terms_add_up_to_the_whole_layers(T):
     """``moe_swiglu_ffn_routed`` for a rank that holds experts ``[off,
-    off + 4)`` of 16, in both forms (masked under ``RIDGE_ROWS``,
-    grouped above): the four shares' sums, gates unchanged, are the
-    whole bank's; a share counts rows for its own pairs only."""
+    off + 4)`` of 16, for a decode step's rows (20, 128) and a chunk
+    fill's (256), all in the grouped form: the four shares' sums, gates
+    unchanged, are the whole bank's; a share's ``rows`` are whole tiles
+    of its own held pairs only, and none for an expert no row chose."""
+    from paddle_tpu.ops.pallas.moe_grouped_matmul import grouped_tiles
     x, w, idx, wg, wu, wd = _bank(T)
     whole, _ = moe.moe_swiglu_ffn_routed(x, w, idx, wg, wu, wd)
-    total, rows = 0, []
+    # a tile is an even share of the EXPECTED held pairs an expert:
+    # a quarter of the T x 4 pairs over the 4 held
+    tm = grouped_tiles(T, 4, 32, 16, 2, 4)[0]
+    total = 0
     for off in range(0, 16, 4):
         part, r = moe.moe_swiglu_ffn_routed(
             x, w, idx, wg[off:off + 4], wu[off:off + 4], wd[off:off + 4],
             expert_offset=off, router_experts=16)
-        total, rows = total + part, rows + [int(r)]
-        held = moe.held_choices(idx, 4, off)[1]
-        want = T * 4 if T < moe.RIDGE_ROWS else int(held.sum())
-        assert int(r) >= want
+        total = total + part
+        local, held = moe.held_choices(idx, 4, off)
+        load = np.bincount(np.asarray(local[held]), minlength=4)
+        assert int(r) == int((-(-load // tm) * tm).sum())
+        assert int(held.sum()) <= int(r) < int(held.sum()) + 4 * tm
+        assert int(r) < T * 4                    # not every row by each
     np.testing.assert_allclose(total, whole, atol=2e-5)
-    if T >= moe.RIDGE_ROWS:
-        # a tile is an even share of the EXPECTED held pairs an expert
-        assert max(rows) < T * 4
-    # a rank none of whose experts was chosen adds nothing
+    # a rank none of whose experts was chosen multiplies no row and
+    # adds nothing
     none, r = moe.moe_swiglu_ffn_routed(
         x, w, jnp.zeros_like(idx), wg[4:8], wu[4:8], wd[4:8],
         expert_offset=4, router_experts=16)
-    assert float(jnp.abs(none).max()) == 0 and \
-        int(r) == (T * 4 if T < moe.RIDGE_ROWS else 0)
+    assert float(jnp.abs(none).max()) == 0 and int(r) == 0
 
 
 def test_held_choices_and_their_counts():
@@ -546,6 +550,52 @@ def test_counters_and_what_is_off():
     assert p["enabled"] is False and p["hits"] == 0
     assert eng.kernel_tiers() == {"kda_state_update": {
         "tier": "xla", "reason": "not on a TPU"}}
+
+
+def test_the_steps_experts_multiply_held_pairs_only():
+    """``moe_step_rows``: the rows a decode step's expert matmuls
+    multiplied, whole tiles of the pairs on HELD experts (all 16 rows
+    are routed, an idle slot's too), summed over the 3 expert layers
+    and the steps: it moves with the steps, is never fewer than the
+    live rows' held pairs, and stays under every row by every held
+    expert (16 x 8 a layer), which is what the masked form multiplied."""
+    eng = _engine(max_batch=16)
+    prompts = _prompts((5, 9, 7, 3), seed=12)
+    seen = [_stats(eng)]
+    for new in (3, 5):
+        for p in prompts:
+            eng.add_request(p, new)
+        eng.run_to_completion()
+        seen.append(_stats(eng))
+    for before, after in zip(seen, seen[1:]):
+        steps = after["decode_steps"] - before["decode_steps"]
+        rows = after["moe_step_rows"] - before["moe_step_rows"]
+        local = after["moe_assignments_local"] \
+            - before["moe_assignments_local"]
+        assert steps > 0 and 0 < local <= rows < steps * 3 * 16 * 8
+        # tiles of 8 rows: at most 7 rows of padding a held expert
+        assert rows % 8 == 0 and rows <= steps * 3 * (16 * 2 + 8 * 7)
+    assert eng.kv_leak_report()["leaked"] == 0
+
+
+@pytest.mark.parametrize("metric, cell", [
+    ("moe_step_slack.ling3flash", "ling3flash-reason"),
+    ("moe_step_slack.glm47flash", "glm47flash-long")])
+def test_the_step_slack_metrics_read_the_two_counters(metric, cell):
+    """``benchmark/metrics/moe_step_slack.*.json``: the steps' rows over
+    their pairs on held experts, as ``scheduler_stats()`` names them; a
+    program without the counter (the parent commit) leaves the metric
+    out of the line."""
+    from benchmark.lib import cell as harness, model
+    (m,) = [m for m in harness.load_metrics(cell, model.HERE)
+            if m["name"] == metric]
+    assert {m["numerator"], m["denominator"]} <= set(
+        _engine().scheduler_stats())
+    got = harness.reduce_metrics([m], {"counters": {
+        "moe_step_rows": 6 * 1040, "moe_assignments_local": 6 * 260}})
+    assert got == {metric: {"value": 4.0, "unit": "rows/pair"}}
+    assert harness.reduce_metrics(
+        [m], {"counters": {"moe_assignments_local": 6 * 260}}) == {}
 
 
 def test_greedy_rows_are_picked_on_the_device():
