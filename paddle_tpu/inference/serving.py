@@ -498,7 +498,9 @@ class ContinuousBatchingEngine:
         self.stats = {"prefix_blocks_reused": 0,
                       "prefix_blocks_registered": 0,
                       "pages_allocated": 0,
-                      "prefill_tokens_computed": 0}
+                      "prefill_tokens_computed": 0,
+                      # what a decode dispatch's fetch brings back
+                      "decode_fetch_bytes": 0}
         self.slots: List[Optional[GenRequest]] = [None] * max_batch
         self.queue: "collections.deque[GenRequest]" = collections.deque()
         self.finished: Dict[int, np.ndarray] = {}
@@ -1726,6 +1728,28 @@ class ContinuousBatchingEngine:
                 return True
         return False
 
+    def _stamp(self, active: List[int], name: str, m0: float, m1: float,
+               pre: Optional[Dict[int, int]] = None) -> None:
+        """Timeline on: the step ``[m0, m1]`` written into every live
+        request's trace as a span ``name`` (with ``pre``, the slots'
+        output lengths before a speculative step, also what each
+        ``committed``).  The tracer's own work, a ``Trace.add`` under a
+        lock a slot: the ``stamp`` span, so that a reader of the time
+        between two steps can leave it out."""
+        tl = self._tl
+        sp = tl.enter("stamp")
+        n = 0
+        for s in active:
+            r = self.slots[s]
+            tr = self._trace_of(r) if r is not None else None
+            if tr is not None:
+                more = {} if pre is None \
+                    else {"committed": len(r.out) - pre[s]}
+                tr.add(name, m0 - tr.mono_t0, m1 - tr.mono_t0,
+                       batch=len(active), **more)
+                n += 1
+        tl.leave(sp, traces=n)
+
     #: the fleet router numbers its replicas' engines; a solo engine
     #: has none (the timeline's ``engine_step`` carries it)
     replica: Optional[int] = None
@@ -1756,11 +1780,16 @@ class ContinuousBatchingEngine:
         self._retire_done()
         self._admit()
         self._retire_done()
+        # what the host settles before it hands a step over: who is
+        # live, how far the walk goes, the counts
+        sp = tl and tl.enter("plan")
         active = [s for s in range(self.B) if self.slots[s] is not None]
         if not active:
             self.last_logits = None     # nothing decoded this iteration
             out = self.finished
             self.finished = {}
+            if tl:
+                tl.leave(sp, batch=0)
             return out
         if self._spec is not None and self._spec.config.enabled:
             # speculative decode: draft K, verify K+1 in one dispatch,
@@ -1769,6 +1798,8 @@ class ContinuousBatchingEngine:
             pre = sum(len(self.slots[s].out) for s in active)
             pre_by_slot = {s: len(self.slots[s].out) for s in active} \
                 if tl else None
+            if tl:
+                tl.leave(sp, batch=len(active))
             m0 = time.monotonic() if tl else 0.0
             sp = tl and tl.enter("spec_decode", batch=len(active))
             self._spec.run_decode(active)
@@ -1776,14 +1807,8 @@ class ContinuousBatchingEngine:
                 tl.leave(sp, committed=sum(
                     len(self.slots[s].out) for s in active) - pre)
                 m1 = time.monotonic()
-                for s in active:
-                    r = self.slots[s]
-                    tr = self._trace_of(r) if r is not None else None
-                    if tr is not None:
-                        tr.add("spec_decode_step",
-                               m0 - tr.mono_t0, m1 - tr.mono_t0,
-                               batch=len(active),
-                               committed=len(r.out) - pre_by_slot[s])
+                self._stamp(active, "spec_decode_step", m0, m1,
+                            pre_by_slot)
             self.decode_steps += 1
             self.decode_slot_steps += len(active)
             self.decode_tokens += \
@@ -1801,16 +1826,38 @@ class ContinuousBatchingEngine:
             np.sum(-(-seen[active] // self.BS)))
         if self._hybrid:
             self.state_slot_steps += len(active) * self.ssm_state.shape[0]
+        if tl:
+            tl.leave(sp, batch=len(active))
         m0 = time.monotonic() if tl else 0.0
         sp = tl and tl.enter("decode_dispatch", batch=len(active))
-        # a hybrid's or a latent model's step also returns its expert
-        # layers' counts and every row's first choice
-        logits, *extra = self._keep(self._step(
-            self.params, *self._carried(), jnp.asarray(self.block_table),
-            jnp.asarray(self.lengths), jnp.asarray(self.tokens)))
+        sc = tl and tl.enter(
+            "upload", bytes=self.block_table.nbytes + self.lengths.nbytes
+            + self.tokens.nbytes)
+        args = (*self._carried(), jnp.asarray(self.block_table),
+                jnp.asarray(self.lengths), jnp.asarray(self.tokens))
         if tl:
+            tl.leave(sc)
+        # a hybrid's or a latent model's step also returns its expert
+        # layers' counts and every row's first choice; when `launch`
+        # ends the step is the runtime's
+        sc = tl and tl.enter("launch")
+        logits, *extra = self._keep(self._step(self.params, *args))
+        if tl:
+            tl.leave(sc)
             tl.leave(sp)
         sp = tl and tl.enter("logits_fetch")     # waits for the device
+        if tl:
+            # the timeline alone splits the wait from the copy: when
+            # `device_wait` ends the device is done, on the host's
+            # clock.  The first array's copy is queued behind the step
+            # first, as the `np.asarray` below queues it when nothing
+            # waits before it: the traced step pays what the untraced
+            # one pays, one wait and not two
+            sc = tl.enter("device_wait")
+            (extra[0] if extra else logits).copy_to_host_async()
+            jax.block_until_ready((logits, extra))
+            tl.leave(sc)
+            sc = tl.enter("copy")
         greedy = None
         if extra:
             # two small arrays come to the host; the logits ([B, V]
@@ -1833,7 +1880,9 @@ class ContinuousBatchingEngine:
         else:
             self.last_logits = np.asarray(logits)
             fetched = self.last_logits.nbytes
+        self.stats["decode_fetch_bytes"] += fetched
         if tl:
+            tl.leave(sc, bytes=fetched)
             tl.leave(sp, bytes=fetched)
         sp = tl and tl.enter("pick")
         for s in active:
@@ -1858,12 +1907,7 @@ class ContinuousBatchingEngine:
             self.tokens[s] = int(tok)
         if tl:
             tl.leave(sp, sampled=len(sampled))
-            m1 = time.monotonic()
-            for s in active:
-                tr = self._trace_of(self.slots[s])
-                if tr is not None:
-                    tr.add("decode_step", m0 - tr.mono_t0,
-                           m1 - tr.mono_t0, batch=len(active))
+            self._stamp(active, "decode_step", m0, time.monotonic())
         self.decode_steps += 1
         self.decode_slot_steps += len(active)
         self.decode_tokens += len(active)

@@ -40,7 +40,7 @@ from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
 from .sinks import JsonlSink, MemorySink, write_prometheus
 from .flight_recorder import FlightRecorder
 from .compile_monitor import CompileMonitor
-from .hw import estimate_mfu, peak_flops_per_chip
+from .hw import peak_flops_per_chip
 from .session import TelemetrySession, observe
 from .traced_lock import LockOrderRecorder, TracedLock
 from .tracing import (Span, SpanTracer, Timeline, Trace, TRACER,
@@ -50,7 +50,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "JsonlSink", "MemorySink", "write_prometheus", "FlightRecorder",
     "CompileMonitor", "TelemetrySession", "observe",
-    "estimate_mfu", "peak_flops_per_chip",
+    "peak_flops_per_chip",
     "LockOrderRecorder", "TracedLock",
     "Span", "SpanTracer", "Timeline", "Trace", "TRACER", "attribution",
     "write_spans_jsonl",

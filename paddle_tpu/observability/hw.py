@@ -1,11 +1,9 @@
-"""Hardware peak-FLOPs table + MFU estimation (shared by Model.fit
-telemetry and the bench harness)."""
+"""Hardware peak-FLOPs table: what ``Model.fit``'s telemetry divides
+its 6N-FLOPs-a-token rate by for the step record's ``mfu``."""
 
 from __future__ import annotations
 
-from typing import Optional
-
-__all__ = ["PEAK_BF16_FLOPS", "peak_flops_per_chip", "estimate_mfu"]
+__all__ = ["PEAK_BF16_FLOPS", "peak_flops_per_chip"]
 
 
 #: bf16 peak FLOP/s of one chip, keyed by ``jax.Device.device_kind``
@@ -33,20 +31,3 @@ def peak_flops_per_chip(device) -> float:
             f"no peak FLOP/s on file for device_kind {kind!r}; known: "
             f"{sorted(PEAK_BF16_FLOPS)} (add the row with its source)")
     return PEAK_BF16_FLOPS[kind]
-
-
-def estimate_mfu(items_per_sec: float, n_params: int,
-                 device=None, peak_flops: Optional[float] = None) -> float:
-    """Model-FLOPs utilization from the standard 6N FLOPs-per-token
-    approximation (fwd 2N + bwd 4N; attention term omitted — fit-level
-    telemetry does not know the sequence length, so this slightly
-    UNDER-estimates transformer MFU).  ``items`` are tokens for LM
-    training, samples otherwise."""
-    if peak_flops is None:
-        if device is None:
-            import jax
-            device = jax.local_devices()[0]
-        peak_flops = peak_flops_per_chip(device)
-    if peak_flops <= 0 or n_params <= 0:
-        return 0.0
-    return items_per_sec * 6.0 * float(n_params) / float(peak_flops)

@@ -586,10 +586,16 @@ class ServingFrontend:
                 if tl:
                     tl.leave(sp)
                 sp = tl and tl.enter("deliver")
+                sc = tl and tl.enter("collect")
                 self._collect(finished, now, deliveries)
                 pending = bool(self._recs)
+                if tl:
+                    tl.leave(sc)
+            # the lock is released: what follows is the clients' time
+            sc = tl and tl.enter("apply")
             self._apply(deliveries)
             if tl:
+                tl.leave(sc)
                 tl.leave(sp, tokens=sum(len(d.toks) for d in deliveries),
                          finished=len(finished))
             return pending

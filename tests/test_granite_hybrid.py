@@ -288,6 +288,19 @@ def test_greedy_rows_are_picked_on_the_device():
     eng.run_to_completion()
 
 
+def test_a_step_fetches_two_small_arrays_not_the_logits():
+    """``decode_fetch_bytes`` (always on): the expert layers' two int32
+    sums and a greedy int32 a slot, a step; the ``[3, 256]`` float32
+    logits (3072 bytes) stay where they are."""
+    eng = _engine()
+    b0, n0 = eng.stats["decode_fetch_bytes"], eng.decode_steps
+    eng.add_request(_prompts((9,))[0], 5)
+    eng.run_to_completion()
+    n = eng.decode_steps - n0
+    assert n == 4
+    assert eng.stats["decode_fetch_bytes"] - b0 == n * (2 * 4 + 3 * 4)
+
+
 def test_a_share_of_the_experts_counts_about_half():
     half = dict(CONFIG, num_local_experts=4, router_num_experts=8)
     eng = _engine(half)

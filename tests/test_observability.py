@@ -23,7 +23,7 @@ from paddle_tpu.io.dataset import TensorDataset
 from paddle_tpu.observability import (REGISTRY, CompileMonitor,
                                       FlightRecorder, JsonlSink,
                                       MemorySink, MetricsRegistry,
-                                      TelemetrySession, estimate_mfu,
+                                      TelemetrySession,
                                       peak_flops_per_chip,
                                       write_prometheus)
 
@@ -524,12 +524,6 @@ class TestHw:
         Dev.platform = "cpu"
         with pytest.raises(KeyError, match="device_kind 'cpu'"):
             peak_flops_per_chip(Dev())
-
-    def test_estimate_mfu(self):
-        # 1e4 tokens/s * 6 * 1e9 params = 6e13 FLOP/s on a 197e12 chip
-        mfu = estimate_mfu(1e4, int(1e9), peak_flops=197e12)
-        assert abs(mfu - 6e13 / 197e12) < 1e-9
-        assert estimate_mfu(1e4, 0, peak_flops=197e12) == 0.0
 
 
 class TestTelemetrySessionLifecycle:

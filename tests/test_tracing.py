@@ -670,7 +670,12 @@ TIMELINE_PARENT = {
     "prefill_chunk": "prefill", "first_token_fetch": "prefill",
     "decode_dispatch": "engine_step", "logits_fetch": "engine_step",
     "pick": "engine_step", "spec_decode": "engine_step",
-    "deliver": "iteration", "publish": "iteration"}
+    "deliver": "iteration", "publish": "iteration",
+    # the seam between two decode steps, phase by phase (ISSUE 38)
+    "plan": "engine_step", "upload": "decode_dispatch",
+    "launch": "decode_dispatch", "device_wait": "logits_fetch",
+    "copy": "logits_fetch", "stamp": "engine_step",
+    "collect": "deliver", "apply": "deliver"}
 
 
 def _assert_tree(it, root="iteration"):
@@ -1057,7 +1062,7 @@ def test_trace_report_xplane_mode(capsys):
     host = [("bench:engine_step", 0.0, 10.0), ("pt:iteration", 0.5, 9.5),
             ("pt:engine_step", 1.0, 8.0), ("pt:logits_fetch", 2.0, 5.0),
             ("pt:pick", 5.0, 6.0), ("pt:deliver", 8.5, 9.0)]
-    named = trace_report.split_gaps([(4.5, 8.75), (20.0, 21.0)], host, xp)
+    named = xp.idle_by_span([(4.5, 8.75), (20.0, 21.0)], host)
     assert named == pytest.approx({
         "pt:logits_fetch": 0.5, "pt:pick": 1.0, "pt:engine_step": 2.0,
         "pt:iteration": 0.5, "pt:deliver": 0.25, "unannotated": 1.0})
@@ -1085,6 +1090,212 @@ def test_trace_report_xplane_mode(capsys):
     named = trace_report.idle_by_span(ops, host, 0.499, xp)[3]
     assert named["pt:logits_fetch"] == pytest.approx(0.001)
     assert named["pt:deliver"] == pytest.approx(0.5)
+
+
+def test_trace_report_narrow_handshakes(capsys):
+    """A trace that holds ``pt:launch`` and ``pt:device_wait`` bounds
+    the clocks' offset by them: the uploads before the launch and the
+    copy after the wait leave the interval; one that holds neither
+    keeps the old pair.  The spans that got children name no idle time
+    as leaves any more."""
+    host = [("pt:iteration", 0.5, 9.5), ("pt:engine_step", 1.0, 8.0),
+            ("pt:decode_dispatch", 1.3, 1.9), ("pt:logits_fetch", 2.0, 5.0),
+            ("pt:pick", 5.0, 6.0), ("pt:deliver", 8.5, 9.0)]
+    mods = [("jit_step(123)", 1.7, 4.2), ("jit_fill(9)", 4.6, 4.7)]
+    assert trace_report.handshakes(host) == trace_report.SYNC_WIDE
+    assert trace_report.inner_nodes(host) == trace_report.INNER_NODES
+    upper, lower = trace_report.clock_check(mods, host)
+    assert (upper, lower) == (pytest.approx([0.4]), pytest.approx([-0.8]))
+    host += [("pt:upload", 1.3, 1.55), ("pt:launch", 1.6, 1.9),
+             ("pt:device_wait", 2.0, 4.3), ("pt:copy", 4.3, 5.0),
+             ("pt:collect", 8.5, 8.7), ("pt:apply", 8.7, 9.0)]
+    assert trace_report.handshakes(host) == trace_report.SYNC_NARROW
+    upper, lower = trace_report.clock_check(mods, host)
+    assert (upper, lower) == (pytest.approx([0.1]), pytest.approx([-0.1]))
+    # the wide pair is still there to compare with
+    upper, lower = trace_report.clock_check(mods, host,
+                                            trace_report.SYNC_WIDE)
+    assert (upper, lower) == (pytest.approx([0.4]), pytest.approx([-0.8]))
+    inner = trace_report.inner_nodes(host)
+    assert set(inner) == {"pt:iteration", "pt:engine_step",
+                          "pt:decode_dispatch", "pt:logits_fetch",
+                          "pt:deliver"}
+    # idle from 4.2 (the program ended) to 9: device_wait .1, copy .7,
+    # pick 1, engine_step 2, iteration .5, collect .2, apply .3
+    from benchmark.lib import xplane as xp
+    named = trace_report.idle_by_span([(0.0, 4.2), (9.0, 10.0)],
+                                      host, 0.0, xp)[3]
+    assert named == pytest.approx({
+        "pt:device_wait": 0.1, "pt:copy": 0.7, "pt:pick": 1.0,
+        "pt:engine_step": 2.0, "pt:iteration": 0.5, "pt:collect": 0.2,
+        "pt:apply": 0.3})
+    assert trace_report.leaf_share(named, inner) == \
+        pytest.approx(100 * 2.3 / 4.8)
+
+
+# ---------------------------------------------------------------------
+# the seam between two decode steps, phase by phase (ISSUE 38)
+# ---------------------------------------------------------------------
+def _kids(it, name):
+    """The children of the iteration's one span ``name``, in order."""
+    up = [s for s in it.spans if s.name == name]
+    assert len(up) == 1, (name, [s.name for s in it.spans])
+    return up[0], [s for s in it.spans if s.parent == up[0].span_id]
+
+
+def _sent(eng):
+    return eng.block_table.nbytes + eng.lengths.nbytes + eng.tokens.nbytes
+
+
+def test_seam_spans_solo_engine(model):
+    """Every host phase between "the device is done" and "the next step
+    is handed over" is a leaf under the span that held it; the parents
+    keep their names and their attrs."""
+    TRACER.enable()
+    eng = _engine(model)
+    eng.add_request(_prompt(model, 6), 4)
+    eng.run_to_completion()
+    its = TRACER.timeline().iterations()
+    for it in its:
+        _assert_tree(it, root="engine_step")
+    steps = [it for it in its
+             if any(s.name == "decode_dispatch" for s in it.spans)]
+    assert len(steps) == eng.decode_steps == 3
+    logits_bytes = eng.B * model[0].vocab_size * 4
+    for it in steps:
+        root, kids = _kids(it, "engine_step")
+        assert root.attrs == {"n": it.n}
+        assert [s.name for s in kids] == [
+            "retire", "admit", "retire", "plan", "decode_dispatch",
+            "logits_fetch", "pick", "stamp"]
+        by = {s.name: s for s in kids}
+        assert by["plan"].attrs == {"batch": 1}
+        assert by["decode_dispatch"].attrs == {"batch": 1}
+        assert by["logits_fetch"].attrs == {"bytes": logits_bytes}
+        assert by["pick"].attrs == {"sampled": 0}
+        # no request trace was active at add_request: nothing to stamp
+        assert by["stamp"].attrs == {"traces": 0}
+        _, kids = _kids(it, "decode_dispatch")
+        assert [(s.name, s.attrs) for s in kids] == [
+            ("upload", {"bytes": _sent(eng)}), ("launch", None)]
+        _, kids = _kids(it, "logits_fetch")
+        assert [(s.name, s.attrs) for s in kids] == [
+            ("device_wait", None), ("copy", {"bytes": logits_bytes})]
+    # the last iteration retires and decodes nothing: its plan says so
+    root, kids = _kids(its[-1], "engine_step")
+    assert [s.name for s in kids] == ["retire", "admit", "retire", "plan"]
+    assert kids[-1].attrs == {"batch": 0}
+
+
+def test_seam_spans_frontend_and_request_traces(model):
+    """Under a frontend ``deliver`` splits into the bookkeeping under
+    the lock and the hand-over to the clients; ``stamp`` holds the
+    per-request ``decode_step`` records, which still run from before
+    the dispatch to after the pick."""
+    TRACER.enable()
+    eng = _engine(model)
+    fe = ServingFrontend(eng)
+    hs = [fe.submit(_prompt(model, n), 4) for n in (6, 9)]
+    _drain(fe)
+    its = TRACER.timeline().iterations()
+    stamped = 0
+    for it in its:
+        _assert_tree(it)
+        root, kids = _kids(it, "iteration")
+        assert set(root.attrs) == {"n", "live", "queued"}
+        assert [s.name for s in kids] == ["expire", "engine_step",
+                                          "publish", "deliver"]
+        deliver, kids = _kids(it, "deliver")
+        assert set(deliver.attrs) == {"tokens", "finished"}
+        assert [(s.name, s.attrs) for s in kids] == [("collect", None),
+                                                     ("apply", None)]
+        if not any(s.name == "decode_dispatch" for s in it.spans):
+            continue
+        by = {s.name: s for s in it.spans}
+        assert by["stamp"].attrs == {"traces": by["plan"].attrs["batch"]}
+        stamped += by["stamp"].attrs["traces"]
+        # what decode_step_ms reads (dispatch start to pick end) lies
+        # inside the step as the requests' traces record it
+        for h in hs:
+            tr = h.trace
+            for s in tr.snapshot():
+                if s.name == "decode_step" and by["stamp"].t0 \
+                        <= tr.mono_t0 + s.t1 + 1e-9 \
+                        and tr.mono_t0 + s.t1 <= by["stamp"].t1:
+                    assert tr.mono_t0 + s.t0 <= by["decode_dispatch"].t0 \
+                        + 1e-6
+                    assert by["pick"].t1 <= tr.mono_t0 + s.t1 + 1e-6
+    assert stamped == eng.decode_slot_steps == sum(
+        sum(s.name == "decode_step" for s in h.trace.snapshot())
+        for h in hs)
+
+
+def test_seam_spans_spec_decode_stamp(model):
+    """The speculative branch has its own ``plan`` and ``stamp`` around
+    ``spec_decode``; the requests' ``spec_decode_step`` records are what
+    they were."""
+    from paddle_tpu.spec_decode import SpecDecodeConfig
+    TRACER.enable()
+    eng = _engine(model, spec_config=SpecDecodeConfig(
+        draft_cfg=model[0], draft_params=model[1], k=3, window=12))
+    fe = ServingFrontend(eng)
+    h = fe.submit(_prompt(model, 6), 5)
+    _drain(fe)
+    its = TRACER.timeline().iterations()
+    spec = [it for it in its
+            if any(s.name == "spec_decode" for s in it.spans)]
+    assert len(spec) == eng.decode_steps > 0
+    for it in spec:
+        _assert_tree(it)
+        _, kids = _kids(it, "engine_step")
+        assert [s.name for s in kids] == ["retire", "admit", "retire",
+                                          "plan", "spec_decode", "stamp"]
+        assert kids[-3].attrs == {"batch": 1}
+        assert kids[-1].attrs == {"traces": 1}
+    mine = [s for s in h.trace.snapshot() if s.name == "spec_decode_step"]
+    assert len(mine) == len(spec)
+    assert [set(s.attrs) for s in mine] == [{"batch", "committed"}] * \
+        len(mine)
+    assert sum(s.attrs["committed"] for s in mine) == 4
+
+
+@pytest.mark.parametrize("tracer_on", [False, True])
+def test_seam_counters_and_the_untraced_path(model, monkeypatch, tracer_on):
+    """``decode_fetch_bytes`` counts N steps x the fetched array's size
+    with the tracer on or off (what a step sends up is the ``upload``
+    span's ``bytes``, timeline on only); off, the step path waits for
+    the device nowhere but in the copy it always made (no
+    ``block_until_ready``), and there is no timeline."""
+    calls = []
+    real = jax.block_until_ready
+
+    def watched(x):
+        calls.append(1)
+        if not tracer_on:
+            raise AssertionError("block_until_ready on the untraced path")
+        return real(x)
+
+    if tracer_on:
+        TRACER.enable()
+    eng = _engine(model, enable_prefix_caching=False)
+    monkeypatch.setattr(jax, "block_until_ready", watched)
+    for n in (6, 11):
+        eng.add_request(_prompt(model, n), 5)
+    eng.run_to_completion()
+    n = eng.decode_steps
+    assert n == 4
+    assert eng.stats["decode_fetch_bytes"] == \
+        n * eng.B * model[0].vocab_size * 4
+    assert len(calls) == (n if tracer_on else 0)
+    if not tracer_on:
+        assert TRACER.timeline() is None
+        return
+    fetched = [s.attrs["bytes"] for it in TRACER.timeline().iterations()
+               for s in it.spans if s.name == "logits_fetch"]
+    assert sum(fetched) == eng.stats["decode_fetch_bytes"]
+    sent = [s.attrs["bytes"] for it in TRACER.timeline().iterations()
+            for s in it.spans if s.name == "upload"]
+    assert sent == [_sent(eng)] * n
 
 
 def test_trace_report_engine_mode(model, tmp_path, capsys):

@@ -291,12 +291,22 @@ def render_engine(tl: Dict[str, Any]) -> str:
 #: the engine timeline's spans (tracing.PROFILER_PREFIX) and the
 #: benchmark harness's own: both are read, only the first are reported
 PT, BENCH = "pt:", "bench:"
-#: spans whose own time is what the span table failed to cover
+#: spans whose own time is what the span table failed to cover ...
 INNER_NODES = (PT + "iteration", PT + "engine_step")
-#: the clock check: the decode program (XLA module ``jit_step``) starts
-#: only after its ``pt:decode_dispatch`` began, and the
-#: ``pt:logits_fetch`` that waits for it returns only after it ended
-SYNC_DISPATCH, SYNC_FETCH = PT + "decode_dispatch", PT + "logits_fetch"
+#: ... and the three that were leaves until their phases got spans of
+#: their own: inner nodes of a trace that holds the child named here
+SPLIT_NODES = {PT + "decode_dispatch": PT + "launch",
+               PT + "logits_fetch": PT + "device_wait",
+               PT + "deliver": PT + "collect"}
+#: the clock check's two handshakes, (before the program, after it):
+#: the decode program (XLA module ``jit_step``) starts only after the
+#: ``pt:launch`` that hands it over began, and the ``pt:device_wait``
+#: that waits for it returns only after it ended.  A trace without the
+#: two (a program before PR 38) has the wider pair around them: the
+#: whole ``pt:decode_dispatch`` (uploads included) and the whole
+#: ``pt:logits_fetch`` (the copy included)
+SYNC_NARROW = (PT + "launch", PT + "device_wait")
+SYNC_WIDE = (PT + "decode_dispatch", PT + "logits_fetch")
 SYNC_MODULE = re.compile(r"^jit_step\b")
 
 HostSpan = Tuple[str, float, float]
@@ -332,35 +342,28 @@ def read_xplane(path: str, xp) -> Tuple[List, List, List[HostSpan]]:
     return ops, modules, host
 
 
-def split_gaps(idle: List[Tuple[float, float]], host: List[HostSpan],
-               xp) -> Dict[str, float]:
-    """Idle seconds by the innermost host span at each instant: a gap
-    is cut at every span boundary inside it, so each piece lies within
-    or outside every span and ``name_gap`` picks the shortest."""
-    cuts = sorted({t for _, a, b in host for t in (a, b)})
-    out: Dict[str, float] = {}
-    for a, b in idle:
-        edges = [a] + cuts[bisect.bisect_right(cuts, a):
-                           bisect.bisect_left(cuts, b)] + [b]
-        for lo, hi in zip(edges, edges[1:]):
-            name = xp.name_gap((lo, hi), host)
-            out[name] = out.get(name, 0.0) + (hi - lo)
-    return out
+def handshakes(host: List[HostSpan]) -> Tuple[str, str]:
+    """The narrow pair where the trace has both spans, else the wide."""
+    names = {n for n, _, _ in host}
+    return SYNC_NARROW if names.issuperset(SYNC_NARROW) else SYNC_WIDE
 
 
-def clock_check(modules, host: List[HostSpan]
+def clock_check(modules, host: List[HostSpan],
+                sync: Optional[Tuple[str, str]] = None
                 ) -> Tuple[List[float], List[float]]:
     """How far the device's clock may be AHEAD of the host's, in
-    seconds, from each decode step's two handshakes: at most
-    ``jit_step`` start less the start of the last ``pt:decode_dispatch``
-    before it (a program cannot start before its dispatch began), and
-    at least ``jit_step`` end less the end of the first
-    ``pt:logits_fetch`` after it (the fetch cannot return before the
-    program ended).  Returns (the upper bounds, the lower bounds); the
-    offset lies between the largest lower and the smallest upper."""
+    seconds, from each decode step's two handshakes ``sync`` (the
+    narrowest pair the trace holds when not given): at most
+    ``jit_step`` start less the start of the last ``sync[0]`` span
+    before it (a program cannot start before it was handed over), and
+    at least ``jit_step`` end less the end of the first ``sync[1]`` span
+    after it (the wait cannot return before the program ended).
+    Returns (the upper bounds, the lower bounds); the offset lies
+    between the largest lower and the smallest upper."""
+    before, after = sync or handshakes(host)
     upper, lower = [], []
-    dispatch = sorted(a for n, a, _ in host if n == SYNC_DISPATCH)
-    fetched = sorted(b for n, _, b in host if n == SYNC_FETCH)
+    dispatch = sorted(a for n, a, _ in host if n == before)
+    fetched = sorted(b for n, _, b in host if n == after)
     for n, a, b in modules:
         if not SYNC_MODULE.match(n):
             continue
@@ -385,14 +388,22 @@ def idle_by_span(ops, host: List[HostSpan], shift: float, xp
     lo = min([a for a, _ in ops] + [a for _, a, _ in window])
     hi = max([b for _, b in ops] + [b for _, _, b in window])
     idle = xp.gaps(ops, lo, hi)
-    named = split_gaps(idle, [s for s in host if s not in window], xp)
+    named = xp.idle_by_span(idle, [s for s in host if s not in window])
     return hi - lo, sum(b - a for a, b in idle), len(idle), named
 
 
-def leaf_share(named: Dict[str, float]) -> float:
-    """Per cent of the idle time named by a leaf span of the table."""
+def inner_nodes(host: List[HostSpan]) -> Tuple[str, ...]:
+    names = {n for n, _, _ in host}
+    return INNER_NODES + tuple(p for p, child in SPLIT_NODES.items()
+                               if child in names)
+
+
+def leaf_share(named: Dict[str, float],
+               inner: Sequence[str] = INNER_NODES) -> float:
+    """Per cent of the idle time named by a leaf span of the table
+    (``inner``: the spans whose own time names nothing)."""
     return 100 * sum(v for k, v in named.items() if k.startswith(PT)
-                     and k not in INNER_NODES) / sum(named.values())
+                     and k not in inner) / sum(named.values())
 
 
 def render_xplane(path: str) -> str:
@@ -431,7 +442,9 @@ def render_xplane(path: str) -> str:
     # the two clocks are not one: name the gaps as recorded, and with
     # the device moved onto the host's clock by either end of the
     # interval the decode steps' handshakes leave for the offset
-    upper, lower = clock_check(modules, host)
+    sync = handshakes(host)
+    inner = inner_nodes(host)
+    upper, lower = clock_check(modules, host, sync)
     ends = [max(lower), min(upper)] if upper and lower else []
     moved = [idle_by_span(ops, host, -off, xp)[3] for off in ends]
     lines += ["", "idle by innermost span".ljust(24)
@@ -443,18 +456,26 @@ def render_xplane(path: str) -> str:
                          f"{100 * m.get(k, 0.0) / sum(m.values()):12.2f}"
                          for m in moved))
     lines.append(f"named by a leaf {PT} span, % of the idle time:"
-                 .ljust(48) + f"{leaf_share(named):12.2f}"
-                 + "".join(f"{leaf_share(m):12.2f}" for m in moved))
+                 .ljust(48) + f"{leaf_share(named, inner):12.2f}"
+                 + "".join(f"{leaf_share(m, inner):12.2f}" for m in moved))
     if ends:
         lines += ["", f"clocks, over {len(lower)} decode steps: device "
                   f"clock - host clock lies in [{1e3 * ends[0]:.3f}, "
                   f"{1e3 * ends[1]:.3f}] ms (from jit_step end - "
-                  f"{SYNC_FETCH} end, median {1e3 * _pct(lower, 50):.3f}"
-                  f", to jit_step start - {SYNC_DISPATCH} start, median "
+                  f"{sync[1]} end, median {1e3 * _pct(lower, 50):.3f}"
+                  f", to jit_step start - {sync[0]} start, median "
                   f"{1e3 * _pct(upper, 50):.3f}); the last columns name "
                   f"the gaps with the device's events moved onto the "
                   f"host's clock for either end; a span shorter than "
                   f"that interval is not resolved"]
+        wide = sync != SYNC_WIDE and clock_check(modules, host, SYNC_WIDE)
+        if wide and all(wide):
+            lines.append(
+                f"the interval's width: {1e3 * (ends[1] - ends[0]):.3f} ms"
+                f" between {sync[0]} and {sync[1]}; "
+                f"{1e3 * (min(wide[0]) - max(wide[1])):.3f} ms between "
+                f"{SYNC_WIDE[0]} and {SYNC_WIDE[1]}, the handshakes of a "
+                f"trace without the two")
     return "\n".join(lines)
 
 
