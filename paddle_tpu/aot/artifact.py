@@ -42,11 +42,16 @@ __all__ = [
     "ArtifactStore", "environment_fingerprint",
     "config_hash", "args_signature", "executable_device_ids",
     "devices_for_ids",
-    "fresh_backend_compile", "MANIFEST_MAGIC", "LATEST_POINTER",
+    "fresh_backend_compile", "MANIFEST_MAGIC", "MANIFEST_VERSION",
+    "LATEST_POINTER",
     "new_generation", "resolve_artifact_dir",
 ]
 
 MANIFEST_MAGIC = "paddle_tpu.aot.v1"
+#: the layout of what an export holds; a manifest of another version is
+#: refused (2: the decode step hands back its greedy row after its
+#: logits, and the merge that feeds it to the next step is exported)
+MANIFEST_VERSION = 2
 _MANIFEST = "manifest.json"
 #: rotation-root pointer file naming the live generation subdirectory
 LATEST_POINTER = "latest"
@@ -250,7 +255,7 @@ class ArtifactStore:
         """Start a fresh manifest for this export run."""
         self._manifest = {
             "magic": MANIFEST_MAGIC,
-            "version": 1,
+            "version": MANIFEST_VERSION,
             "env": environment_fingerprint(),
             "config": config,
             "config_hash": config_hash(config),
@@ -374,6 +379,10 @@ class ArtifactStore:
             raise AotManifestMismatchError(
                 f"{path}: not a {MANIFEST_MAGIC} manifest "
                 f"(magic={m.get('magic')!r})")
+        if m.get("version") != MANIFEST_VERSION:
+            raise AotManifestMismatchError(
+                f"{path}: manifest version {m.get('version')!r}, this "
+                f"tree reads {MANIFEST_VERSION} — re-export")
         if not isinstance(m.get("executables"), dict):
             raise AotArtifactCorruptError(
                 f"{path}: manifest has no executables table")

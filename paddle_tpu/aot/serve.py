@@ -20,6 +20,11 @@ a wrong program.
 
 Like the fresh engine, the exported steps donate the KV pools.
 
+The merge that feeds a step the greedy row of the step still in
+flight (``_ahead_tokens``) is one more program of the decode width,
+exported beside the decode step, so the order that launches ahead
+warm-starts without a compile too.
+
 Sampler coverage (ISSUE 7): the engine samples every sub-batch at the
 FIXED decode width ``max_batch`` (rows padded; vmap keeps real rows
 independent of padding), so exactly one sampler program exists per
@@ -54,6 +59,7 @@ __all__ = ["export_engine", "load_engine_artifacts", "engine_config",
 _DECODE = "decode"
 _FILL = "chunk_fill_{c}"
 _SAMPLER = "sampler"
+_AHEAD = "ahead_tokens"
 _DRAFT = "spec_draft"
 _VERIFY = "spec_verify"
 
@@ -147,6 +153,14 @@ def _sampler_args(engine) -> Tuple:
             jnp.asarray(np.zeros((B,), np.float32)))
 
 
+def _ahead_args(engine) -> Tuple:
+    """The merge of the host's tokens with the greedy row of the step
+    in flight (``inference/serving.py:_ahead_tokens``): two ``[B]``
+    int32 rows."""
+    row = jnp.asarray(np.zeros((engine.B,), np.int32))
+    return (row, row)
+
+
 def export_engine(engine, directory: str, *,
                   buckets: Optional[ShapeBucketRegistry] = None,
                   rotate: bool = False, keep_last: Optional[int] = None,
@@ -205,6 +219,12 @@ def export_engine(engine, directory: str, *,
         compiled = jax.jit(build_sampler()).lower(*args).compile()
         store.put(_SAMPLER, compiled, args)
 
+        # the next step's tokens while the last is in flight: one
+        # program per decode width (never donated)
+        from ..inference.serving import _ahead_tokens
+        args = _ahead_args(engine)
+        store.put(_AHEAD, _ahead_tokens.lower(*args).compile(), args)
+
         # speculative decode (ISSUE 8): the windowed draft and the
         # fixed-width K+1 verify are one program each per engine
         # geometry — exported so a speculating warm start is zero
@@ -256,8 +276,9 @@ def load_engine_artifacts(engine, directory: str, *, registry=None):
     """Verify + deserialize the serve executables for ``engine``.
 
     Returns ``(decode_step, {bucket: fill}, ShapeBucketRegistry,
-    sampler, spec_programs)`` — ``spec_programs`` is ``{}`` for a
-    non-speculating engine, else ``{"draft": ..., "verify": ...}``;
+    sampler, ahead_tokens, spec_programs)`` — ``spec_programs`` is
+    ``{}`` for a non-speculating engine, else ``{"draft": ...,
+    "verify": ...}``;
     raises an :class:`~paddle_tpu.aot.artifact.AotError` subclass on
     version skew, geometry mismatch, corruption, or a donation-unsafe
     artifact — the engine falls back to fresh compiles.  An artifact
@@ -286,9 +307,14 @@ def load_engine_artifacts(engine, directory: str, *, registry=None):
         raise AotManifestMismatchError(
             f"{directory}: sampler signature drifted from this engine's "
             "fixed decode width — re-export")
+    if not store.matches_signature(_AHEAD, _ahead_args(engine)):
+        raise AotManifestMismatchError(
+            f"{directory}: ahead-token signature drifted from this "
+            "engine's decode width — re-export")
     decode = store.get(_DECODE)
     fills = {c: store.get(_FILL.format(c=c)) for c in breg.chunk_sizes}
     sampler = store.get(_SAMPLER)
+    ahead = store.get(_AHEAD)
     spec = {}
     if engine.spec_config is not None:
         # the config hash already pinned the spec geometry; still match
@@ -303,4 +329,4 @@ def load_engine_artifacts(engine, directory: str, *, registry=None):
                 f"{directory}: verify signature drifted from this "
                 "engine's spec geometry — re-export")
         spec = {"draft": store.get(_DRAFT), "verify": store.get(_VERIFY)}
-    return decode, fills, breg, sampler, spec
+    return decode, fills, breg, sampler, ahead, spec

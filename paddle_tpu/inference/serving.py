@@ -152,8 +152,8 @@ class GenRequest:
 @dataclass
 class _Dispatch:
     """A decode step handed to the device and not yet consumed: its
-    outputs (``extra`` is a hybrid's or a latent model's counts and
-    greedy row, empty for the Llama family), the request each slot ran
+    outputs (``extra`` is the greedy row, after the expert layers'
+    counts where the model routes), the request each slot ran
     when it was launched (None for a row the step did not run), and
     what the consumer counts for it."""
     logits: object
@@ -171,6 +171,20 @@ def _ahead_tokens(host, greedy):
     """The next step's tokens while the last is in flight: its greedy
     row, where the host has no token of its own (-1) for the slot."""
     return jnp.where(host < 0, greedy, host)
+
+
+def _row_placement(params):
+    """Where a program's small outputs land for weights ``params``:
+    replicated over the devices of committed weights; None (the
+    runtime's choice) where the weights are not committed."""
+    leaf = jax.tree_util.tree_leaves(params)[0]
+    if not getattr(leaf, "committed", False):
+        return None
+    s = leaf.sharding
+    if isinstance(s, jax.sharding.NamedSharding):
+        return jax.sharding.NamedSharding(s.mesh,
+                                          jax.sharding.PartitionSpec())
+    return s
 
 
 def build_sampler():
@@ -586,6 +600,9 @@ class ContinuousBatchingEngine:
         self.aot_error: Optional[str] = None
         self._step = None
         self._sampler_fn = None
+        # the merge of ``_ahead_tokens`` as an AOT artifact loaded it
+        # (None: the module's own jitted function)
+        self._ahead_fn = None
         self._spec = None
         self.spec_config = spec_config
         # decode-phase accounting (extra.spec bench row).
@@ -649,9 +666,12 @@ class ContinuousBatchingEngine:
         # once per step(), read by the phases underneath it
         self._tl = None
         # the decode step on the device whose tokens the host has not
-        # taken yet (a step runs ahead only where it picks its greedy
-        # row itself: ``_iterate``)
+        # taken yet (``_iterate``)
         self._inflight: Optional[_Dispatch] = None
+        # the decode step's rows from the host are placed as the step's
+        # greedy row is: a step fed by the host and one fed the row in
+        # flight then see ONE placement, and the step compiles once
+        self._row_place = _row_placement(self.params)
         _spec_programs = {}
         if spec_config is not None:
             spec_config.validate_against(cfg)
@@ -660,7 +680,7 @@ class ContinuousBatchingEngine:
             from ..aot.serve import load_engine_artifacts
             try:
                 (self._step, self._bucket_fills, self._buckets,
-                 self._sampler_fn, _spec_programs) = \
+                 self._sampler_fn, self._ahead_fn, _spec_programs) = \
                     load_engine_artifacts(self, aot_dir)
                 self.aot_loaded = True
             except AotError as e:
@@ -738,7 +758,8 @@ class ContinuousBatchingEngine:
             xf = rms(x, params["lnf_w"])
             logits = jnp.einsum("bh,hv->bv", xf, params["head"],
                                 preferred_element_type=jnp.float32)
-            return pk2, pv2, logits
+            return (pk2, pv2, logits,
+                    jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
         return step
 
@@ -824,8 +845,8 @@ class ContinuousBatchingEngine:
     def last_logits(self) -> Optional[np.ndarray]:
         """``[B, V]`` float32 logits of the last decode dispatch (None
         when the last iteration decoded nothing), for tests and
-        debugging.  A hybrid's step leaves them on the device (it picks
-        the greedy tokens itself); they are fetched here, on demand."""
+        debugging.  The step leaves them on the device (it picks the
+        greedy tokens itself); they are fetched here, on demand."""
         if self._last_logits is not None \
                 and not isinstance(self._last_logits, np.ndarray):
             self._last_logits = np.asarray(self._last_logits)
@@ -1840,12 +1861,12 @@ class ContinuousBatchingEngine:
             self._tl = None
 
     def _iterate(self) -> Dict[int, np.ndarray]:
-        """Retire, admit, then hand the next decode step over.  Where
-        the step picks its greedy row on the device and no row samples,
-        step n+1 is launched on step n's greedy row BEFORE step n is
-        fetched, so that the host's seam and the round trip run while
-        the device computes: launch the next, consume the previous.
-        Otherwise: launch, consume."""
+        """Retire, admit, then hand the next decode step over.  The step
+        picks its greedy row on the device; where no row samples, step
+        n+1 is launched on step n's greedy row BEFORE step n is fetched,
+        so that the host's seam and the round trip run while the device
+        computes: launch the next, consume the previous.  Otherwise:
+        launch, consume."""
         tl = self._tl
         # retire first so freed slots/pages admit this very iteration;
         # then AGAIN after admission — the prefill's first token can
@@ -1915,7 +1936,7 @@ class ContinuousBatchingEngine:
             tl.leave(sp, batch=len(rows))
         d = self._launch(reqs, bt, lengths, trips * chunk_pages * self.B,
                          int(np.sum(-(-seen[rows] // self.BS))))
-        if ahead and d.extra:
+        if ahead:
             self._inflight = d
             if prev is not None:
                 self._consume(prev)
@@ -1951,27 +1972,30 @@ class ContinuousBatchingEngine:
                              batch=sum(r is not None for r in reqs))
         sc = tl and tl.enter(
             "upload", bytes=bt.nbytes + lengths.nbytes + self.tokens.nbytes)
+        place = self._row_place
+        up = jnp.asarray if place is None \
+            else (lambda a: jax.device_put(a, place))
         if prev is None:
-            tokens = jnp.asarray(self.tokens.copy())
+            tokens = up(self.tokens.copy())
         else:
-            tokens = _ahead_tokens(jnp.asarray(np.where(
+            merge = self._ahead_fn or _ahead_tokens
+            tokens = merge(up(np.where(
                 [prev.reqs[s] is self.slots[s] for s in range(self.B)], -1,
                 self.tokens).astype(np.int32)), prev.extra[-1])
-        args = (*self._carried(), jnp.asarray(bt), jnp.asarray(lengths),
-                tokens)
+        args = (*self._carried(), up(bt), up(lengths), tokens)
         if tl:
             tl.leave(sc)
-        # a hybrid's or a latent model's step also returns its expert
-        # layers' counts and every row's first choice; when `launch`
-        # ends the step is the runtime's, and what the host will fetch
-        # of it is queued to come back the moment it is done
+        # the step returns every row's first choice (after its expert
+        # layers' counts where the model routes); when `launch` ends the
+        # step is the runtime's, and what the host will fetch of it is
+        # queued to come back the moment it is done
         sc = tl and tl.enter("launch")
         ahead = prev is not None and not prev.extra[-1].is_ready()
         # the last consumed step's logits are let go before this step's
         # are made: two sets alive (the one in flight), not three
         self.last_logits = None
         logits, *extra = self._keep(self._step(self.params, *args))
-        for a in extra or (logits,):
+        for a in extra:
             a.copy_to_host_async()
         if tl:
             tl.leave(sc)
@@ -2003,22 +2027,16 @@ class ContinuousBatchingEngine:
             jax.block_until_ready((logits, extra))
             tl.leave(sc)
             sc = tl.enter("copy")
-        greedy = None
         n = sum(r is not None for r in d.reqs)
-        if extra:
-            # small arrays come to the host (the greedy row, and the
-            # expert layers' counts where the model routes); the logits
-            # ([B, V] float32: 25.7 MB a step at 64 slots and a 100k
-            # vocabulary) stay on the device unless a row samples or a
-            # test looks
-            *counts, greedy = (np.asarray(a) for a in extra)
-            self.last_logits = logits
-            fetched = sum(a.nbytes for a in extra)
-            if counts:
-                self._count_experts(n, counts[0])
-        else:
-            self.last_logits = np.asarray(logits)
-            fetched = self.last_logits.nbytes
+        # small arrays come to the host (the greedy row, and the expert
+        # layers' counts where the model routes); the logits ([B, V]
+        # float32: 25.7 MB a step at 64 slots and a 100k vocabulary)
+        # stay on the device unless a row samples or a test looks
+        *counts, greedy = (np.asarray(a) for a in extra)
+        self.last_logits = logits
+        fetched = sum(a.nbytes for a in extra)
+        if counts:
+            self._count_experts(n, counts[0])
         self.stats["decode_fetch_bytes"] += fetched
         if tl:
             tl.leave(sc, bytes=fetched)
@@ -2028,21 +2046,18 @@ class ContinuousBatchingEngine:
                 if r is not None and self.slots[s] is r]
         sampled = [s for s in live
                    if (self.slots[s].temperature or 0.0) > 0.0]
-        picks: Dict[int, int] = {}
+        picks = greedy
         if sampled:
             # ONE dispatch + sync for the whole sampled sub-batch
-            toks = self._sample_rows(
+            picks = greedy.copy()
+            picks[sampled] = self._sample_rows(
                 [self.slots[s] for s in sampled],
                 self.last_logits[sampled],
                 [int(self.lengths[s]) for s in sampled])
-            picks = dict(zip(sampled, toks.tolist()))
         for s in live:
-            tok = picks.get(s)
-            if tok is None:
-                tok = int(self.last_logits[s].argmax() if greedy is None
-                          else greedy[s])
-            self._append_tok(self.slots[s], int(tok))
-            self.tokens[s] = int(tok)
+            tok = int(picks[s])
+            self._append_tok(self.slots[s], tok)
+            self.tokens[s] = tok
         if tl:
             tl.leave(sp, sampled=len(sampled))
             self._stamp(live, "decode_step", d.t0, time.monotonic())

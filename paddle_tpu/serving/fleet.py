@@ -578,6 +578,10 @@ class EngineRouter:
             self._final_replica[rid] = p.replica
             self.stats["synthesized"] += 1
             return
+        # what the extraction took from the device and the router has
+        # not absorbed yet (a step in flight when the source engine was
+        # drained) joins the outer stream before the splice
+        p.req.out.extend(out[len(p.req.out):])
         need = portable.snapshot.num_blocks \
             if portable.snapshot is not None \
             else self._blocks_needed(

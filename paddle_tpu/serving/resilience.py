@@ -656,9 +656,9 @@ class SupervisedEngine:
                 if self._consecutive_slow >= p.slow_steps_to_crash:
                     n = self._consecutive_slow
                     self._consecutive_slow = 0
-                    self._recover(EngineCrashError(
+                    self._recover(self._settle(EngineCrashError(
                         f"{n} consecutive steps slower than "
-                        f"{p.slow_step_s}s — declaring the engine hung"))
+                        f"{p.slow_step_s}s — declaring the engine hung")))
                     return self._absorb({})
             else:
                 self._consecutive_slow = 0
@@ -748,13 +748,15 @@ class SupervisedEngine:
         t = self._tracked.pop(req_id, None)
         if t is None:
             return None
-        self._bridge(t)                 # fold any unabsorbed tokens in
         eng = self.engine
         for slot in range(eng.B):
             r = eng.slots[slot]
             if r is not None and r.req_id == req_id:
                 eng.preempt(slot)       # snapshot committed KV first
                 break
+        # fold any unabsorbed tokens in, the step the preemption took
+        # from the device included
+        self._bridge(t)
         snap = eng._spill.pop(req_id, None)
         eng.cancel(req_id)              # queued by now; frees nothing
         return PortableRequest(
@@ -867,6 +869,22 @@ class SupervisedEngine:
             out.update(self._pending_finished)
             self._pending_finished = {}
         return out
+
+    def _settle(self, exc: BaseException) -> BaseException:
+        """Before a slow (not dead) engine is torn down: take the decode
+        step it has in flight and fold every token it produced into the
+        outer requests, so the replay starts where the engine stood.
+        Returns the crash to recover from: ``exc``, or the step's own
+        error if taking it fails."""
+        try:
+            self.engine._drain()
+        except (KeyboardInterrupt, SystemExit):
+            raise
+        except Exception as e:
+            exc = e
+        for t in self._tracked.values():
+            self._bridge(t)
+        return exc
 
     def _recover(self, exc: BaseException) -> None:
         """Declared crash: circuit-breaker check, flight dump, rebuild

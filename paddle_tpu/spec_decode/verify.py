@@ -55,8 +55,9 @@ def build_verify_program(step_fn):
     def verify(params, pool_k, pool_v, block_table, lengths, tokens):
         def body(carry, tok):
             pk, pv, ln = carry
+            # the step's greedy row, after its logits, is not read here
             pk, pv, logits = step_fn(params, pk, pv, block_table, ln,
-                                     tok)
+                                     tok)[:3]
             return (pk, pv, ln + 1), logits
 
         (pk, pv, _), logits = jax.lax.scan(

@@ -179,6 +179,51 @@ def test_engine_config_mismatch_falls_back_with_event(serve_setup):
                                   list(fresh.values())[0])
 
 
+def test_three_result_step_artifact_is_refused(serve_setup, tmp_path,
+                                              monkeypatch):
+    """An artifact exported when the decode step handed back its logits
+    alone (three results, no greedy row to feed the step launched ahead
+    of its fetch) carries manifest version 1 and is refused by it,
+    never loaded: the engine falls back loudly, compiles its own step
+    (the greedy row is all a step fetches) and serves the fresh
+    engine's tokens."""
+    import json
+    cfg, params, prompts, aot_dir, fresh = serve_setup
+    old = tmp_path / "three_results"
+    eng = _engine(cfg, params)
+    build = eng._build_step
+    with monkeypatch.context() as m:
+        m.setattr(eng, "_build_step",
+                  lambda: (lambda *a: build()(*a)[:3]))
+        export_engine(eng, str(old))
+    # such a tree stamped version 1 and exported no merge of the ahead
+    # tokens; the config hash alone cannot tell the two steps apart
+    mpath = old / "manifest.json"
+    manifest = json.loads(mpath.read_text())
+    manifest["version"] = 1
+    del manifest["executables"]["ahead_tokens"]
+    mpath.write_text(json.dumps(manifest))
+    assert manifest["config_hash"] == ArtifactStore(str(aot_dir)) \
+        .manifest()["config_hash"]
+    sink = MemorySink()
+    REGISTRY.add_sink(sink)
+    REGISTRY.enable()
+    try:
+        warm = _engine(cfg, params, aot_dir=str(old))
+    finally:
+        REGISTRY.disable()
+        REGISTRY.remove_sink(sink)
+    assert not warm.aot_loaded and warm._ahead_fn is None
+    assert "manifest version 1" in warm.aot_error
+    assert [e for e in sink.by_kind("aot") if e.get("action") == "fallback"]
+    for p in prompts:
+        warm.add_request(p, 4)
+    out = warm.run_to_completion()
+    assert warm.stats["decode_fetch_bytes"] == warm.decode_steps * 4 * 2
+    for rid in fresh:
+        np.testing.assert_array_equal(out[rid], fresh[rid])
+
+
 def test_engine_version_skew_falls_back(serve_setup, tmp_path):
     """A manifest stamped by another jax version is NOT ours: fall back
     cleanly (never deserialize)."""

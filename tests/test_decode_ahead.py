@@ -1,12 +1,13 @@
 """Decode steps launched ahead (``ContinuousBatchingEngine._iterate``):
-where the step picks its greedy row on the device and no row samples,
-step n+1 goes to the device on step n's greedy row before step n is
-fetched.  Held to the same engine stepped in the synchronous order
+every family's step picks its greedy row on the device, and where no row
+samples, step n+1 goes to the device on step n's greedy row before step
+n is fetched.  Held to the same engine stepped in the synchronous order
 (launch, consume: forced here by patching ``_runs_ahead``, never by an
-option) for the three families whose step hands back its greedy row,
-across EOS, budget ends, re-admission, cancel, deadline expiry, a
-sampling row joining and the drain at the end.  Tiny sizes, float32,
-CPU: the tiny engines of the families' own tests."""
+option) for the four families, across EOS, budget ends, re-admission,
+cancel, deadline expiry, a sampling row joining and the drain at the
+end; and, for the Llama family alone, a prefix-cache hit and a priority
+preemption with a step in flight.  Tiny sizes, float32, CPU: the tiny
+engines of the families' own tests."""
 
 import jax
 import numpy as np
@@ -19,14 +20,47 @@ from paddle_tpu.inference import serving
 from paddle_tpu.inference.serving import ContinuousBatchingEngine
 from paddle_tpu.serving import ServingFrontend
 
-#: family: (its test module, configuration, weight scale).  The tiny
-#: hybrid's draw repeats one token an answer; its weights times 8 give
-#: answers that change, so that an EOS can fall mid-answer
-FAMILIES = {"granite": (granite, granite.CONFIG, 8.0),
-            "glm": (glm, glm.CONFIG, 1.0),
-            "ling": (ling, ling.SHARE, 1.0)}
+
+def _drawn(mod, config, scale=1.0):
+    """A family's tiny configuration and its weights, drawn by the
+    benchmark's program module."""
+    def make():
+        params = jax.tree_util.tree_map(
+            lambda x: x * scale, mod.prog.make_params(config, mod.SEED))
+        return mod.prog.program_config(config), params
+    return make
+
+
+def _llama():
+    from paddle_tpu import parallel as dist
+    from paddle_tpu.models.llama import build_llama_train_step, llama_tiny
+    from paddle_tpu.parallel.topology import HybridTopology, set_topology
+    cfg = llama_tiny()
+    topo = dist.init_topology(devices=jax.devices()[:1])
+    _, init_fn = build_llama_train_step(cfg, topo, num_microbatches=1)
+    params = jax.tree_util.tree_map(lambda x: x * 4.0,
+                                    init_fn(0)["params"])
+    set_topology(HybridTopology())
+    return cfg, params
+
+
+#: family: its configuration and weights.  The tiny hybrid's draw
+#: repeats one token an answer, the tiny Llama's runs of one; their
+#: weights times 8 and 4 give answers that change token by token, so
+#: that an EOS can fall mid-answer and a token fed twice shows
+FAMILIES = {"granite": _drawn(granite, granite.CONFIG, 8.0),
+            "glm": _drawn(glm, glm.CONFIG),
+            "ling": _drawn(ling, ling.SHARE),
+            "llama": _llama}
 
 _ENGINES = {}
+
+
+def _fresh(family, **kw):
+    cfg, params = FAMILIES[family]()
+    return ContinuousBatchingEngine(
+        cfg, params, max_batch=3, block_size=4, num_blocks=64,
+        max_blocks_per_seq=16, prefill_buckets=(8, 16), **kw)
 
 
 def _engine(family):
@@ -34,13 +68,7 @@ def _engine(family):
     every run leaves it drained).  No prefix cache: the second order
     would hit the first's prompts."""
     if family not in _ENGINES:
-        mod, config, scale = FAMILIES[family]
-        params = jax.tree_util.tree_map(
-            lambda x: x * scale, mod.prog.make_params(config, mod.SEED))
-        _ENGINES[family] = ContinuousBatchingEngine(
-            mod.prog.program_config(config), params, max_batch=3,
-            block_size=4, num_blocks=64, max_blocks_per_seq=16,
-            prefill_buckets=(8, 16), enable_prefix_caching=False)
+        _ENGINES[family] = _fresh(family, enable_prefix_caching=False)
     return _ENGINES[family]
 
 
@@ -186,23 +214,90 @@ def test_ahead_equals_the_synchronous_order(family, case, monkeypatch):
         assert got[1] is None and None not in (got[0], got[2])
 
 
-def test_the_llama_step_never_runs_ahead():
-    """The Llama family's step hands back logits, not a greedy row: it
-    keeps launch, consume, and counts no step ahead."""
-    from paddle_tpu.models.llama import llama_tiny, build_llama_train_step
-    from paddle_tpu import parallel as dist
-    from paddle_tpu.parallel.topology import HybridTopology, set_topology
-    cfg = llama_tiny()
-    topo = dist.init_topology(devices=jax.devices()[:1])
-    _, init_fn = build_llama_train_step(cfg, topo, num_microbatches=1)
-    params = init_fn(0)["params"]
-    set_topology(HybridTopology())
-    eng = ContinuousBatchingEngine(cfg, params, max_batch=2, block_size=8,
-                                   num_blocks=64)
-    for p in _prompts((5, 9, 7)):
-        eng.add_request(p % cfg.vocab_size, 5)
-    while eng.queue or eng.active_requests:
-        eng.step()
-        assert eng._inflight is None
-    assert eng.decode_steps > 0 and eng.stats["decode_ahead_steps"] == 0
-    _drained(eng)
+def _prefix_hit(eng):
+    """A request whose prompt starts with the first's 12 tokens (three
+    whole pages, cached at its admission) arrives after two steps, so it
+    is admitted onto the cached pages while a step is in flight."""
+    first, tail = _prompts((14, 5), seed=11)
+
+    def join(eng, rids):
+        rids.append(eng.add_request(
+            np.concatenate([first[:12], tail]), 6))
+    return lambda: _serve(eng, [(first, 9, {}), (tail, 7, {})],
+                          at={2: join})
+
+
+def _preempt(eng):
+    """Three rows fill the three slots; a request of a higher priority
+    arrives after two steps and the admission spills the shortest row's
+    pages with a step in flight (``preempt`` drains it), then restores
+    them when a slot frees."""
+    p = _prompts((6,), seed=12)[0]
+
+    def join(eng, rids):
+        rids.append(eng.add_request(p, 4, priority=5))
+    reqs = [(q, n, {}) for q, n in zip(_prompts((5, 9, 7), seed=13),
+                                       (10, 8, 9))]
+    return lambda: _serve(eng, reqs, at={2: join})
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_one_decode_step_compile(family, monkeypatch):
+    """The step fed the host's rows and the step fed the greedy row in
+    flight are ONE program: the host's rows are uploaded with the
+    placement the step's outputs have, committed weights (the Llama
+    family's, on the train step's mesh) or not."""
+    eng = _engine(family)
+    fed, take = [], serving._ahead_tokens
+    monkeypatch.setattr(serving, "_ahead_tokens",
+                        lambda *a: fed.append(1) or take(*a))
+    _batch(eng)()
+    assert fed
+    committed = jax.tree_util.tree_leaves(eng.params)[0].committed
+    assert committed == (family == "llama")
+    assert eng._step._cache_size() == 1
+
+
+LLAMA_CASES = {"prefix_hit": (_prefix_hit, {}),
+               "preempt": (_preempt, {"enable_prefix_caching": False})}
+
+
+@pytest.mark.parametrize("case", list(LLAMA_CASES))
+def test_llama_cache_and_preemption_with_a_step_in_flight(case,
+                                                           monkeypatch):
+    """The Llama family is the one that runs ahead with the prefix
+    cache and priority preemption on: a page freed or spilled while a
+    step is in flight is rewritten only by programs queued after that
+    step.  A fresh engine an order (a second run on one engine would
+    hit the first's cached prompts)."""
+    make, kw = LLAMA_CASES[case]
+    counts = {}
+    for order in ("ahead", "sync"):
+        eng = _fresh("llama", **kw)
+        if order == "sync":
+            monkeypatch.setattr(eng, "_runs_ahead", lambda: False)
+        run = make(eng)
+        inflight = []
+        real_step = eng.step
+
+        def step():
+            # whether the admission of this iteration meets a step in
+            # flight (the arrival comes after step 2)
+            inflight.append(eng._inflight is not None)
+            return real_step()
+        monkeypatch.setattr(eng, "step", step)
+        counts[order] = (run(), inflight[2],
+                         eng.prefix_stats()["hits"],
+                         dict(eng.resilience),
+                         eng.stats["decode_ahead_steps"])
+    (got, in_flight, hits, res, ahead), (want, sync_in_flight, hits_s,
+                                         res_s, ahead_s) = \
+        counts["ahead"], counts["sync"]
+    assert got == want and None not in got
+    assert in_flight and not sync_in_flight
+    assert ahead > 0 and ahead_s == 0
+    if case == "prefix_hit":
+        assert hits == hits_s >= 1
+    else:
+        assert res["preemptions"] == res_s["preemptions"] >= 1
+        assert res["restores"] == res_s["restores"] >= 1

@@ -390,6 +390,31 @@ def test_slow_step_policy_declares_crash(model):
     np.testing.assert_array_equal(res[a], want)
 
 
+def test_slow_step_crash_keeps_the_step_in_flight(model):
+    """A slow engine is not a dead one: the decode step it has in flight
+    when the policy declares the crash is taken before the rebuild, so
+    the replay starts from as many committed tokens as the synchronous
+    order (launch, consume) leaves."""
+    p1 = _prompt(model, 9)
+    committed = {}
+    for order in ("ahead", "sync"):
+        sup = SupervisedEngine(
+            lambda: _engine(model),
+            policy=_fast_policy(slow_step_s=0.0, slow_steps_to_crash=2),
+            sleep=lambda s: None)
+        if order == "sync":
+            sup.engine._runs_ahead = lambda: False
+        a = sup.add_request(p1, 8)
+        with faults.slow_steps(sup.engine, 0.002, n=2):
+            sup.step()
+            sup.step()                   # second slow step escalates
+        assert sup.stats["recoveries"] == 1
+        committed[order] = list(sup.tracked_request(a).out)
+    # the prefill's token and one a step
+    assert len(committed["ahead"]) == 3
+    assert committed["ahead"] == committed["sync"]
+
+
 def test_circuit_breaker_falls_back_to_abort_all(model):
     """A persistently crashing engine opens the circuit breaker; the
     front-end's existing typed abort-all path gives every live stream
@@ -456,7 +481,8 @@ def test_cancel_synthesized_result_after_recovery(model):
                            policy=_fast_policy(), sleep=lambda s: None)
     a = sup.add_request(pA, 2)
     b = sup.add_request(pB, 8)
-    sup.step()                            # a's budget fills this step
+    sup.step()                            # a's second token launched
+    sup.step()                            # ... and taken: budget met
     # recovery with a's budget already met synthesizes its terminal
     # result into the pending buffer and replays only b (this is the
     # pre-absorb window a concurrent cancel can land in)

@@ -846,10 +846,13 @@ def test_decode_walk_counters_scripted(model):
     reg = MetricsRegistry(enabled=True)
     fe = ServingFrontend(eng, registry=reg)
     a = fe.submit(_prompt(model, 20), 6)
-    fe.step()           # the attention sees 21 tokens of A: one trip
-    assert (eng.decode_pages_walked, eng.decode_pages_live) == (64, 3)
+    fe.step()           # the attention sees 21 tokens of A: one trip,
+    # still on the device (a step counts when its tokens are taken)
+    assert (eng.decode_pages_walked, eng.decode_pages_live) == (0, 0)
     b = fe.submit(_prompt(model, 300), 3)
-    fe.step()           # A 22, B 301: two trips over both rows
+    fe.step()           # A's first step taken; A 22, B 301 launched
+    assert (eng.decode_pages_walked, eng.decode_pages_live) == (64, 3)
+    fe.step()           # A 22, B 301 taken: two trips over both rows
     assert (eng.decode_pages_walked, eng.decode_pages_live) \
         == (64 + 128, 3 + 3 + 38)
     _drain(fe)          # A 23 + B 302, then A alone at 24 and 25
@@ -984,7 +987,7 @@ def test_timeline_spans_are_profiler_annotations(model, monkeypatch):
 
 
 def test_timeline_disabled_costs_nothing(model, monkeypatch):
-    """Tracer off: no annotation is opened, and 200 engine steps that
+    """Tracer off: no annotation is opened, and 250 engine steps that
     admit, decode and retire keep no record — under half a block a
     step stays allocated (what JAX's own dispatch leaves behind),
     against dozens a step once the tracer is on."""
@@ -992,11 +995,13 @@ def test_timeline_disabled_costs_nothing(model, monkeypatch):
     eng = _engine(model, enable_prefix_caching=False)
     prompt = _prompt(model, 6)
 
-    def forty_steps():
-        # per request: the admitting step, two more tokens, the retire
+    def fifty_steps():
+        # per request: the admitting step (its first decode step goes
+        # to the device), two more launched as the last is taken, the
+        # drain of the last, the retire
         for _ in range(10):
             eng.add_request(prompt, 4)
-            for _ in range(4):
+            for _ in range(5):
                 eng.step()
             assert not eng.queue and eng.active_requests == 0
 
@@ -1004,12 +1009,12 @@ def test_timeline_disabled_costs_nothing(model, monkeypatch):
         gc.collect()
         before = sys.getallocatedblocks()
         for _ in range(5):
-            forty_steps()
+            fifty_steps()
         gc.collect()
         return sys.getallocatedblocks() - before
 
     for _ in range(4):                          # compile, fill caches
-        forty_steps()
+        fifty_steps()
     off = min(net_blocks() for _ in range(3))
     assert off <= 100, f"disabled timeline kept {off} blocks"
     assert rec.log == [] and TRACER.timeline() is None
@@ -1020,9 +1025,10 @@ def test_timeline_disabled_costs_nothing(model, monkeypatch):
 def test_timeline_leaves_harness_readings_alone(model):
     """What benchmark/lib/serve.py:_readings extracts: the per-request
     ``decode_step`` spans (one engine-level fact copied per slot) name
-    exactly ``decode_steps`` distinct steps, each inside its
-    iteration's ``engine_step``; ``ttft_s`` is the ``first_token``
-    instant."""
+    exactly ``decode_steps`` distinct steps, each from inside the
+    ``engine_step`` that launched it to inside the one that took its
+    tokens, the next (the step runs ahead) or the same (a drain);
+    ``ttft_s`` is the ``first_token`` instant."""
     TRACER.enable()
     eng = _engine(model)
     fe = ServingFrontend(eng)
@@ -1042,9 +1048,12 @@ def test_timeline_leaves_harness_readings_alone(model):
     assert len(steps) == eng.decode_steps > 0
     tl_steps = [s for it in TRACER.timeline().iterations()
                 for s in it.spans if s.name == "engine_step"]
+    def within(t):
+        return [i for i, s in enumerate(tl_steps)
+                if s.t0 <= t + 1e-6 and t <= s.t1 + 1e-6]
     for t0, dur in steps:
-        assert any(s.t0 <= t0 + 1e-6 and t0 + dur <= s.t1 + 1e-6
-                   for s in tl_steps)
+        i, j = within(t0), within(t0 + dur)
+        assert i and j and j[-1] - i[0] in (0, 1)
 
 
 def test_trace_report_xplane_mode(capsys):
@@ -1154,7 +1163,8 @@ def _sent(eng):
 def test_seam_spans_solo_engine(model):
     """Every host phase between "the device is done" and "the next step
     is handed over" is a leaf under the span that held it; the parents
-    keep their names and their attrs."""
+    keep their names and their attrs.  The step runs ahead: an
+    iteration hands step n+1 over, then takes step n's greedy row."""
     TRACER.enable()
     eng = _engine(model)
     eng.add_request(_prompt(model, 6), 4)
@@ -1162,40 +1172,45 @@ def test_seam_spans_solo_engine(model):
     its = TRACER.timeline().iterations()
     for it in its:
         _assert_tree(it, root="engine_step")
-    steps = [it for it in its
-             if any(s.name == "decode_dispatch" for s in it.spans)]
-    assert len(steps) == eng.decode_steps == 3
-    logits_bytes = eng.B * model[0].vocab_size * 4
-    for it in steps:
+    head = ["retire", "admit", "retire", "plan"]
+    launch, take = ["decode_dispatch"], ["logits_fetch", "pick", "stamp"]
+    # the first step goes to the device; two more are launched as the
+    # one before is taken; the last is drained; the last iteration
+    # retires and decodes nothing
+    assert [[s.name for s in _kids(it, "engine_step")[1]] for it in its] \
+        == [head + launch, head + launch + take, head + launch + take,
+            head + take, head]
+    assert eng.decode_steps == 3
+    greedy_bytes = eng.B * 4
+    for it in its:
         root, kids = _kids(it, "engine_step")
         assert root.attrs == {"n": it.n}
-        assert [s.name for s in kids] == [
-            "retire", "admit", "retire", "plan", "decode_dispatch",
-            "logits_fetch", "pick", "stamp"]
         by = {s.name: s for s in kids}
-        assert by["plan"].attrs == {"batch": 1}
-        assert by["decode_dispatch"].attrs == {"batch": 1}
-        assert by["logits_fetch"].attrs == {"bytes": logits_bytes}
-        assert by["pick"].attrs == {"sampled": 0}
-        # no request trace was active at add_request: nothing to stamp
-        assert by["stamp"].attrs == {"traces": 0}
-        _, kids = _kids(it, "decode_dispatch")
-        assert [(s.name, s.attrs) for s in kids] == [
-            ("upload", {"bytes": _sent(eng)}), ("launch", None)]
-        _, kids = _kids(it, "logits_fetch")
-        assert [(s.name, s.attrs) for s in kids] == [
-            ("device_wait", None), ("copy", {"bytes": logits_bytes})]
-    # the last iteration retires and decodes nothing: its plan says so
-    root, kids = _kids(its[-1], "engine_step")
-    assert [s.name for s in kids] == ["retire", "admit", "retire", "plan"]
-    assert kids[-1].attrs == {"batch": 0}
+        launched = "decode_dispatch" in by
+        # the plan says what the step it hands over runs
+        assert by["plan"].attrs == {"batch": int(launched)}
+        if launched:
+            assert by["decode_dispatch"].attrs == {"batch": 1}
+            _, sub = _kids(it, "decode_dispatch")
+            assert [(s.name, s.attrs) for s in sub] == [
+                ("upload", {"bytes": _sent(eng)}), ("launch", None)]
+        if "logits_fetch" in by:
+            assert by["logits_fetch"].attrs == {"bytes": greedy_bytes}
+            assert by["pick"].attrs == {"sampled": 0}
+            # no request trace was active at add_request: nothing to
+            # stamp
+            assert by["stamp"].attrs == {"traces": 0}
+            _, sub = _kids(it, "logits_fetch")
+            assert [(s.name, s.attrs) for s in sub] == [
+                ("device_wait", None), ("copy", {"bytes": greedy_bytes})]
 
 
 def test_seam_spans_frontend_and_request_traces(model):
     """Under a frontend ``deliver`` splits into the bookkeeping under
     the lock and the hand-over to the clients; ``stamp`` holds the
     per-request ``decode_step`` records, which still run from before
-    the dispatch to after the pick."""
+    the dispatch that launched the step to after the pick that took
+    it (an iteration later: the step runs ahead)."""
     TRACER.enable()
     eng = _engine(model)
     fe = ServingFrontend(eng)
@@ -1203,6 +1218,7 @@ def test_seam_spans_frontend_and_request_traces(model):
     _drain(fe)
     its = TRACER.timeline().iterations()
     stamped = 0
+    launched = []           # steps on the device: (plan batch, dispatch)
     for it in its:
         _assert_tree(it)
         root, kids = _kids(it, "iteration")
@@ -1213,22 +1229,26 @@ def test_seam_spans_frontend_and_request_traces(model):
         assert set(deliver.attrs) == {"tokens", "finished"}
         assert [(s.name, s.attrs) for s in kids] == [("collect", None),
                                                      ("apply", None)]
-        if not any(s.name == "decode_dispatch" for s in it.spans):
-            continue
         by = {s.name: s for s in it.spans}
-        assert by["stamp"].attrs == {"traces": by["plan"].attrs["batch"]}
-        stamped += by["stamp"].attrs["traces"]
-        # what decode_step_ms reads (dispatch start to pick end) lies
-        # inside the step as the requests' traces record it
-        for h in hs:
-            tr = h.trace
-            for s in tr.snapshot():
-                if s.name == "decode_step" and by["stamp"].t0 \
-                        <= tr.mono_t0 + s.t1 + 1e-9 \
-                        and tr.mono_t0 + s.t1 <= by["stamp"].t1:
-                    assert tr.mono_t0 + s.t0 <= by["decode_dispatch"].t0 \
-                        + 1e-6
-                    assert by["pick"].t1 <= tr.mono_t0 + s.t1 + 1e-6
+        for sp in sorted((by[k] for k in ("decode_dispatch", "stamp")
+                          if k in by), key=lambda s: s.t0):
+            if sp.name == "decode_dispatch":
+                launched.append((by["plan"].attrs["batch"], sp))
+                continue
+            batch, dispatch = launched.pop(0)
+            assert sp.attrs == {"traces": batch}
+            stamped += batch
+            # what decode_step_ms reads (dispatch start to pick end)
+            # lies inside the step as the requests' traces record it
+            for h in hs:
+                tr = h.trace
+                for s in tr.snapshot():
+                    if s.name == "decode_step" \
+                            and sp.t0 <= tr.mono_t0 + s.t1 + 1e-9 \
+                            and tr.mono_t0 + s.t1 <= sp.t1:
+                        assert tr.mono_t0 + s.t0 <= dispatch.t0 + 1e-6
+                        assert by["pick"].t1 <= tr.mono_t0 + s.t1 + 1e-6
+    assert not launched
     assert stamped == eng.decode_slot_steps == sum(
         sum(s.name == "decode_step" for s in h.trace.snapshot())
         for h in hs)
@@ -1265,8 +1285,9 @@ def test_seam_spans_spec_decode_stamp(model):
 
 @pytest.mark.parametrize("tracer_on", [False, True])
 def test_seam_counters_and_the_untraced_path(model, monkeypatch, tracer_on):
-    """``decode_fetch_bytes`` counts N steps x the fetched array's size
-    with the tracer on or off (what a step sends up is the ``upload``
+    """``decode_fetch_bytes`` counts N steps x the greedy row's size
+    (``B`` int32: the logits stay on the device) with the tracer on or
+    off (what a step sends up is the ``upload``
     span's ``bytes``, timeline on only); off, the step path waits for
     the device nowhere but in the copy it always made (no
     ``block_until_ready``), and there is no timeline."""
@@ -1288,8 +1309,7 @@ def test_seam_counters_and_the_untraced_path(model, monkeypatch, tracer_on):
     eng.run_to_completion()
     n = eng.decode_steps
     assert n == 4
-    assert eng.stats["decode_fetch_bytes"] == \
-        n * eng.B * model[0].vocab_size * 4
+    assert eng.stats["decode_fetch_bytes"] == n * eng.B * 4
     assert len(calls) == (n if tracer_on else 0)
     if not tracer_on:
         assert TRACER.timeline() is None
@@ -1300,6 +1320,32 @@ def test_seam_counters_and_the_untraced_path(model, monkeypatch, tracer_on):
     sent = [s.attrs["bytes"] for it in TRACER.timeline().iterations()
             for s in it.spans if s.name == "upload"]
     assert sent == [_sent(eng)] * n
+
+
+def test_last_logits_fetched_on_demand(model, monkeypatch):
+    """The Llama step leaves its logits on the device: read through
+    ``last_logits`` after a step, they are the logits of the step the
+    host took last, the same that the synchronous order fetches after
+    that step, and the greedy row the rows were fed is their argmax."""
+    ahead = _engine(model, enable_prefix_caching=False)
+    sync = _engine(model, enable_prefix_caching=False)
+    monkeypatch.setattr(sync, "_runs_ahead", lambda: False)
+    for n in (6, 11):
+        p = _prompt(model, n)
+        ahead.add_request(p, 6)
+        sync.add_request(p, 6)
+    for _ in range(3):
+        sync.step()
+    for _ in range(4):                  # the fourth takes the third step
+        ahead.step()
+    assert ahead._inflight is not None
+    assert not isinstance(ahead._last_logits, np.ndarray)
+    got = ahead.last_logits
+    assert isinstance(got, np.ndarray)
+    assert got.shape == (ahead.B, model[0].vocab_size)
+    np.testing.assert_array_equal(got, sync.last_logits)
+    np.testing.assert_array_equal(got.argmax(-1), ahead.tokens)
+    assert ahead.decode_steps == sync.decode_steps == 3
 
 
 def test_trace_report_engine_mode(model, tmp_path, capsys):

@@ -110,7 +110,12 @@ def gpt_train() -> Callable[[], None]:
 def serve_fresh() -> Callable[[], None]:
     """Serving at liveness shapes: cold engine start
     (the one-time q/k/v relayout, 13 -> 14 with ISSUE 31; decode step +
-    one declared-bucket fill compile) + full drain."""
+    one declared-bucket fill compile) + full drain.  14 -> 15 once the
+    Llama family's steps launch ahead of their fetch: the merge of the
+    host's tokens with the greedy row in flight.  The step still
+    compiles once: these weights are committed to the train step's
+    mesh, and the host's rows are uploaded with the placement the
+    step's greedy row has."""
     cfg, params, prompts = _tiny_llama()
 
     def workload():
